@@ -9,7 +9,7 @@ import sys
 import time
 
 
-def main() -> None:
+def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true",
                     help="smaller graph suite (CI)")
@@ -31,18 +31,23 @@ def main() -> None:
         ("roofline", roofline.main),
     ]
     only = set(args.only.split(",")) if args.only else None
+    failed = []
     for name, fn in sections:
         if only and name not in only:
             continue
         t0 = time.time()
         try:
             out = fn(fast=args.fast)
-        except Exception as e:  # keep the suite running; report the failure
+        except Exception as e:  # keep the suite running; fail at the end
             out = f"# {name} FAILED: {type(e).__name__}: {e}\n"
+            failed.append(name)
         sys.stdout.write(f"\n===== {name} ({time.time()-t0:.1f}s) =====\n")
         sys.stdout.write(out)
         sys.stdout.flush()
+    if failed:
+        print(f"failed sections: {', '.join(failed)}", file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

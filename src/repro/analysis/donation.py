@@ -3,10 +3,9 @@
 `jax.jit(..., donate_argnums=/donate_argnames=)` lets XLA alias the
 argument's device buffer into the output — after the donating call the
 python name still points at an invalidated buffer, and touching it
-raises (or worse, on some backends silently reads garbage). Both
-double-buffered drivers in this repo donate (`core/driver.py` chunk
-buffers, `launch/serve.py` KV cache), so the safe idiom is pinned down
-here:
+raises (or worse, on some backends silently reads garbage). Steps that
+carry state donate it (`launch/serve.py` KV cache, `launch/train.py`
+parameters), so the safe idiom is pinned down here:
 
     params, opt, loss = jit_step(params, opt, batch)   # rebind: OK
     logits, cache = decode(params, cache, tok)         # loop rebind: OK
@@ -96,7 +95,7 @@ def _collect_donors(scope_body: Sequence[ast.stmt],
         if d is None and isinstance(st.value, ast.Name):
             d = donors.get(st.value.id)            # alias of a donor
         if d is None and isinstance(st.value, ast.IfExp):
-            # fn = plain if cpu else donated  (driver.py lazy variant pick)
+            # fn = plain if cpu else donated  (lazy variant pick)
             for branch in (st.value.body, st.value.orelse):
                 if isinstance(branch, ast.Name) and branch.id in donors:
                     d = donors[branch.id]

@@ -19,12 +19,18 @@ Writes that are pure functions of grid-invariant inputs (the idempotent
 revisited-block pattern frame_step uses) carry no cross-step state and
 pass clean.
 
-R3 (Mosaic compilability): flag
+R3 (Mosaic compilability): flag what the installed TPU compiler refuses
+(jax 0.9.0; each refusal checked by compiling for a described v5e):
 
-* integer/bool-dtype axis reductions (`jnp.sum/cumsum/prod/mean`, or the
-  `.sum(axis=...)` method forms) inside a kernel body — Mosaic rejects
-  integer-axis reductions; accumulate in f32 (exact below 2^24) and cast
-  back (the PR-1 review fix);
+* reductions over unsigned-integer values (`jnp.sum/max/min/...` over
+  the uint32 `population_count` of bitset words, with or without an
+  axis) — "Reductions over unsigned integers not implemented". int32
+  and bool reductions compile over either axis, so counts hop to int32
+  first (`population_count(x).astype(jnp.int32)`);
+* casts between unsigned integers and floats (`.astype(jnp.float32)` of
+  a uint32 popcount, or back) — "Unsupported cast: uint32 -> float32",
+  the fault that kept every bitset kernel off the chip. uint32 <-> int32
+  and int32 <-> float32 casts compile, so a float count goes via int32;
 * `pl.BlockSpec` shapes built from literals whose trailing dims are
   neither (8, 128)-multiples nor 1 (1 ~ "equals the array dim", which
   is legal; non-literal dims are shape-dependent and skipped; specs
@@ -42,10 +48,12 @@ R3 (Mosaic compilability): flag
   poster child (literal (8, 128) frames); SMEM scratch is scalar memory
   and exempt.
 
-Both rules are static approximations: dtypes are inferred by a local
-forward dataflow over the kernel body (population_count/bitwise -> int,
-`.astype(jnp.float32)` -> float, unknown stays unknown and is never
-flagged).
+The dtype rules are static approximations: dtypes are inferred by a
+local forward dataflow over the kernel body and over the module's own
+functions it calls (`.astype`/iota/`jnp.uint32(...)` fix a kind,
+`population_count` keeps its operand's kind and takes an operand the
+pass cannot see as unsigned — the bitset words; Python int literals are
+weakly typed; anything else unknown stays unknown and is never flagged).
 """
 from __future__ import annotations
 
@@ -60,13 +68,14 @@ RULE_VMAP = "R2"
 RULE_MOSAIC = "R3"
 
 _FLOAT_NAMES = {"float32", "float64", "float16", "bfloat16", "float_", "float"}
-_INT_NAMES = {"int8", "int16", "int32", "int64", "uint8", "uint16", "uint32",
-              "uint64", "int_", "int"}
-_REDUCERS = {"sum", "cumsum", "prod", "mean"}
+_INT_NAMES = {"int8", "int16", "int32", "int64", "int_", "int"}
+_UINT_NAMES = {"uint8", "uint16", "uint32", "uint64"}
+_REDUCERS = {"sum", "cumsum", "prod", "mean", "max", "min", "amax", "amin"}
 _FLOAT_FNS = {"exp", "log", "sqrt", "rsqrt", "sigmoid", "softmax", "tanh",
               "logaddexp", "erf"}
 
-INT, FLOAT, BOOL, UNKNOWN = "int", "float", "bool", "unknown"
+INT, UINT, FLOAT, BOOL, UNKNOWN = ("int", "uint", "float", "bool",
+                                   "unknown")
 
 
 # ---------------------------------------------------------------------------
@@ -264,6 +273,8 @@ def _dtype_kind(node: Optional[ast.AST]) -> str:
         return FLOAT
     if name in _INT_NAMES:
         return INT
+    if name in _UINT_NAMES:
+        return UINT
     if name in ("bool", "bool_"):
         return BOOL
     return UNKNOWN
@@ -276,7 +287,25 @@ def _join(a: str, b: str) -> str:
         return FLOAT
     if a == b:
         return a
-    return INT                                      # int ∨ bool -> int
+    if BOOL in (a, b):
+        return a if b == BOOL else b                # bool promotes
+    return INT                                      # int ∨ uint -> int
+
+
+def _callee(node: ast.Call) -> str:
+    """Last name of the callee, also for methods of call results
+    (`population_count(x).astype(...)` -> 'astype')."""
+    if isinstance(node.func, ast.Attribute):
+        return node.func.attr
+    return (call_name(node) or "").rpartition(".")[2]
+
+
+def _weak(node: ast.AST) -> bool:
+    """A Python int literal (or its negation): weakly typed in jax."""
+    if isinstance(node, ast.UnaryOp):
+        node = node.operand
+    return (isinstance(node, ast.Constant) and isinstance(node.value, int)
+            and not isinstance(node.value, bool))
 
 
 class _DtypeFlow:
@@ -311,7 +340,7 @@ class _DtypeFlow:
         if isinstance(node, ast.BinOp):
             if isinstance(node.op, ast.Div):
                 return FLOAT
-            return _join(self.infer(node.left), self.infer(node.right))
+            return self._join_weak(node.left, node.right)
         if isinstance(node, (ast.Compare, ast.BoolOp)):
             return BOOL
         if isinstance(node, ast.IfExp):
@@ -320,20 +349,36 @@ class _DtypeFlow:
             return self._infer_call(node)
         return UNKNOWN
 
+    def _join_weak(self, a: ast.AST, b: ast.AST) -> str:
+        if _weak(a) and not _weak(b):
+            return self.infer(b)
+        if _weak(b) and not _weak(a):
+            return self.infer(a)
+        return _join(self.infer(a), self.infer(b))
+
     def _infer_call(self, node: ast.Call) -> str:
         name = call_name(node) or ""
-        last = name.rpartition(".")[2]
-        if last == "astype":
-            return _dtype_kind(node.args[0] if node.args else None)
+        last = _callee(node)
+        if last in ("astype", "convert_element_type"):
+            return _dtype_kind(node.args[-1] if node.args else None)
+        if last in _UINT_NAMES | _INT_NAMES | _FLOAT_NAMES and \
+                name.startswith(("jnp.", "np.")):
+            return _dtype_kind(ast.Name(id=last))   # jnp.uint32(0)
         if last == "population_count":
-            return INT
+            kind = self.infer(node.args[0]) if node.args else UNKNOWN
+            return kind if kind in (INT, UINT) else UINT
         if last.startswith("bitwise") or last in ("left_shift",
                                                   "right_shift", "invert"):
-            return INT
+            kinds = [self.infer(a) for a in node.args]
+            known = [k for k in kinds if k != UNKNOWN]
+            out = known[0] if known else UNKNOWN
+            for k in known[1:]:
+                out = _join(out, k)
+            return out
         if last in _FLOAT_FNS:
             return FLOAT
         if last == "where" and len(node.args) == 3:
-            return _join(self.infer(node.args[1]), self.infer(node.args[2]))
+            return self._join_weak(node.args[1], node.args[2])
         if last in ("broadcasted_iota", "iota"):
             return _dtype_kind(node.args[0] if node.args else None)
         if last in ("zeros", "ones", "full", "arange", "zeros_like",
@@ -361,43 +406,70 @@ class _DtypeFlow:
 
 
 def _reduction_operand(node: ast.Call) -> Optional[ast.AST]:
-    """Operand of jnp.sum(x, axis=...) or x.sum(axis=...); None if the
-    call has no axis argument (full reductions lower fine)."""
-    has_axis = _kw(node, "axis") is not None
+    """Operand of jnp.sum(x, ...) or x.sum(...); None for builtins."""
     name = call_name(node) or ""
-    if isinstance(node.func, ast.Attribute) and not name.startswith(
-            ("jnp.", "np.", "jax.", "lax.", "numpy.")):
-        # method form: x.sum(axis=1) / x.sum(1)
-        if not (has_axis or node.args):
-            return None
-        return node.func.value
-    if not (has_axis or len(node.args) >= 2):
-        return None
+    if not isinstance(node.func, ast.Attribute):
+        return None                       # builtin max(a, b) / min(a, b)
+    if not name.startswith(("jnp.", "np.", "jax.", "lax.", "numpy.")):
+        return node.func.value            # method form: x.sum(axis=1)
     return node.args[0] if node.args else None
+
+
+def _cast(node: ast.Call) -> Optional[Tuple[ast.AST, str]]:
+    """(operand, target kind) of x.astype(T) / convert_element_type(x, T)."""
+    last = _callee(node)
+    if last == "astype" and isinstance(node.func, ast.Attribute) and node.args:
+        return node.func.value, _dtype_kind(node.args[0])
+    if last == "convert_element_type" and len(node.args) == 2:
+        return node.args[0], _dtype_kind(node.args[1])
+    return None
+
+
+def kernel_helpers(mod: Module, fn: ast.FunctionDef) -> List[ast.FunctionDef]:
+    """`fn` plus the module's top-level functions it calls, transitively:
+    their bodies run inside the kernel too."""
+    top = {n.name: n for n in mod.tree.body if isinstance(n, ast.FunctionDef)}
+    out, todo = [], [fn]
+    while todo:
+        f = todo.pop()
+        if any(f is g for g in out):
+            continue
+        out.append(f)
+        for node in ast.walk(f):
+            if (isinstance(node, ast.Call) and isinstance(node.func, ast.Name)
+                    and node.func.id in top):
+                todo.append(top[node.func.id])
+    return out
 
 
 def check_kernel_mosaic(mod: Module, fn: ast.FunctionDef) -> List[Finding]:
     flow = _DtypeFlow()
     flow.run(fn)
     findings: List[Finding] = []
+
+    def flag(node: ast.AST, message: str) -> None:
+        findings.append(Finding(rule=RULE_MOSAIC, path=mod.path,
+                                line=node.lineno, col=node.col_offset,
+                                message=message + " (DESIGN.md §3)"))
+
     for node in ast.walk(fn):
         if not isinstance(node, ast.Call):
             continue
-        name = call_name(node) or ""
-        if name.rpartition(".")[2] not in _REDUCERS:
+        cast = _cast(node)
+        if cast is not None:
+            src, dst = flow.infer(cast[0]), cast[1]
+            if {src, dst} == {UINT, FLOAT}:
+                flag(node, f"{src} -> {dst} cast inside a Pallas kernel — "
+                           f"Mosaic refuses casts between unsigned ints and "
+                           f"floats; go through int32")
+            continue
+        if _callee(node) not in _REDUCERS:
             continue
         operand = _reduction_operand(node)
-        if operand is None:
-            continue
-        kind = flow.infer(operand)
-        if kind in (INT, BOOL):
-            findings.append(Finding(
-                rule=RULE_MOSAIC, path=mod.path, line=node.lineno,
-                col=node.col_offset,
-                message=(f"{kind}-dtype axis reduction inside a Pallas "
-                         f"kernel body — Mosaic rejects integer-axis "
-                         f"reductions; accumulate in f32 (exact below 2^24) "
-                         f"and cast back (DESIGN.md §3)")))
+        if operand is not None and flow.infer(operand) == UINT:
+            flag(node, "reduction over unsigned ints inside a Pallas kernel "
+                       "— Mosaic refuses it; cast the counts to int32 first "
+                       "(int32 reduces over any axis)")
     return findings
 
 
@@ -483,13 +555,13 @@ def check_scratch_shapes(mod: Module) -> List[Finding]:
 def check(index: PackageIndex) -> List[Finding]:
     findings: List[Finding] = []
     for mod in index:
-        seen = set()
-        for fn, kinds in find_kernels(mod):
-            if id(fn) in seen:
-                continue
-            seen.add(id(fn))
+        kernels = {id(fn): (fn, kinds) for fn, kinds in find_kernels(mod)}
+        bodies = {}                   # kernel bodies + the helpers they call
+        for fn, kinds in kernels.values():
             findings.extend(check_kernel_vmap_safety(mod, fn, kinds))
-            findings.extend(check_kernel_mosaic(mod, fn))
+            bodies.update((id(f), f) for f in kernel_helpers(mod, fn))
+        for f in bodies.values():
+            findings.extend(check_kernel_mosaic(mod, f))
         findings.extend(check_blockspecs(mod))
         findings.extend(check_scratch_shapes(mod))
     return findings

@@ -8,7 +8,7 @@ Deployment model for 1000+ nodes (DESIGN.md §5–§6):
   without work-stealing, which SPMD forbids; instead we over-decompose).
 * **Streaming ingest**: the driver consumes `RootBucket`s from a
   `PrepStream` as the host packs them, and runs **double-buffered**: chunk
-  *k* is dispatched asynchronously (device buffers donated), then the host
+  *k* is dispatched asynchronously, then the host
   packs and uploads chunk *k+1* while the device works, and only then
   blocks on chunk *k*'s counters. The host never sits between the device
   and its next batch; `stats` records how much packing was hidden.
@@ -45,7 +45,6 @@ from repro.core.engine import (BACKENDS, EngineConfig, MCEResult,
                                root_cost_skew, run_bucket_persistent,
                                run_root)
 from repro.graph.csr import CSRGraph
-from repro.sharding.compat import shard_map
 
 # "truncated" folds each chunk's iters-exhausted flags so a max_iters cutoff
 # surfaces as MCEResult.iters_exhausted instead of silently partial counts.
@@ -157,36 +156,19 @@ def _sharded_counts_impl(a, p0, xr, xa, rz, cfg: EngineConfig, mesh: Mesh,
 
     specs_in = (P(axis), P(axis), P(axis), P(axis), P(axis))
     specs_out = {k: P(axis) for k in COUNTER_KEYS}
-    fn = shard_map(per_shard, mesh=mesh, in_specs=specs_in,
-                   out_specs=specs_out, check_vma=False)
+    fn = jax.shard_map(per_shard, mesh=mesh, in_specs=specs_in,
+                       out_specs=specs_out, check_vma=False)
     out = fn(a, p0, xr, xa, rz)
     return {k: jnp.sum(v) for k, v in out.items()}
 
 
-# Chunk buffers are fresh device_puts the driver never reuses, so on real
-# accelerators they are donated: engine scratch aliases them instead of
-# growing the footprint while the next chunk's upload is in flight (double
-# buffering). Donation is a no-op on CPU (and warns per compile), and the
-# backend must not be probed at import time (a 1000-node launcher calls
-# jax.distributed.initialize() after importing this module) — so the
-# variant is chosen lazily at the first call.
-_sharded_counts_donated = partial(jax.jit,
-                                  static_argnames=("cfg", "mesh", "axis",
-                                                   "engine", "lanes"),
-                                  donate_argnums=(0, 1, 2, 3, 4))(
-    _sharded_counts_impl)
-_sharded_counts_plain = partial(jax.jit,
-                                static_argnames=("cfg", "mesh", "axis",
-                                                 "engine", "lanes"))(
-    _sharded_counts_impl)
-
-
-def _sharded_counts(a, p0, xr, xa, rz, cfg: EngineConfig, mesh: Mesh, axis,
-                    engine: str = "perroot", lanes: int = 64):
-    fn = (_sharded_counts_plain if jax.default_backend() == "cpu"
-          else _sharded_counts_donated)
-    return fn(a, p0, xr, xa, rz, cfg=cfg, mesh=mesh, axis=axis,
-              engine=engine, lanes=lanes)
+# One jitted program per (chunk shape, cfg, engine, lanes). Its inputs are
+# not donated: the step returns only scalar counters, so no output could
+# alias a chunk buffer, and the driver drops each chunk's arrays after the
+# dispatch anyway.
+_sharded_counts = partial(jax.jit,
+                          static_argnames=("cfg", "mesh", "axis", "engine",
+                                           "lanes"))(_sharded_counts_impl)
 
 
 @dataclasses.dataclass
@@ -246,8 +228,6 @@ class DistributedMCE:
         self.engine = engine
         self.lanes = lanes
         if mesh is None:
-            # no axis_types kwarg: Auto is the default and the kwarg does
-            # not exist on jax 0.4.x
             mesh = jax.make_mesh((len(jax.devices()),), ("data",))
             axis = "data"
         self.mesh = mesh
@@ -261,6 +241,7 @@ class DistributedMCE:
                       "dispatch_s": 0.0, "device_wait_s": 0.0, "chunks": 0,
                       "engine_choices": {"perroot": 0, "persistent": 0}}
         self.last_counters: dict = {}   # COUNTER_KEYS of the last run()
+        self._last_step = None          # (arg shapes, engine kwargs)
         self.prep: Optional[PreparedMCE] = None
         self.stream: Optional[PrepStream] = None
         if prep is not None and g is not None:
@@ -410,7 +391,18 @@ class DistributedMCE:
         a, p0, xr, xa, rz = (jax.device_put(t, sharding) for t in stacked)
         out = _sharded_counts(a, p0, xr, xa, rz, self.cfg, self.mesh,
                               self.axis, engine=engine, lanes=lanes)
+        self._last_step = (
+            tuple(jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding)
+                  for x in (a, p0, xr, xa, rz)),
+            dict(engine=engine, lanes=lanes))
         return out, n_pad
+
+    def compiled_step(self):
+        """The compiled program of the last chunk step this driver ran, to
+        inspect (`as_text()`, `input_shardings`); compiles it again."""
+        shapes, kw = self._last_step
+        return _sharded_counts.lower(*shapes, cfg=self.cfg, mesh=self.mesh,
+                                     axis=self.axis, **kw).compile()
 
     def _settle(self, pending, state: DriverCheckpoint) -> None:
         """Block on a dispatched chunk, fold counters, checkpoint cursor."""

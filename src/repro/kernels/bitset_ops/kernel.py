@@ -30,12 +30,20 @@ interpret-mode tests:
   state goes wrong silently. Each grid step writes only its own block;
   cross-tile reductions happen in jnp outside the `pallas_call`.
   Enforced by the vmap parity tests in tests/test_bitset_ops_dispatch.py.
-* **Mosaic-lowerable shapes/ops.** Word-axis popcount sums accumulate in
-  float32 (Mosaic has no integer-axis reductions; exact for counts < 2^24,
-  i.e. any W < 2^19) and every block keeps its last two dims (8, 128)-
-  divisible or equal to the full array dims. Enforced without hardware by
-  tests/test_kernels_tpu_lowering.py, which lowers every kernel (plain and
-  vmapped) for a TPU target via jax.export.
+* **Mosaic-compilable shapes/ops.** The installed Mosaic (jax 0.9.0)
+  refuses casts between uint32 and float32, reductions over unsigned
+  ints, and a float iota; it accepts uint32 <-> int32 and int32 ->
+  float32 casts and reduces int32 over either axis. So every popcount
+  leaves the unsigned domain as int32 (`_popcount`) and all counts, sums
+  and index selections stay in int32. Every block keeps its
+  last two dims (8, 128)-divisible or equal to the full array dims.
+  Enforced without hardware by tests/test_kernels_tpu_lowering.py, which
+  compiles every kernel (plain and vmapped, at the engine's bucket widths)
+  for a described TPU v5e, and by mce_lint R3.
+
+Every entry point runs the compiled kernel unless its caller passes
+`interpret=True` (the CPU parity tests do); each `pallas_call` carries a
+stable `name`, which is how a compiled step's kernels are identified.
 
 These kernels exist because the ops execute once per BK tree node over the
 whole row matrix — the paper's measurement that set intersections are 73.6%
@@ -55,18 +63,28 @@ DEFAULT_BLOCK_K = 256
 DEFAULT_BLOCK_M = 256
 
 
+def _popcount(x):
+    """Per-word set-bit count as int32. Mosaic refuses uint32 -> float32
+    casts and unsigned reductions, so every count leaves the unsigned
+    domain through int32 first."""
+    return jax.lax.population_count(x).astype(jnp.int32)
+
+
+def _pcsum(x):
+    """(R, W) uint32 -> (R, 1) int32 row popcounts, summed in int32."""
+    return jnp.sum(_popcount(x), axis=1, keepdims=True)
+
+
 def _and_popcount_kernel(rows_ref, mask_ref, out_ref):
     rows = rows_ref[...]                      # (BK, W) uint32
     mask = mask_ref[...]                      # (1, W) uint32
-    anded = jnp.bitwise_and(rows, mask)
-    pc = jax.lax.population_count(anded).astype(jnp.float32)
-    out_ref[...] = jnp.sum(pc, axis=1, keepdims=True).astype(jnp.int32)
+    out_ref[...] = _pcsum(jnp.bitwise_and(rows, mask))
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
 def and_popcount_rows(rows: jnp.ndarray, mask: jnp.ndarray,
                       block_k: int = DEFAULT_BLOCK_K,
-                      interpret: bool = True) -> jnp.ndarray:
+                      interpret: bool = False) -> jnp.ndarray:
     """Pallas path. rows: (K, W) uint32, mask: (W,) uint32 -> (K,) int32."""
     k, w = rows.shape
     bk = min(block_k, k)
@@ -84,6 +102,7 @@ def and_popcount_rows(rows: jnp.ndarray, mask: jnp.ndarray,
             pl.BlockSpec((1, w), lambda i: (0, 0)),       # mask replicated
         ],
         out_specs=pl.BlockSpec((bk, 1), lambda i: (i, 0)),
+        name="and_popcount_rows",
         interpret=interpret,
     )(rows, mask[None, :])
     return out[:k, 0]
@@ -93,9 +112,7 @@ def _and_popcount_argmax_kernel(rows_ref, mask_ref, valid_ref, scores_ref):
     rows = rows_ref[...]                      # (BK, W) uint32
     mask = mask_ref[...]                      # (1, W) uint32
     valid = valid_ref[...]                    # (BK, 1) int32 (0/1)
-    pc = jax.lax.population_count(jnp.bitwise_and(rows, mask))
-    counts = jnp.sum(pc.astype(jnp.float32), axis=1,
-                     keepdims=True).astype(jnp.int32)   # (BK, 1)
+    counts = _pcsum(jnp.bitwise_and(rows, mask))        # (BK, 1)
     scores_ref[...] = jnp.where(valid != 0, counts, jnp.int32(-1))
 
 
@@ -103,7 +120,7 @@ def _and_popcount_argmax_kernel(rows_ref, mask_ref, valid_ref, scores_ref):
 def and_popcount_argmax(rows: jnp.ndarray, mask: jnp.ndarray,
                         valid: jnp.ndarray,
                         block_k: int = DEFAULT_BLOCK_K,
-                        interpret: bool = True):
+                        interpret: bool = False):
     """Fused pivot-select. rows: (K, W) uint32, mask: (W,) uint32,
     valid: (K,) bool -> (idx int32, best int32) with invalid rows scoring -1.
 
@@ -133,6 +150,7 @@ def and_popcount_argmax(rows: jnp.ndarray, mask: jnp.ndarray,
             pl.BlockSpec((bk, 1), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((bk, 1), lambda i: (i, 0)),
+        name="and_popcount_argmax",
         interpret=interpret,
     )(rows, mask[None, :], valid_i[:, None])[:k, 0]
     return jnp.argmax(scores).astype(jnp.int32), jnp.max(scores)
@@ -151,22 +169,20 @@ def _frame_step_kernel(rows_ref, p_ref, xp_ref, wrow_ref,
     childp_ref[...] = childp
     childxp_ref[...] = jnp.bitwise_and(xp, wrow)
     anded = jnp.bitwise_and(rows, childp)
-    pc = jax.lax.population_count(anded).astype(jnp.float32)
-    deg_ref[...] = jnp.sum(pc, axis=1, keepdims=True).astype(jnp.int32)
+    deg_ref[...] = _pcsum(anded)
     # per-word lowest-set-bit position; summed contributions are exact when
     # exactly one bit survives (the Lemma-7 partner), garbage otherwise
     low = jnp.bitwise_and(anded, jnp.uint32(0) - anded)
-    pos = jax.lax.population_count(low - jnp.uint32(1)).astype(jnp.float32)
-    wi = jax.lax.broadcasted_iota(jnp.float32, anded.shape, 1) * 32.0
-    contrib = jnp.where(anded != 0, wi + pos, 0.0)
-    partner_ref[...] = jnp.sum(contrib, axis=1,
-                               keepdims=True).astype(jnp.int32)
+    pos = _popcount(low - jnp.uint32(1))
+    wi = jax.lax.broadcasted_iota(jnp.int32, anded.shape, 1) * 32
+    contrib = jnp.where(anded != 0, wi + pos, 0)
+    partner_ref[...] = jnp.sum(contrib, axis=1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
 def frame_step(rows: jnp.ndarray, p: jnp.ndarray, xp: jnp.ndarray,
                wrow: jnp.ndarray, block_k: int = DEFAULT_BLOCK_K,
-               interpret: bool = True):
+               interpret: bool = False):
     """Fused BK frame step (see ref.frame_step for the contract).
 
     rows: (K, W) uint32, p/xp/wrow: (W,) uint32 ->
@@ -199,6 +215,7 @@ def frame_step(rows: jnp.ndarray, p: jnp.ndarray, xp: jnp.ndarray,
                    pl.BlockSpec((1, w), lambda i: (0, 0)),
                    pl.BlockSpec((bk, 1), lambda i: (i, 0)),
                    pl.BlockSpec((bk, 1), lambda i: (i, 0))),
+        name="frame_step",
         interpret=interpret,
     )(rows, p[None, :], xp[None, :], wrow[None, :])
     return childp[0], childxp[0], deg[:k, 0], partner[:k, 0]
@@ -210,21 +227,18 @@ def _clique_counts_kernel(rows_ref, mask_ref, inp_ref, inx_ref,
     mask = mask_ref[...]                      # (1, W) uint32
     inp = inp_ref[...]                        # (BK, 1) int32 (0/1)
     inx = inx_ref[...]                        # (BK, 1) int32 (0/1)
-    anded = jnp.bitwise_and(rows, mask)
-    pc = jnp.sum(jax.lax.population_count(anded).astype(jnp.float32),
-                 axis=1, keepdims=True)       # (BK, 1) f32 (exact < 2^24)
-    msize = jnp.sum(jax.lax.population_count(mask).astype(jnp.float32),
-                    axis=1, keepdims=True)    # (1, 1)
+    pc = _pcsum(jnp.bitwise_and(rows, mask))    # (BK, 1)
+    msize = _pcsum(mask)                        # (1, 1)
     # per-row 0/1 flags; the two scalar counts reduce in jnp outside the
     # pallas_call (batch-safety: each grid step writes only its own block)
-    full_ref[...] = ((inp != 0) & (pc == msize - 1.0)).astype(jnp.int32)
+    full_ref[...] = ((inp != 0) & (pc == msize - 1)).astype(jnp.int32)
     dom_ref[...] = ((inx != 0) & (pc == msize)).astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
 def clique_counts(rows: jnp.ndarray, mask: jnp.ndarray, in_p: jnp.ndarray,
                   in_x: jnp.ndarray, block_k: int = DEFAULT_BLOCK_K,
-                  interpret: bool = True):
+                  interpret: bool = False):
     """Fused early-termination census (see ref.clique_counts for the
     contract). rows: (K, W) uint32, mask: (W,) uint32, in_p/in_x: (K,) bool
     -> (n_full, n_dom) int32 scalars.
@@ -258,6 +272,7 @@ def clique_counts(rows: jnp.ndarray, mask: jnp.ndarray, in_p: jnp.ndarray,
         ],
         out_specs=(pl.BlockSpec((bk, 1), lambda i: (i, 0)),
                    pl.BlockSpec((bk, 1), lambda i: (i, 0))),
+        name="clique_counts",
         interpret=interpret,
     )(rows, mask[None, :], inp_i[:, None], inx_i[:, None])
     return (jnp.sum(full[:k, 0]).astype(jnp.int32),
@@ -268,8 +283,7 @@ def _and_popcount_many_kernel(rows_ref, masks_ref, out_ref):
     rows = rows_ref[...]                      # (BK, W) uint32
     masks = masks_ref[...]                    # (BM, W) uint32
     anded = jnp.bitwise_and(rows[None, :, :], masks[:, None, :])
-    pc = jax.lax.population_count(anded).astype(jnp.float32)
-    out_ref[...] = jnp.sum(pc, axis=2).astype(jnp.int32)
+    out_ref[...] = jnp.sum(_popcount(anded), axis=2)
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_k",
@@ -277,7 +291,7 @@ def _and_popcount_many_kernel(rows_ref, masks_ref, out_ref):
 def and_popcount_many(rows: jnp.ndarray, masks: jnp.ndarray,
                       block_m: int = DEFAULT_BLOCK_M,
                       block_k: int = DEFAULT_BLOCK_K,
-                      interpret: bool = True) -> jnp.ndarray:
+                      interpret: bool = False) -> jnp.ndarray:
     """Batched-mask path. rows: (K, W), masks: (M, W) -> (M, K) int32
     with out[m, k] = popcount(rows[k] & masks[m])."""
     k, w = rows.shape
@@ -285,8 +299,8 @@ def and_popcount_many(rows: jnp.ndarray, masks: jnp.ndarray,
     assert w == wm, f"word-width mismatch {w} vs {wm}"
     bk = min(block_k, k)
     bm = min(block_m, m)
-    # VMEM budget: the kernel body materialises (BM, BK, W) uint32 + f32
-    # intermediates (8 B/elem); cap the tile at ~4 MiB so wide-W buckets
+    # VMEM budget: the kernel body materialises (BM, BK, W) uint32 words +
+    # int32 counts (8 B/elem); cap the tile at ~4 MiB so wide-W buckets
     # (e.g. W=32 at 256×256 blocks) don't blow VMEM on the compiled path.
     # Shrink bm first (Mosaic needs a shrunk second-minor block dim to stay
     # 8-divisible), then bk in 128-lane multiples (the out block's last dim
@@ -313,6 +327,7 @@ def and_popcount_many(rows: jnp.ndarray, masks: jnp.ndarray,
             pl.BlockSpec((bm, w), lambda i, j: (i, 0)),
         ],
         out_specs=pl.BlockSpec((bm, bk), lambda i, j: (i, j)),
+        name="and_popcount_many",
         interpret=interpret,
     )(rows, masks)
     return out[:m, :k]
@@ -339,21 +354,16 @@ def _window_walk(a, xr, eye, alive0, read_a, read_x,
     `a`/`xr`/`eye`/`alive0` are the materialized per-invocation constants;
     `read_a(i)`/`read_x(i)` load one (1, W) row via a ref dynamic slice
     (the per-root and lane-batched kernels differ only in ref rank, which
-    these closures absorb). Every reduction accumulates in f32 (Mosaic has
-    no integer-axis reductions; counts < 2^24 are exact) and
-    argmax/first-bit selections use the f32 min/max-of-masked-iota idiom
-    so tie-breaking matches jnp.argmax (first occurrence wins)
-    bit-for-bit. Returns the final (dloc, done, calls, branches, sum_px,
-    cliques, steps_done) state."""
-    big = jnp.float32(1e9)
-    iw_f = jax.lax.broadcasted_iota(jnp.float32, (1, w), 1)
-    iw_i = jax.lax.broadcasted_iota(jnp.int32, (1, w), 1)
-    iu_f = jax.lax.broadcasted_iota(jnp.float32, (u, 1), 0)
-    ix_f = jax.lax.broadcasted_iota(jnp.float32, (xc, 1), 0)
-
-    def pcsum(x):
-        return jnp.sum(jax.lax.population_count(x).astype(jnp.float32),
-                       axis=1, keepdims=True)
+    these closures absorb). Every count and selection stays in int32:
+    Mosaic has no float iota and no uint32 -> float32 cast, and it reduces
+    int32 over either axis. Argmax/first-bit selections use the
+    min-of-masked-iota idiom so tie-breaking matches jnp.argmax (first
+    occurrence wins) bit-for-bit. Returns the final (dloc, done, calls,
+    branches, sum_px, cliques, steps_done) state."""
+    big = jnp.int32(1 << 30)
+    iw = jax.lax.broadcasted_iota(jnp.int32, (1, w), 1)
+    iu = jax.lax.broadcasted_iota(jnp.int32, (u, 1), 0)
+    ix = jax.lax.broadcasted_iota(jnp.int32, (xc, 1), 0)
 
     def step(_, s):
         dl, done, calls, branches, spx, clq, sdone = s
@@ -363,19 +373,17 @@ def _window_walk(a, xr, eye, alive0, read_a, read_x,
         fXp = sxp_ref[pl.ds(d, 1), :w]
         fRb = srb_ref[pl.ds(d, 1), :w]
         frsz = srsz_ref[d]
-        has_branch = jnp.max(jnp.where(fB != 0, 1.0, 0.0)) > 0.5
+        has_branch = jnp.max(jnp.where(fB != 0, 1, 0)) > 0
         blocked = has_branch & (dl >= t - 1)
         act = (done == 0) & ~blocked & (dl >= 0)
         done = jnp.where(blocked | (dl < 0), jnp.int32(1), done)
 
-        # first set bit of B: per-word low-bit position, f32 min over words
+        # first set bit of B: per-word low-bit position, min over words
         low = jnp.bitwise_and(fB, jnp.uint32(0) - fB)
-        pos = jax.lax.population_count(
-            low - jnp.uint32(1)).astype(jnp.float32)
-        cand = jnp.where(fB != 0, iw_f * 32.0 + pos, big)
-        wv = jnp.clip(jnp.min(cand), 0.0,
-                      jnp.float32(u - 1)).astype(jnp.int32)
-        wbit = jnp.where(iw_i == wv // 32,
+        pos = _popcount(low - jnp.uint32(1))
+        cand = jnp.where(fB != 0, iw * 32 + pos, big)
+        wv = jnp.clip(jnp.min(cand), 0, u - 1)
+        wbit = jnp.where(iw == wv // 32,
                          jnp.uint32(1) << (wv % 32).astype(jnp.uint32),
                          jnp.uint32(0))
         wrow = read_a(wv)
@@ -383,42 +391,38 @@ def _window_walk(a, xr, eye, alive0, read_a, read_x,
         childXp = jnp.bitwise_and(fXp, wrow)
         childRb = jnp.bitwise_or(fRb, wbit)
 
-        deg = pcsum(jnp.bitwise_and(a, childP))            # (u, 1)
+        deg = _pcsum(jnp.bitwise_and(a, childP))           # (u, 1)
         # gather-free P ∪ X membership: one-hot rows AND the member bitset
-        inpool = pcsum(jnp.bitwise_and(
-            eye, jnp.bitwise_or(childP, childXp))) > 0.5
-        pcx = pcsum(jnp.bitwise_and(xr, childP))           # (xc, 1)
-        # closed-form alive set from Rb (see ref.dfs_step_window); pcsum
-        # of x&Rb never exceeds |Rb|, so >= |Rb|−0.5 is exactly ==
-        pc_rb = jnp.sum(jax.lax.population_count(
-            childRb).astype(jnp.float32))
+        inpool = _pcsum(jnp.bitwise_and(
+            eye, jnp.bitwise_or(childP, childXp))) > 0
+        pcx = _pcsum(jnp.bitwise_and(xr, childP))          # (xc, 1)
+        # closed-form alive set from Rb (see ref.dfs_step_window)
+        pc_rb = jnp.sum(_pcsum(childRb))
         alive = jnp.where(
-            (alive0 > 0.5) & (pcsum(jnp.bitwise_and(xr, childRb))
-                              >= pc_rb - 0.5), 1.0, 0.0)
+            (alive0 > 0) & (_pcsum(jnp.bitwise_and(xr, childRb)) == pc_rb),
+            1, 0)
 
         # enter_call, restricted: counts + leaf report + pivot branch set
         en = act & has_branch
         en_i = en.astype(jnp.int32)
         branches = branches + en_i
         calls = calls + en_i
-        pc_p = jnp.sum(jax.lax.population_count(
-            childP).astype(jnp.float32))
-        pc_x = jnp.sum(jax.lax.population_count(
-            childXp).astype(jnp.float32))
+        pc_p = jnp.sum(_pcsum(childP))
+        pc_x = jnp.sum(_pcsum(childXp))
         nal = jnp.sum(alive)
-        spx = spx + (pc_p + pc_x + nal).astype(jnp.int32) * en_i
-        p_empty = pc_p < 0.5
-        x_empty = (nal < 0.5) & (pc_x < 0.5)
+        spx = spx + (pc_p + pc_x + nal) * en_i
+        p_empty = pc_p == 0
+        x_empty = (nal == 0) & (pc_x == 0)
         crsz = frsz + 1
         clq = clq + (p_empty & x_empty & (crsz >= 2) & en).astype(jnp.int32)
         push = ~p_empty & en
 
-        su_s = jnp.where(inpool, deg, -1.0)
+        su_s = jnp.where(inpool, deg, -1)
         su = jnp.max(su_s)
-        best_u = jnp.min(jnp.where(su_s == su, iu_f, big)).astype(jnp.int32)
-        sx_s = jnp.where(alive > 0.5, pcx, -1.0)
+        best_u = jnp.min(jnp.where(su_s == su, iu, big))
+        sx_s = jnp.where(alive > 0, pcx, -1)
         sx = jnp.max(sx_s)
-        best_x = jnp.min(jnp.where(sx_s == sx, ix_f, big)).astype(jnp.int32)
+        best_x = jnp.min(jnp.where(sx_s == sx, ix, big))
         use_x = sx > su
         rowu = read_a(best_u)
         rowx = read_x(jnp.clip(best_x, 0, xc - 1))
@@ -480,7 +484,7 @@ def _dfs_step_window_kernel(a_ref, xr_ref, eye_ref, alive_ref,
     for i in range(t):
         srsz_ref[i] = winrsz_ref[0, i]
     s = _window_walk(a_ref[...], xr_ref[...], eye_ref[...],
-                     alive_ref[...].astype(jnp.float32),
+                     alive_ref[...],
                      lambda i: a_ref[pl.ds(i, 1), :],
                      lambda i: xr_ref[pl.ds(i, 1), :],
                      sp_ref, sb_ref, sxp_ref, srb_ref, srsz_ref,
@@ -533,7 +537,7 @@ def _dfs_step_window_lanes_kernel(a_ref, xr_ref, eye_ref, alive_ref,
     for i in range(t):
         srsz_ref[i] = winrsz_ref[0, 0, i]
     s = _window_walk(a_ref[0], xr_ref[0], eye_ref[...],
-                     alive_ref[0].astype(jnp.float32),
+                     alive_ref[0],
                      lambda i: a_ref[0, pl.ds(i, 1), :],
                      lambda i: xr_ref[0, pl.ds(i, 1), :],
                      sp_ref, sb_ref, sxp_ref, srb_ref, srsz_ref,
@@ -561,7 +565,7 @@ def dfs_step_window(a: jnp.ndarray, x_rows: jnp.ndarray, eye: jnp.ndarray,
                     winB: jnp.ndarray, winXp: jnp.ndarray,
                     winRb: jnp.ndarray, winrsz: jnp.ndarray,
                     dloc: jnp.ndarray, steps: int = 16,
-                    interpret: bool = True):
+                    interpret: bool = False):
     """Pallas path for ref.dfs_step_window (same contract).
 
     The (T, W) window frames are copied into VMEM scratch once, mutated
@@ -601,6 +605,7 @@ def dfs_step_window(a: jnp.ndarray, x_rows: jnp.ndarray, eye: jnp.ndarray,
             pltpu.VMEM((8, 128), jnp.uint32),
             pltpu.SMEM((8,), jnp.int32),
         ],
+        name="dfs_step_window",
         interpret=interpret,
     )(a, x_rows, eye, alive0.astype(jnp.int32)[:, None], winP, winB,
       winXp, winRb, winrsz.astype(jnp.int32)[None],
@@ -615,7 +620,7 @@ def dfs_step_window_lanes(a: jnp.ndarray, x_rows: jnp.ndarray,
                           winP: jnp.ndarray, winB: jnp.ndarray,
                           winXp: jnp.ndarray, winRb: jnp.ndarray,
                           winrsz: jnp.ndarray, dloc: jnp.ndarray,
-                          steps: int = 16, interpret: bool = True):
+                          steps: int = 16, interpret: bool = False):
     """Pallas path for ref.dfs_step_window_lanes (same contract).
 
     The grid runs over lanes: each grid step walks one lane's window for
@@ -671,6 +676,7 @@ def dfs_step_window_lanes(a: jnp.ndarray, x_rows: jnp.ndarray,
             pltpu.VMEM((8, 128), jnp.uint32),
             pltpu.SMEM((8,), jnp.int32),
         ],
+        name="dfs_step_window_lanes",
         interpret=interpret,
     )(a, x_rows, eye, alive0.astype(jnp.int32)[..., None], winP, winB,
       winXp, winRb, winrsz.astype(jnp.int32)[:, None, :],

@@ -62,12 +62,8 @@ COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
 
 
 def xla_cost_analysis(compiled) -> Dict[str, float]:
-    """Normalise ``compiled.cost_analysis()`` across jax versions: newer jax
-    returns a dict, 0.4.x returns a list with one dict per program."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
+    """``compiled.cost_analysis()`` as a dict (empty when XLA gives none)."""
+    return compiled.cost_analysis() or {}
 
 
 def shape_elems_bytes(shape_str: str) -> Tuple[int, int]:

@@ -25,6 +25,7 @@ import time
 from repro.core.engine import EngineConfig
 from repro.core.driver import DistributedMCE
 from repro.graph import generators as gen
+from repro.launch import compile_cache
 
 
 def _num(v: str):
@@ -113,6 +114,7 @@ def main() -> None:
                          "windows every config (fused kernel when "
                          "eligible, windowed dfs_step otherwise)")
     args = ap.parse_args()
+    compile_cache.enable()
 
     g = parse_graph(args.graph)
     print(f"graph: n={g.n} m={g.m}")

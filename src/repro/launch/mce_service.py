@@ -53,6 +53,7 @@ class MCEService:
         self.engine = engine
         self.lanes = lanes
         self.queries = 0
+        self.last_driver: Optional[DistributedMCE] = None
         self.stats = {"live_iters": 0, "lane_iters": 0, "truncated": 0,
                       "steals": 0, "entry_terms": 0,
                       "window_spills": 0, "window_hits": 0,
@@ -112,6 +113,7 @@ class MCEService:
                              ckpt_path=ckpt_path, cfg=cfg,
                              engine=engine, lanes=lanes, **kwargs)
         res = drv.run(resume=resume)
+        self.last_driver = drv
         self.queries += 1
         delta = {k: int(drv.last_counters.get(k, 0))
                  for k in ("live_iters", "lane_iters", "truncated",
@@ -136,8 +138,10 @@ def main() -> None:
                     choices=["perroot", "persistent", "auto"])
     ap.add_argument("--lanes", type=int, default=64)
     args = ap.parse_args()
+    from repro.launch import compile_cache
     from repro.launch.mce_run import parse_graph
 
+    compile_cache.enable()
     g = parse_graph(args.graph)
     svc = MCEService(g, chunk=args.chunk, engine=args.engine,
                      lanes=args.lanes)

@@ -24,7 +24,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.models import transformer as T
 from repro.models import layers as L
-from repro.sharding.compat import shard_map
 
 
 def stack_stages(layer_params: dict, n_stages: int) -> dict:
@@ -93,7 +92,7 @@ def pipeline_forward(cfg: T.TransformerConfig, params, tokens, *,
         return out
 
     specs_layers = jax.tree.map(lambda _: P(pp_axis), params["layers"])
-    pipe_fn = shard_map(
+    pipe_fn = jax.shard_map(
         pipe, mesh=mesh, in_specs=(specs_layers, P()), out_specs=P(),
         check_vma=False)
     y = pipe_fn(params["layers"], x_mbs)

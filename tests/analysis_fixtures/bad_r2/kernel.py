@@ -20,7 +20,7 @@ def _pivot_kernel(rows_ref, mask_ref, best_ref):
         best_ref[...] = jnp.zeros_like(best_ref)            # EXPECT-R2
 
     anded = rows_ref[...] & mask_ref[...]
-    pc = jax.lax.population_count(anded).astype(jnp.float32)
+    pc = jax.lax.population_count(anded).astype(jnp.int32)
     score = jnp.sum(pc, axis=1, keepdims=True)
     best_ref[...] = jnp.maximum(best_ref[...], score)       # EXPECT-R2
 
@@ -32,6 +32,6 @@ def pivot_scores(rows, mask):
         grid=(k // 8,),
         in_specs=[pl.BlockSpec((8, w), lambda i: (i, 0)),
                   pl.BlockSpec((1, w), lambda i: (0, 0))],
-        out_shape=jax.ShapeDtypeStruct((1, 128), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((1, 128), jnp.int32),
         out_specs=pl.BlockSpec((1, 128), lambda i: (0, 0)),
     )(rows, mask)

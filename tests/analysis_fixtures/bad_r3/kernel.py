@@ -1,6 +1,6 @@
-"""R3 bad fixture: integer-axis reduction + misaligned literal BlockSpec.
+"""R3 bad fixture: unsigned reduction + misaligned literal BlockSpec.
 
-Mosaic rejects integer-dtype axis reductions (`jnp.sum` on the int32
+Mosaic refuses reductions over unsigned ints (`jnp.sum` on the uint32
 popcount output) and block shapes whose trailing dims are neither
 (8, 128)-multiples nor equal to the array dims.
 """

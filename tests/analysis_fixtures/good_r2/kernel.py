@@ -11,7 +11,7 @@ from jax.experimental import pallas as pl
 
 def _pivot_kernel(rows_ref, mask_ref, score_ref):
     anded = rows_ref[...] & mask_ref[...]
-    pc = jax.lax.population_count(anded).astype(jnp.float32)
+    pc = jax.lax.population_count(anded).astype(jnp.int32)
     score_ref[...] = jnp.sum(pc, axis=1, keepdims=True)
 
 
@@ -22,6 +22,6 @@ def pivot_scores(rows, mask):
         grid=(k // 8,),
         in_specs=[pl.BlockSpec((8, w), lambda i: (i, 0)),
                   pl.BlockSpec((1, w), lambda i: (0, 0))],
-        out_shape=jax.ShapeDtypeStruct((k, 1), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((k, 1), jnp.int32),
         out_specs=pl.BlockSpec((8, 1), lambda i: (i, 0)),
     )(rows, mask)

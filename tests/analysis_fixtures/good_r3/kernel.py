@@ -1,5 +1,6 @@
-"""R3 good twin: f32 accumulation (exact below 2^24), aligned blocks,
-literal (8, 128)-aligned VMEM scratch (SMEM scalar scratch is exempt)."""
+"""R3 good twin: int32 counts (int32 reduces over any axis), aligned
+blocks, literal (8, 128)-aligned VMEM scratch (SMEM scalar scratch is
+exempt)."""
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -8,8 +9,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 def _degree_kernel(rows_ref, mask_ref, deg_ref):
     anded = rows_ref[...] & mask_ref[...]
-    pc = jax.lax.population_count(anded).astype(jnp.float32)
-    deg_ref[...] = jnp.sum(pc, axis=1, keepdims=True).astype(jnp.int32)
+    pc = jax.lax.population_count(anded).astype(jnp.int32)
+    deg_ref[...] = jnp.sum(pc, axis=1, keepdims=True)
 
 
 def degrees(rows, mask):

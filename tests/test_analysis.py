@@ -42,8 +42,8 @@ def _expected(fixture_dir):
     return out
 
 
-BAD = ["bad_r1", "bad_r2", "bad_r3", "bad_r4", "bad_r5"]
-GOOD = ["good_r1", "good_r2", "good_r3", "good_r4", "good_r5"]
+BAD = ["bad_r1", "bad_r2", "bad_r3", "bad_r3_cast", "bad_r4", "bad_r5"]
+GOOD = ["good_r1", "good_r2", "good_r3", "good_r3_cast", "good_r4", "good_r5"]
 
 
 @pytest.mark.parametrize("fixture", BAD)

@@ -142,9 +142,7 @@ def test_ef_int8_allreduce_error_feedback():
     g = dict(w=jnp.asarray(np.linspace(-1, 1, 256), jnp.float32) * 0.01)
     ef = ef_state_init(g)
 
-    from repro.sharding.compat import shard_map
-
-    @partial(shard_map, mesh=mesh, in_specs=(P(), P()),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P(), P()),
              out_specs=(P(), P()), check_vma=False)
     def run(gg, ee):
         return ef_int8_allreduce(gg, ee, axis_name="data")

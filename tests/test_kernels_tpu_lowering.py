@@ -1,61 +1,90 @@
-"""Mosaic lowering smoke tests: export the bitset kernels for a TPU target.
+"""Compile the bitset kernels for a described TPU v5e, without the chip.
 
-Every parity test in this suite runs the Pallas kernels in interpret mode,
-and on this CPU container the compiled (interpret=False) path is otherwise
-never exercised — so the first real TPU run would also be the first compile
-attempt. `jax.export` runs the full Pallas→Mosaic lowering pipeline on any
-host, which catches the failure classes Mosaic actually rejects without
-needing hardware: integer-axis reductions (unimplemented), block shapes
-whose last two dims are neither (8, 128)-divisible nor equal to the array
-dims, and batching-rule breakage under vmap (the engine's real call
-pattern). Numeric parity is covered by the interpret-mode tests; this file
-only asserts the kernels *compile* for TPU, both plain and vmapped.
+Every parity test runs the Pallas kernels in interpret mode, which
+accepts programs the chip's compiler refuses (uint32 -> float32 casts,
+unsigned reductions, misaligned blocks, scratch that overflows VMEM).
+Here each kernel, and the driver's jitted chunk step, goes through the
+real TPU compiler (`jax.jit(...).lower(...).compile()`) against a
+described `v5e:2x2` topology: shapes only, nothing runs. Cases:
+
+* the original shapes, plain and vmapped (the engine's `run_bucket`
+  vmaps `run_root`, so the pallas_calls compile with the batch axis
+  prepended to the grid);
+* every kernel at the engine's real bucket widths: W = U/32 for
+  U in 32..1024 (`configs/rmce.py`), chunks of 1024 roots, the window
+  kernels at WINDOW_FRAMES = 8 and 64 lanes;
+* the chunk step `_sharded_counts` on a one-device mesh at the
+  `web_sparse` and `dense_core` shapes, for each engine path.
+
+The topology is described inside a module fixture (never at import),
+which skips the file where libtpu cannot describe it.
 """
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-try:
-    from jax import export
-except ImportError:                           # pragma: no cover
-    export = None
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.bitset_ops import kernel as bk
 
-pytestmark = pytest.mark.skipif(export is None,
-                                reason="jax.export not available")
+U32, I32, BOOL = jnp.uint32, jnp.int32, jnp.bool_
 
 
-def _rand(shape, seed):
-    return jnp.asarray(np.random.default_rng(seed).integers(
-        0, 2**32, shape, dtype=np.uint32))
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                       # no libtpu here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described device's program cannot be read back from the
+    # persistent cache, so keep these compiles out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield topo
+    jax.config.update("jax_enable_compilation_cache", was)
 
 
-def _lower_tpu(f, *args):
-    exported = export.export(jax.jit(f), platforms=["tpu"])(*args)
-    assert "tpu_custom_call" in exported.mlir_module()
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def S(one_chip):
+    """Shape on the described chip: S(shape, dtype=uint32)."""
+    return lambda shape, dt=U32: jax.ShapeDtypeStruct(shape, dt,
+                                                      sharding=one_chip)
+
+
+def _compile_tpu(f, *args):
+    text = jax.jit(f).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
 
 
 # Default block sizes, K forcing both multi-tile grids and pad remainders.
 K, W, M = 515, 8, 33
 
 
-def test_lower_and_popcount_rows():
-    _lower_tpu(lambda r, m: bk.and_popcount_rows(r, m, interpret=False),
-               _rand((K, W), 0), _rand((W,), 1))
+def test_lower_and_popcount_rows(S):
+    _compile_tpu(lambda r, m: bk.and_popcount_rows(r, m),
+                 S((K, W)), S((W,)))
 
 
-def test_lower_and_popcount_argmax():
-    valid = jnp.asarray(np.random.default_rng(2).random(K) < 0.7)
-    _lower_tpu(
-        lambda r, m, v: bk.and_popcount_argmax(r, m, v, interpret=False),
-        _rand((K, W), 3), _rand((W,), 4), valid)
+def test_lower_and_popcount_argmax(S):
+    _compile_tpu(lambda r, m, v: bk.and_popcount_argmax(r, m, v),
+                 S((K, W)), S((W,)), S((K,), BOOL))
 
 
-def test_lower_and_popcount_many():
-    _lower_tpu(lambda r, ms: bk.and_popcount_many(r, ms, interpret=False),
-               _rand((K, W), 5), _rand((M, W), 6))
+def test_lower_and_popcount_many(S):
+    _compile_tpu(lambda r, ms: bk.and_popcount_many(r, ms),
+                 S((K, W)), S((M, W)))
 
 
 @pytest.mark.parametrize("k,m,w", [
@@ -63,133 +92,192 @@ def test_lower_and_popcount_many():
     (600, 300, 32),               # shrinks bm with multiple k tiles
     (2000, 8, 512),               # bm floor reached, shrinks bk to 128
 ])
-def test_lower_and_popcount_many_vmem_clamp(k, m, w):
+def test_lower_and_popcount_many_vmem_clamp(S, k, m, w):
     """Shapes that trip the VMEM tile clamp must still produce
-    Mosaic-lowerable blocks (shrunk dims 8-/128-divisible or full-array)."""
-    _lower_tpu(lambda r, ms: bk.and_popcount_many(r, ms, interpret=False),
-               _rand((k, w), 14), _rand((m, w), 15))
+    compilable blocks (shrunk dims 8-/128-divisible or full-array)."""
+    _compile_tpu(lambda r, ms: bk.and_popcount_many(r, ms),
+                 S((k, w)), S((m, w)))
 
 
-def test_lower_clique_counts():
-    flags = np.random.default_rng(24).random(K) < 0.5
-    _lower_tpu(
-        lambda r, m, p, x: bk.clique_counts(r, m, p, x, interpret=False),
-        _rand((K, W), 25), _rand((W,), 26),
-        jnp.asarray(flags), jnp.asarray(~flags))
+def test_lower_clique_counts(S):
+    _compile_tpu(lambda r, m, p, x: bk.clique_counts(r, m, p, x),
+                 S((K, W)), S((W,)), S((K,), BOOL), S((K,), BOOL))
 
 
-def test_lower_frame_step():
-    _lower_tpu(lambda r, p, x, wr: bk.frame_step(r, p, x, wr,
-                                                 interpret=False),
-               _rand((K, W), 16), _rand((W,), 17), _rand((W,), 18),
-               _rand((W,), 19))
+def test_lower_frame_step(S):
+    _compile_tpu(lambda r, p, x, wr: bk.frame_step(r, p, x, wr),
+                 S((K, W)), S((W,)), S((W,)), S((W,)))
 
 
-# Vmapped lowering: run_bucket vmaps run_root, so on TPU the pallas_calls
-# compile with the batch axis prepended to the grid — lower exactly that.
+# Vmapped: run_bucket vmaps run_root, so on TPU the pallas_calls compile
+# with the batch axis prepended to the grid — compile exactly that.
 
 B = 3
 
 
-def test_lower_vmapped_and_popcount_rows():
-    _lower_tpu(
-        jax.vmap(lambda r, m: bk.and_popcount_rows(r, m, interpret=False)),
-        _rand((B, K, W), 7), _rand((B, W), 8))
+def test_lower_vmapped_and_popcount_rows(S):
+    _compile_tpu(jax.vmap(bk.and_popcount_rows), S((B, K, W)), S((B, W)))
 
 
-def test_lower_vmapped_and_popcount_argmax():
-    valid = jnp.asarray(np.random.default_rng(9).random((B, K)) < 0.7)
-    _lower_tpu(
-        jax.vmap(lambda r, m, v: bk.and_popcount_argmax(
-            r, m, v, interpret=False)),
-        _rand((B, K, W), 10), _rand((B, W), 11), valid)
+def test_lower_vmapped_and_popcount_argmax(S):
+    _compile_tpu(jax.vmap(bk.and_popcount_argmax),
+                 S((B, K, W)), S((B, W)), S((B, K), BOOL))
 
 
-def test_lower_vmapped_and_popcount_many():
-    _lower_tpu(
-        jax.vmap(lambda r, ms: bk.and_popcount_many(r, ms, interpret=False)),
-        _rand((B, K, W), 12), _rand((B, M, W), 13))
+def test_lower_vmapped_and_popcount_many(S):
+    _compile_tpu(jax.vmap(bk.and_popcount_many), S((B, K, W)), S((B, M, W)))
 
 
-def test_lower_vmapped_clique_counts():
-    flags = np.random.default_rng(27).random((B, K)) < 0.5
-    _lower_tpu(
-        jax.vmap(lambda r, m, p, x: bk.clique_counts(r, m, p, x,
-                                                     interpret=False)),
-        _rand((B, K, W), 28), _rand((B, W), 29),
-        jnp.asarray(flags), jnp.asarray(~flags))
+def test_lower_vmapped_clique_counts(S):
+    _compile_tpu(jax.vmap(bk.clique_counts), S((B, K, W)), S((B, W)),
+                 S((B, K), BOOL), S((B, K), BOOL))
 
 
-def test_lower_vmapped_frame_step():
-    _lower_tpu(
-        jax.vmap(lambda r, p, x, wr: bk.frame_step(r, p, x, wr,
-                                                   interpret=False)),
-        _rand((B, K, W), 20), _rand((B, W), 21), _rand((B, W), 22),
-        _rand((B, W), 23))
+def test_lower_vmapped_frame_step(S):
+    _compile_tpu(jax.vmap(bk.frame_step), S((B, K, W)), S((B, W)),
+                 S((B, W)), S((B, W)))
 
 
 # ---------------------------------------------------------------------------
-# dfs_step_window: the fused VMEM stack-window kernel (plain + vmapped)
+# dfs_step_window / dfs_step_window_lanes: the fused VMEM stack-window
+# kernels (plain + vmapped; eye is shared, in_axes=None)
 # ---------------------------------------------------------------------------
 
-def _window_args(batch=None):
-    """One plausible window invocation (U=64 vertices, 2 words, 8 frames)."""
-    rng = np.random.default_rng(11)
-    u, w, xc, t = 64, 2, 24, 8
-    from repro.core.engine import frames as fr
-    a = _rand((u, w), 11)
-    xr = _rand((xc, w), 12)
-    eye = fr.eye_bits(u, w)
-    alive0 = jnp.asarray((rng.random(xc) < 0.5).astype(np.int32))
-    winP = _rand((t, w), 13)
-    zeros = jnp.zeros((t, w), jnp.uint32)
-    winrsz = jnp.zeros((t,), jnp.int32)
-    dloc = jnp.int32(0)
-    args = (a, xr, eye, alive0, winP, zeros, zeros, zeros, winrsz, dloc)
-    if batch is None:
-        return args
-    return tuple(x if i == 2 else jnp.stack([x] * batch)
-                 for i, x in enumerate(args))
+T = bk.WINDOW_FRAMES
+SHARED_EYE = (0, 0, None, 0, 0, 0, 0, 0, 0, 0)
 
 
-def test_lower_dfs_step_window():
-    _lower_tpu(lambda *a: bk.dfs_step_window(*a, steps=16, interpret=False),
-               *_window_args())
+def _window_shapes(S, u, w, xc, lead=()):
+    """One window invocation's operands; `lead` prefixes every operand
+    but the shared eye (lanes and/or a vmap batch)."""
+    L = tuple(lead)
+    return (S(L + (u, w)), S(L + (xc, w)), S((u, w)), S(L + (xc,), I32),
+            S(L + (T, w)), S(L + (T, w)), S(L + (T, w)), S(L + (T, w)),
+            S(L + (T,), I32), S(L, I32))
 
 
-def test_lower_vmapped_dfs_step_window():
-    # the engine vmaps run_root over a bucket; eye is shared (in_axes=None)
-    f = lambda *a: bk.dfs_step_window(*a, steps=16, interpret=False)
-    _lower_tpu(
-        jax.vmap(f, in_axes=(0, 0, None, 0, 0, 0, 0, 0, 0, 0)),
-        *_window_args(batch=2))
+def _window(steps):
+    return lambda *a: bk.dfs_step_window(*a, steps=steps)
+
+
+def _window_lanes(steps):
+    return lambda *a: bk.dfs_step_window_lanes(*a, steps=steps)
+
+
+def test_lower_dfs_step_window(S):
+    _compile_tpu(_window(16), *_window_shapes(S, 64, 2, 24))
+
+
+def test_lower_vmapped_dfs_step_window(S):
+    _compile_tpu(jax.vmap(_window(16), in_axes=SHARED_EYE),
+                 *_window_shapes(S, 64, 2, 24, lead=(2,)))
+
+
+def test_lower_dfs_step_window_lanes(S):
+    _compile_tpu(_window_lanes(16), *_window_shapes(S, 64, 2, 24, lead=(4,)))
+
+
+def test_lower_vmapped_dfs_step_window_lanes(S):
+    # shard_map/vmap over device shards batches the lane axis
+    _compile_tpu(jax.vmap(_window_lanes(16), in_axes=SHARED_EYE),
+                 *_window_shapes(S, 64, 2, 24, lead=(2, 4)))
 
 
 # ---------------------------------------------------------------------------
-# dfs_step_window_lanes: the grid-over-lanes window kernel the persistent
-# engine dispatches (plain + vmapped, eye shared)
+# Real bucket widths: each kernel as the engine calls it, at every bucket
+# size the service packs (U = 32..1024 vertices, W = U/32 words)
 # ---------------------------------------------------------------------------
 
-def _lanes_args(nlanes=4, batch=None):
-    args = _window_args()
-    lanes = tuple(x if i == 2 else jnp.stack([x] * nlanes)
-                  for i, x in enumerate(args))
-    if batch is None:
-        return lanes
-    return tuple(x if i == 2 else jnp.stack([x] * batch)
-                 for i, x in enumerate(lanes))
+CHUNK = 1024              # roots per chunk step (configs/rmce.py)
+LANES = 64                # persistent-engine lanes (driver default)
+UNIVERSES = (32, 64, 128, 256, 512, 1024)
 
 
-def test_lower_dfs_step_window_lanes():
-    _lower_tpu(
-        lambda *a: bk.dfs_step_window_lanes(*a, steps=16, interpret=False),
-        *_lanes_args())
+def _real_case(name, S, u):
+    """(fn, shapes) of one kernel at universe u: per-root calls vmapped
+    over a chunk of roots, X rows padded to u as in the rmce cells."""
+    w, xc, c = u // 32, u, CHUNK
+    if name == "and_popcount_rows":
+        return jax.vmap(bk.and_popcount_rows), (S((c, u, w)), S((c, w)))
+    if name == "and_popcount_argmax":
+        return (jax.vmap(bk.and_popcount_argmax),
+                (S((c, u, w)), S((c, w)), S((c, u), BOOL)))
+    if name == "and_popcount_many":
+        # the rcd maximality test: P against X0 ∪ universe non-neighbours
+        return (jax.vmap(bk.and_popcount_many),
+                (S((c, 1, w)), S((c, xc + u, w))))
+    if name == "clique_counts":
+        return (jax.vmap(bk.clique_counts),
+                (S((c, u + xc, w)), S((c, w)), S((c, u + xc), BOOL),
+                 S((c, u + xc), BOOL)))
+    if name == "frame_step":
+        return (jax.vmap(bk.frame_step),
+                (S((c, u, w)), S((c, w)), S((c, w)), S((c, w))))
+    if name == "dfs_step_window":
+        return (jax.vmap(_window(8), in_axes=SHARED_EYE),
+                _window_shapes(S, u, w, xc, lead=(c,)))
+    if name == "dfs_step_window_lanes":
+        return _window_lanes(8), _window_shapes(S, u, w, xc, lead=(LANES,))
+    raise KeyError(name)
 
 
-def test_lower_vmapped_dfs_step_window_lanes():
-    # shard_map/vmap over device shards batches the lane axis; eye stays
-    # shared (in_axes=None), same as the engine's call pattern
-    f = lambda *a: bk.dfs_step_window_lanes(*a, steps=16, interpret=False)
-    _lower_tpu(
-        jax.vmap(f, in_axes=(0, 0, None, 0, 0, 0, 0, 0, 0, 0)),
-        *_lanes_args(batch=2))
+@pytest.mark.parametrize("u", UNIVERSES, ids=lambda u: f"U{u}")
+@pytest.mark.parametrize("name", [
+    "and_popcount_rows", "and_popcount_argmax", "and_popcount_many",
+    "clique_counts", "frame_step",
+    "dfs_step_window", "dfs_step_window_lanes"])
+def test_compile_real_width(S, name, u):
+    f, shapes = _real_case(name, S, u)
+    _compile_tpu(f, *shapes)
+
+
+def test_compile_and_popcount_many_vmem_clamp_widest(S):
+    """(1024, 1024) rows x masks at the widest bucket (W = 32): the VMEM
+    tile clamp must shrink the blocks to something that compiles."""
+    _compile_tpu(bk.and_popcount_many, S((CHUNK, 32)), S((CHUNK, 32)))
+
+
+# ---------------------------------------------------------------------------
+# The driver's chunk step on a one-device mesh of the described chip
+# ---------------------------------------------------------------------------
+
+# (roots per step, U pad, X rows pad): configs/rmce.py's regimes
+CELLS = {"web_sparse": (1024, 64, 64), "dense_core": (128, 1024, 1024)}
+
+
+def _engine(name):
+    from repro.core.engine import EngineConfig
+    if name == "persistent":   # the fused window-lanes path
+        return "persistent", EngineConfig(backend="pivot", dynamic_red=False,
+                                          window_steps=8)
+    return "perroot", EngineConfig(backend=name)
+
+
+@pytest.mark.parametrize("engine,kernel", [
+    ("pivot", "frame_step"), ("persistent", "dfs_step_window_lanes"),
+    ("hybrid", "clique_counts")])
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_compile_chunk_step(topo, monkeypatch, cell, engine, kernel):
+    """The jitted chunk step the driver dispatches, with the TPU kernel
+    dispatch the chip takes (the test steers `ops` onto its TPU branch;
+    `jax.default_backend()` here is the CPU)."""
+    from repro.core import driver
+    from repro.kernels.bitset_ops import ops
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    chunk, u, xc = CELLS[cell]
+    w = u // 32
+    mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+    sh = NamedSharding(mesh, P("data"))
+
+    def S(shape, dt=U32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sh)
+
+    eng, cfg = _engine(engine)
+    text = driver._sharded_counts.lower(
+        S((1, chunk, u, w)), S((1, chunk, w)), S((1, chunk, xc, w)),
+        S((1, chunk, xc), BOOL), S((1, chunk), I32),
+        cfg=cfg, mesh=mesh, axis=("data",), engine=eng,
+        lanes=LANES).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert f"%{kernel}" in text, f"{kernel} missing from the {cell} step"
