@@ -5,11 +5,11 @@ the streaming pipeline against a frozen copy of the pre-refactor
 `prepare()` — the per-vertex `np.isin` row packer and the unmemoized
 X-reduction, vendored below so the baseline cannot silently inherit
 later optimizations. Also runs the double-buffered distributed driver
-once to record the host/device overlap fraction.
+once to record its host seconds per stage.
 
 Emits BENCH_prep.json:
   {graph, n, m, roots, legacy_prep_s, stream_prep_s, speedup,
-   stage_timings, overlap_fraction, device_wait_s, host_pack_s}
+   stage_timings, driver_spans, host_pack_s}
 
   PYTHONPATH=src python -m benchmarks.perf_prep \
       [--graph ba:n=20000,m=8] [--overlap-graph ba:n=4000,m=6] \
@@ -312,23 +312,22 @@ def run(graph_desc: str = "ba:n=20000,m=8",
 
     og = parse_graph(overlap_graph)
     # warmup pass populates the jit cache; the measured pass re-packs a
-    # fresh stream against warm executables = steady-state overlap
+    # fresh stream against warm executables = steady state
     DistributedMCE(og, chunk=128, stream_roots=256).run()
     drv = DistributedMCE(og, chunk=128, stream_roots=256)
     res = drv.run()
-    print(f"overlap run {overlap_graph}: cliques={res.cliques} "
-          f"overlap={drv.overlap_fraction:.2f} "
-          f"host_pack={drv.stats['host_pack_s']:.2f}s "
-          f"device_wait={drv.stats['device_wait_s']:.2f}s", flush=True)
+    spans = {k: round(v, 4) for k, v in drv.stats["spans"].items()}
+    print(f"driver run {overlap_graph}: cliques={res.cliques} "
+          f"host_pack={drv.stats['host_pack_s']:.2f}s spans={spans}",
+          flush=True)
 
     row = dict(graph=graph_desc, n=g.n, m=g.m, roots=n_roots,
                legacy_prep_s=legacy_s, stream_prep_s=stream_s,
                speedup=speedup,
                stage_timings=stream.timings,
                overlap_graph=overlap_graph,
-               overlap_fraction=drv.overlap_fraction,
-               host_pack_s=drv.stats["host_pack_s"],
-               device_wait_s=drv.stats["device_wait_s"])
+               driver_spans=dict(drv.stats["spans"]),
+               host_pack_s=drv.stats["host_pack_s"])
     print(f"host-prep speedup: {speedup:.1f}x", flush=True)
     if out_json:
         from benchmarks.bench_record import append_run

@@ -81,9 +81,10 @@ def run_query(svc, label, cfg, engine, kernels, want, runs=2):
         t0 = time.perf_counter()
         res = svc.query(cfg, engine=engine)
         dt = time.perf_counter() - t0
-        # dispatch_s holds the step's compile on a cold call
-        drv = {k: svc.last_driver.stats[k]
-               for k in ("host_pack_s", "dispatch_s", "device_wait_s")}
+        # driver.dispatch holds the step's compile on a cold call, and
+        # driver.settle the wait for the device
+        drv = {k: round(v, 4)
+               for k, v in svc.last_driver.stats["spans"].items()}
         print(f"{label} run {i} ({'cold' if i == 0 else 'warm'}): "
               f"cliques={res.cliques} oracle={want} calls={res.calls} "
               f"{dt:.3f}s driver={drv} stats={res.stats}", flush=True)

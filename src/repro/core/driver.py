@@ -11,7 +11,9 @@ Deployment model for 1000+ nodes (DESIGN.md §5–§6):
   *k* is dispatched asynchronously, then the host
   packs and uploads chunk *k+1* while the device works, and only then
   blocks on chunk *k*'s counters. The host never sits between the device
-  and its next batch; `stats` records how much packing was hidden.
+  and its next batch. Each host stage (fetch, gather, upload, dispatch,
+  settle) is a span on the profiler's clock (`core/spans.py`), and its
+  seconds land in `stats["spans"]`.
 * **Straggler mitigation** is static balancing: per bucket, roots are sorted
   by a cost estimate (|P|·2^{λ̂} proxy: universe² × mean row popcount) and
   dealt round-robin across shards, so each shard receives the same cost mass
@@ -30,9 +32,8 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
-import time
 from functools import partial
-from typing import Iterator, List, Optional, Sequence, Tuple, Union
+from typing import Iterator, List, Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
@@ -44,6 +45,7 @@ from repro.core.engine import (BACKENDS, EngineConfig, MCEResult,
                                RootBucket, choose_engine, estimate_costs,
                                root_cost_skew, run_bucket_persistent,
                                run_root)
+from repro.core.spans import span
 from repro.graph.csr import CSRGraph
 
 # "truncated" folds each chunk's iters-exhausted flags so a max_iters cutoff
@@ -63,6 +65,12 @@ from repro.graph.csr import CSRGraph
 COUNTER_KEYS = ("cliques", "calls", "branches", "sum_px", "truncated",
                 "live_iters", "lane_iters", "steals", "entry_terms",
                 "window_spills", "window_hits")
+# the work each chunk program does, folded per (u_pad, x_pad, engine) into
+# stats["buckets"]: the operands of a work-based kernel roofline
+BUCKET_KEYS = ("calls", "sum_px", "live_iters", "lane_iters")
+# the host stages that `host_pack_s` sums: everything but the settle
+HOST_PACK_SPANS = ("driver.fetch", "driver.gather", "driver.upload",
+                   "driver.dispatch")
 
 
 # ---------------------------------------------------------------------------
@@ -237,8 +245,10 @@ class DistributedMCE:
         self.chunk = chunk
         self.cfg = cfg
         self.ckpt_path = ckpt_path
-        self.stats = {"host_pack_s": 0.0, "host_pack_overlap_s": 0.0,
-                      "dispatch_s": 0.0, "device_wait_s": 0.0, "chunks": 0,
+        # spans: host seconds per driver stage (core/spans.py); buckets:
+        # BUCKET_KEYS folded per (u_pad, x_pad, engine) at each settle
+        self.stats = {"host_pack_s": 0.0, "chunks": 0, "spans": {},
+                      "buckets": {},
                       "engine_choices": {"perroot": 0, "persistent": 0}}
         self.last_counters: dict = {}   # COUNTER_KEYS of the last run()
         self._last_step = None          # (arg shapes, engine kwargs)
@@ -308,17 +318,15 @@ class DistributedMCE:
         state.schedule = self._schedule
 
         window = self.n_shards * self.chunk
-        pending: Optional[Tuple[dict, int, int, int]] = None
-        self._inflight_host = 0.0       # host work while `pending` flies
+        spans = self.stats["spans"]
+        pending: Optional[tuple] = None
         src = self._buckets()
         b = -1
+        k = 0                               # chunks dispatched by this run
         while True:
-            t0 = time.perf_counter()
-            bucket = next(src, None)        # streaming: host packs here,
-            dt = time.perf_counter() - t0   # overlapped with the device chunk
-            self.stats["host_pack_s"] += dt
-            if pending is not None:
-                self._inflight_host += dt
+            # streaming: the host packs here, overlapped with the device
+            with span("driver.fetch", spans, bucket=b + 1, chunk=k):
+                bucket = next(src, None)
             if bucket is None:
                 break
             b += 1
@@ -348,19 +356,18 @@ class DistributedMCE:
             done = state.roots_done if b == state.bucket else 0
             while done < total:
                 hi = min(done + window, total)
-                t0 = time.perf_counter()
-                handle = self._run_chunk(bucket, order[done:hi],
-                                         eng_b, lanes_b)
-                dt = time.perf_counter() - t0   # gather/pad/upload: host work
-                self.stats["dispatch_s"] += dt
-                self.stats["host_pack_s"] += dt
+                out, n_pad = self._run_chunk(bucket, order[done:hi],
+                                             eng_b, lanes_b, b, k)
                 if pending is not None:
-                    self._inflight_host += dt
                     self._settle(pending, state)
-                pending = (*handle, b, hi)
+                pending = (out, n_pad, b, hi, k,
+                           (bucket.u_pad, bucket.x_pad, eng_b))
                 done = hi
+                k += 1
         if pending is not None:
             self._settle(pending, state)
+        self.stats["host_pack_s"] = sum(spans.get(s, 0.0)
+                                        for s in HOST_PACK_SPANS)
 
         late = len(self.stream.late_reported) if self.stream is not None else 0
         self.last_counters = dict(state.counters)
@@ -374,23 +381,29 @@ class DistributedMCE:
     # ---- chunk pipeline --------------------------------------------------
 
     def _run_chunk(self, bucket: RootBucket, window: np.ndarray,
-                   engine: str, lanes: int):
+                   engine: str, lanes: int, b: int, k: int):
         """Gather/pad + upload + *asynchronously* dispatch one chunk.
 
         `engine`/`lanes` are per-bucket: under engine="auto" the driver
-        resolves them from the bucket's cost skew before each chunk.
+        resolves them from the bucket's cost skew before each chunk; `b`
+        and `k` are the bucket and chunk ids its spans carry.
         Returns (unrealized device counters, n_pad); the caller settles the
         previous chunk after dispatching this one, so host pack/upload of
         chunk k+1 overlaps device execution of chunk k."""
-        slices = [window[s::self.n_shards] for s in range(self.n_shards)]
-        pad_to = max(len(s) for s in slices)
-        parts = [_shard_batch(bucket, s, pad_to) for s in slices]
-        n_pad = sum(pad_to - len(s) for s in slices)
-        stacked = (np.stack([p[i] for p in parts]) for i in range(5))
+        spans = self.stats["spans"]
+        with span("driver.gather", spans, bucket=b, chunk=k):
+            slices = [window[s::self.n_shards] for s in range(self.n_shards)]
+            pad_to = max(len(s) for s in slices)
+            parts = [_shard_batch(bucket, s, pad_to) for s in slices]
+            n_pad = sum(pad_to - len(s) for s in slices)
+            stacked = [np.stack([p[i] for p in parts]) for i in range(5)]
         sharding = NamedSharding(self.mesh, P(self.axis))
-        a, p0, xr, xa, rz = (jax.device_put(t, sharding) for t in stacked)
-        out = _sharded_counts(a, p0, xr, xa, rz, self.cfg, self.mesh,
-                              self.axis, engine=engine, lanes=lanes)
+        with span("driver.upload", spans, bucket=b, chunk=k):
+            a, p0, xr, xa, rz = (jax.device_put(t, sharding) for t in stacked)
+        # a cold shape traces and compiles (or loads from the cache) here
+        with span("driver.dispatch", spans, bucket=b, chunk=k):
+            out = _sharded_counts(a, p0, xr, xa, rz, self.cfg, self.mesh,
+                                  self.axis, engine=engine, lanes=lanes)
         self._last_step = (
             tuple(jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding)
                   for x in (a, p0, xr, xa, rz)),
@@ -406,35 +419,22 @@ class DistributedMCE:
 
     def _settle(self, pending, state: DriverCheckpoint) -> None:
         """Block on a dispatched chunk, fold counters, checkpoint cursor."""
-        out, n_pad, b, hi = pending
-        t0 = time.perf_counter()
-        out = jax.tree.map(lambda x: np.asarray(x), out)
-        wait = time.perf_counter() - t0
-        self.stats["device_wait_s"] += wait
-        # credit in-flight host time as hidden only when the settle proves
-        # the device was still busy; a zero wait means the device may have
-        # finished early, so that host time gets no overlap credit (the
-        # stat is a lower bound, never an optimistic one)
-        if wait > 1e-4:
-            self.stats["host_pack_overlap_s"] += self._inflight_host
-        self._inflight_host = 0.0
-        self.stats["chunks"] += 1
-        # padded no-op roots contribute exactly one call each; remove them so
-        # distributed counters match the single-host run bit-for-bit
-        out["calls"] = out["calls"] - n_pad
-        for k in COUNTER_KEYS:
-            # .get: checkpoints written before a counter key existed resume
-            # cleanly (the missing key starts from zero)
-            state.counters[k] = state.counters.get(k, 0) + int(out[k])
-        state.bucket, state.roots_done = b, hi
-        if self.ckpt_path:
-            state.save(self.ckpt_path)
-
-    @property
-    def overlap_fraction(self) -> float:
-        """Share of host ingest time hidden behind device compute.
-
-        Conservative: in-flight host time counts as hidden only for chunks
-        whose settle still had to wait on the device (lower bound)."""
-        total = self.stats["host_pack_s"]
-        return self.stats["host_pack_overlap_s"] / total if total > 0 else 0.0
+        out, n_pad, b, hi, k, shape = pending
+        with span("driver.settle", self.stats["spans"], bucket=b, chunk=k):
+            out = {key: int(np.asarray(v)) for key, v in out.items()}
+            self.stats["chunks"] += 1
+            # padded no-op roots contribute exactly one call each; remove
+            # them so distributed counters match the single-host run
+            # bit-for-bit
+            out["calls"] = out["calls"] - n_pad
+            for key in COUNTER_KEYS:
+                # .get: checkpoints written before a counter key existed
+                # resume cleanly (the missing key starts from zero)
+                state.counters[key] = state.counters.get(key, 0) + out[key]
+            per = self.stats["buckets"].setdefault(
+                shape, {key: 0 for key in BUCKET_KEYS})
+            for key in BUCKET_KEYS:
+                per[key] += out[key]
+            state.bucket, state.roots_done = b, hi
+            if self.ckpt_path:
+                state.save(self.ckpt_path)

@@ -21,6 +21,7 @@ from repro.core.engine import reductions as red
 from repro.core.engine.frames import U32, WORD, EngineConfig, Frame, FrameStack
 from repro.core.engine.prepare import (_unpack_bits_np, estimate_costs,
                                        prepare)
+from repro.core.spans import scoped
 from repro.graph.csr import CSRGraph
 from repro.kernels.bitset_ops import ops as bitops
 
@@ -204,6 +205,7 @@ def run_root_windowed(a, p0, x_rows, x_alive0, rsz0, cfg: EngineConfig):
     def cond(s):
         return (s[0] >= 0) & (s[1] < cfg.max_iters)
 
+    @scoped("engine.step")
     def body(s):
         d, it, sP, sB, sXp, sRb, srsz, carry = s
         base = jnp.clip(d - T // 2, 0, D - T)
@@ -261,6 +263,7 @@ def run_root(a, p0, x_rows, x_alive0, rsz0, cfg: EngineConfig):
     def cond(s):
         return (s[0] >= 0) & (s[1] < cfg.max_iters)
 
+    @scoped("engine.step")
     def body(s):
         depth, it, stack, carry = s
         depth, stack, carry = dfs_step(cfg, ctx, depth, stack, carry)
@@ -378,6 +381,7 @@ def _persistent_segment(a, p0, x_rows, x_alive0, rsz0, root_base, state,
         more = ((cp < R) | jnp.any(depth >= 0)) if drain else (cp < R)
         return more & (it < cfg.max_iters)
 
+    @scoped("engine.refill")
     def refill(args):
         """Claim protocol: exhausted lanes take consecutive queue slots."""
         cp, ls, et, depth, al, xrl, stack, carry = args
@@ -431,6 +435,7 @@ def _persistent_segment(a, p0, x_rows, x_alive0, rsz0, root_base, state,
         et = et + done_entry
         return cp, ls, et, depth, al, xrl, stack, carry
 
+    @scoped("engine.steal")
     def steal(args):
         """STEAL transition (DESIGN.md §2.6): an idle lane adopts half of
         a live lane's shallowest splittable branch set (slot 0 — the true
@@ -570,10 +575,11 @@ def _persistent_segment(a, p0, x_rows, x_alive0, rsz0, root_base, state,
             # extend slot 0's, so alive0' ∧ Rb ⊆ N(x) is exact)
             alive0l = jax.vmap(
                 lambda bits: fr.bitset_to_mask(bits, XC))(stack.xal[:, 0])
-            wP, wB, wXp, wRb, wrsz, ctl = bitops.dfs_step_window_lanes(
-                al, xrl, eye, alive0l.astype(jnp.int32), wstack.P,
-                wstack.B, wstack.Xp, wstack.Rb, wstack.rsz, wd,
-                steps=K)
+            with jax.named_scope("engine.step"):
+                wP, wB, wXp, wRb, wrsz, ctl = bitops.dfs_step_window_lanes(
+                    al, xrl, eye, alive0l.astype(jnp.int32), wstack.P,
+                    wstack.B, wstack.Xp, wstack.Rb, wstack.rsz, wd,
+                    steps=K)
             wstack = wstack._replace(P=wP, B=wB, Xp=wXp, Rb=wRb, rsz=wrsz)
             nd = ctl[:, 0]
             carry = dict(carry,
@@ -593,6 +599,7 @@ def _persistent_segment(a, p0, x_rows, x_alive0, rsz0, root_base, state,
             stage = cfg.out_cap == 0 and R > 0
             S = max(2, L // 4)
 
+            @scoped("engine.step")
             def one_step(wdep, wstk, car, sd, al_, xrl_):
                 lv = (wdep >= 0) & (wdep < WT - 1)
                 d_in = jnp.clip(wdep, 0, WT - 2)
@@ -645,6 +652,7 @@ def _persistent_segment(a, p0, x_rows, x_alive0, rsz0, root_base, state,
                 # calls, skipped entirely (lax.cond) once the queue is
                 # out. Entry effects land in per-root counter DELTAS,
                 # applied exactly once when a lane consumes the root.
+                @scoped("engine.refill")
                 def do_stage(_):
                     s_idx = cp + jnp.arange(S, dtype=jnp.int32)
                     s_ok = s_idx < R
@@ -694,6 +702,7 @@ def _persistent_segment(a, p0, x_rows, x_alive0, rsz0, root_base, state,
                 trip_steal = can_steal and full_win
                 squorum = jnp.int32(max(1, L // 16))
 
+                @scoped("engine.steal")
                 def steal_multi(cs):
                     """Multi-way in-trip STEAL: rank-partition the
                     branchiest victim's donation slot across ALL idle
@@ -780,6 +789,7 @@ def _persistent_segment(a, p0, x_rows, x_alive0, rsz0, root_base, state,
                     stl = stl + jnp.sum(tk.astype(jnp.int32))
                     return wdep, wstk, car, al_, xrl_, stl
 
+                @scoped("engine.refill")
                 def consume(cs):
                     """Swap staged roots into dead lanes, death order."""
                     wdep, wstk, car, al_, xrl_, used, ntm = cs
@@ -950,6 +960,7 @@ def _persistent_segment(a, p0, x_rows, x_alive0, rsz0, root_base, state,
         else:
             ls = ls + jnp.sum((depth >= 0).astype(jnp.int32))
 
+            @scoped("engine.step")
             def lane_step(a_l, xr_l, depth_l, stack_l, carry_l):
                 ctx = fr.RootContext(A=a_l, x_rows=xr_l, eye=eye,
                                      eye_x=eye_x)

@@ -34,11 +34,11 @@ are regenerated deterministically on every fresh iteration.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Dict, Iterator, List, Optional, Sequence, Set
 
 import numpy as np
 
+from repro.core.spans import span
 from repro.graph.csr import CSRGraph
 from repro.graph.order import degeneracy_order
 from repro.graph.pack import pack_bucket
@@ -120,31 +120,29 @@ class PrepStream:
     def front(self) -> _Front:
         if self._front is not None:
             return self._front
-        t0 = time.perf_counter()
-        if self.global_red:
-            from repro.core.global_reduction import (global_reduce_host,
-                                                     reduce_prepass)
+        with span("prep.reduce", self.timings, "reduce"):
+            if self.global_red:
+                from repro.core.global_reduction import (global_reduce_host,
+                                                         reduce_prepass)
 
-            residual, pre_reports = reduce_prepass(self.g)
-            red = global_reduce_host(residual)
-            g_work = red.graph
-            self.pre_reported = pre_reports + list(red.reported)
-        else:
-            g_work = self.g
-        self.timings["reduce"] = time.perf_counter() - t0
+                residual, pre_reports = reduce_prepass(self.g)
+                red = global_reduce_host(residual)
+                g_work = red.graph
+                self.pre_reported = pre_reports + list(red.reported)
+            else:
+                g_work = self.g
 
-        t0 = time.perf_counter()
-        order, rank, lam = degeneracy_order(g_work)
-        # python-list slicing beats 20k tiny numpy slices by ~5x here
-        idx_list = g_work.indices.tolist()
-        ptr = g_work.indptr.tolist()
-        adj = [set(idx_list[ptr[v]:ptr[v + 1]]) for v in range(g_work.n)]
-        kept_x = None
-        if self.x_red:
-            from repro.core.xreduction import x_prune_roots
+        with span("prep.order", self.timings, "order"):
+            order, rank, lam = degeneracy_order(g_work)
+            # python-list slicing beats 20k tiny numpy slices by ~5x here
+            idx_list = g_work.indices.tolist()
+            ptr = g_work.indptr.tolist()
+            adj = [set(idx_list[ptr[v]:ptr[v + 1]]) for v in range(g_work.n)]
+            kept_x = None
+            if self.x_red:
+                from repro.core.xreduction import x_prune_roots
 
-            kept_x = x_prune_roots(adj, order, rank)
-        self.timings["order"] = time.perf_counter() - t0
+                kept_x = x_prune_roots(adj, order, rank)
         self._front = _Front(g=g_work, order=order, rank=rank, degeneracy=lam,
                              adj=adj, kept_x=kept_x)
         return self._front
@@ -214,21 +212,19 @@ class PrepStream:
 
     def _pack(self, bucket: int, specs: List[RootSpec],
               n_pad: int = 0) -> RootBucket:
-        t0 = time.perf_counter()
         f = self._front
-        a, p0, x_rows, x_alive = pack_bucket(
-            f.g.indptr, f.g.indices, f.g.n,
-            [s.p_ids for s in specs], [s.x_ids for s in specs], bucket)
-        out = RootBucket(
-            u_pad=bucket, x_pad=x_rows.shape[1], a=a, p0=p0, x_rows=x_rows,
-            x_alive0=x_alive,
-            roots=np.array([s.base[0] for s in specs], np.int64),
-            rsz0=np.array([len(s.base) for s in specs], np.int32),
-            bases=[s.base for s in specs],
-            universes=[s.p_ids for s in specs],
-            n_pad=n_pad)
-        self.timings["pack"] += time.perf_counter() - t0
-        return out
+        with span("prep.pack", self.timings, "pack", bucket=bucket):
+            a, p0, x_rows, x_alive = pack_bucket(
+                f.g.indptr, f.g.indices, f.g.n,
+                [s.p_ids for s in specs], [s.x_ids for s in specs], bucket)
+            return RootBucket(
+                u_pad=bucket, x_pad=x_rows.shape[1], a=a, p0=p0,
+                x_rows=x_rows, x_alive0=x_alive,
+                roots=np.array([s.base[0] for s in specs], np.int64),
+                rsz0=np.array([len(s.base) for s in specs], np.int32),
+                bases=[s.base for s in specs],
+                universes=[s.p_ids for s in specs],
+                n_pad=n_pad)
 
     def _pad_count(self, n: int) -> int:
         """Remainder-flush pad: round the root count up to the smallest
@@ -259,13 +255,27 @@ class PrepStream:
         self.late_reported = []
         self.num_buckets = 0
         done: List[RootBucket] = []
+        flushes = self._flushes(done)
+        while True:
+            # staging runs lazily inside next(): its span covers the
+            # specs walked up to the next flush, and the `stage` timing
+            # leaves out the pack span nested in it
+            pack_before = self.timings["pack"]
+            with span("prep.stage", self.timings, "stage"):
+                bk = next(flushes, None)
+            self.timings["stage"] -= self.timings["pack"] - pack_before
+            if bk is None:
+                break
+            yield bk
+        if self.cache:
+            self._cached = done
+
+    def _flushes(self, done: List[RootBucket]) -> Iterator[RootBucket]:
+        """Stage the root specs and yield each bucket as it fills."""
         pending: Dict[int, List[RootSpec]] = {b: [] for b in self.bucket_sizes}
-        t_mark = time.perf_counter()
 
         def flush(b: int) -> RootBucket:
-            """Pack + book-keep one bucket; staging time since the last
-            yield (minus pack time) lands in the `stage` timing."""
-            pack_before = self.timings["pack"]
+            """Pack + book-keep one bucket."""
             specs = pending[b]
             n_pad = self._pad_count(len(specs))
             if n_pad:
@@ -277,8 +287,6 @@ class PrepStream:
             self.num_buckets += 1
             if self.cache:
                 done.append(bk)
-            self.timings["stage"] += (time.perf_counter() - t_mark
-                                      - (self.timings["pack"] - pack_before))
             return bk
 
         for spec in self._specs():
@@ -286,13 +294,9 @@ class PrepStream:
             pending[b].append(spec)
             if self.stream_roots and len(pending[b]) >= self.stream_roots:
                 yield flush(b)
-                t_mark = time.perf_counter()
         for b in self.bucket_sizes:
             if pending[b]:
                 yield flush(b)
-                t_mark = time.perf_counter()
-        if self.cache:
-            self._cached = done
 
     # ---- legacy one-shot API ---------------------------------------------
 

@@ -19,9 +19,14 @@ kernel grid. That IS the engine's real call pattern (`loop.run_bucket`
 vmaps `run_root`), so the kernels are written batch-safe (no `program_id`
 reads, no revisited output blocks — see kernel.py) and vmap parity is
 tested per kernel in tests/test_bitset_ops_dispatch.py.
+
+Every public entry point traces under the device scope `kernels.bitset_ops`
+(`jax.named_scope`), so a profiler trace can sum this layer's device time;
+the scope is metadata only.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -34,11 +39,22 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def _scoped(fn):
+    """Trace `fn` under the device scope `kernels.bitset_ops`."""
+    @functools.wraps(fn)
+    def inner(*args, **kwargs):
+        with jax.named_scope("kernels.bitset_ops"):
+            return fn(*args, **kwargs)
+    return inner
+
+
+@_scoped
 def popcount_words(bits: jnp.ndarray) -> jnp.ndarray:
     """Total set-bit count over the trailing word axis: (..., W) -> (...)."""
     return jnp.sum(jax.lax.population_count(bits), axis=-1).astype(jnp.int32)
 
 
+@_scoped
 def and_popcount_rows(rows: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
     """popcount(rows & mask) per row; dispatches pallas on TPU, jnp elsewhere.
 
@@ -51,11 +67,13 @@ def and_popcount_rows(rows: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
     return ref.and_popcount_rows(rows, mask)
 
 
+@_scoped
 def and_rows(rows: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
     """rows & mask broadcast over the row axis (materialised intersection)."""
     return ref.and_rows(rows, mask)
 
 
+@_scoped
 def and_popcount_argmax(rows: jnp.ndarray, mask: jnp.ndarray,
                         valid: Optional[jnp.ndarray] = None
                         ) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -67,6 +85,7 @@ def and_popcount_argmax(rows: jnp.ndarray, mask: jnp.ndarray,
     return ref.and_popcount_argmax(rows, mask, valid)
 
 
+@_scoped
 def and_popcount_many(rows: jnp.ndarray, masks: jnp.ndarray) -> jnp.ndarray:
     """out[m, k] = popcount(rows[k] & masks[m]) — one row matrix against an
     (M, W) batch of masks (the X-subset maximality-test shape)."""
@@ -75,6 +94,7 @@ def and_popcount_many(rows: jnp.ndarray, masks: jnp.ndarray) -> jnp.ndarray:
     return ref.and_popcount_many(rows, masks)
 
 
+@_scoped
 def clique_counts(rows: jnp.ndarray, mask: jnp.ndarray, in_p: jnp.ndarray,
                   in_x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Fused early-termination census (hybrid backend): (n_full, n_dom).
@@ -99,6 +119,7 @@ WINDOW_MAX_WORDS = 128
 WINDOW_MAX_XROWS = 4096
 
 
+@_scoped
 def dfs_step_window(a: jnp.ndarray, x_rows: jnp.ndarray, eye: jnp.ndarray,
                     alive0: jnp.ndarray, winP: jnp.ndarray,
                     winB: jnp.ndarray, winXp: jnp.ndarray,
@@ -123,6 +144,7 @@ def dfs_step_window(a: jnp.ndarray, x_rows: jnp.ndarray, eye: jnp.ndarray,
                                winRb, winrsz, dloc, steps)
 
 
+@_scoped
 def dfs_step_window_lanes(a: jnp.ndarray, x_rows: jnp.ndarray,
                           eye: jnp.ndarray, alive0: jnp.ndarray,
                           winP: jnp.ndarray, winB: jnp.ndarray,
@@ -153,6 +175,7 @@ def dfs_step_window_lanes(a: jnp.ndarray, x_rows: jnp.ndarray,
                                      winXp, winRb, winrsz, dloc, steps)
 
 
+@_scoped
 def frame_step(rows: jnp.ndarray, p: jnp.ndarray, xp: jnp.ndarray,
                wrow: jnp.ndarray):
     """Fused BK frame step: (childp, childxp, deg, partner).

@@ -143,11 +143,10 @@ def main() -> None:
     n_buckets = (drv.stream.num_buckets if drv.stream is not None
                  else len(drv.prep.buckets))
     print(f"prep stages: {stage_str or f'(materialized in {init_s:.2f}s)'}")
+    spans = " ".join(f"{k.split('.')[1]} {v:.2f}s"
+                     for k, v in drv.stats["spans"].items())
     print(f"run {run_s:.2f}s  shards={drv.n_shards} buckets={n_buckets} "
-          f"chunks={drv.stats['chunks']}  "
-          f"device_wait {drv.stats['device_wait_s']:.2f}s  "
-          f"host_pack {drv.stats['host_pack_s']:.2f}s "
-          f"(overlapped {100 * drv.overlap_fraction:.0f}%)")
+          f"chunks={drv.stats['chunks']}  host: {spans}")
     if args.engine == "auto":
         print(f"engine choices: {drv.stats['engine_choices']}")
     lc = drv.last_counters

@@ -64,15 +64,6 @@ class MCEService:
         cap = self.stats["lane_iters"]
         return self.stats["live_iters"] / cap if cap else 0.0
 
-    # stream_occupancy is the health metric the window tentpole moves:
-    # occupancy() already folds window trips into both numerator and
-    # capacity (lane_iters scales by window_steps), so it stays the
-    # cross-engine comparable ratio and this is just the named alias the
-    # launch summaries print alongside boundary_stall.
-    def stream_occupancy(self) -> float:
-        """Alias of occupancy() under its DESIGN.md §2.6 stream name."""
-        return self.occupancy()
-
     def boundary_stall(self) -> float:
         """Fraction of windowed lane-trips that ended at a stack boundary.
 
@@ -160,7 +151,7 @@ def main() -> None:
               f"occ={occ:.2f} stall={stall:.2f} {time.time() - t0:.2f}s "
               f"({'cold: streamed+packed' if svc.queries == 1 else 'cached buckets'})")
     print(f"service: {svc.queries} queries, "
-          f"stream_occupancy {svc.stream_occupancy():.2f}, "
+          f"occupancy {svc.occupancy():.2f}, "
           f"boundary_stall {svc.boundary_stall():.2f} "
           f"(spills={svc.stats['window_spills']} "
           f"hits={svc.stats['window_hits']}), "

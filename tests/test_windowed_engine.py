@@ -231,7 +231,7 @@ def test_choose_engine_steal_halves_skew_threshold():
 
 
 # ---------------------------------------------------------------------------
-# Service surfacing: boundary_stall / stream_occupancy / window counters
+# Service surfacing: boundary_stall / occupancy / window counters
 # ---------------------------------------------------------------------------
 
 def test_service_surfaces_window_stats():
@@ -247,8 +247,7 @@ def test_service_surfaces_window_stats():
     assert svc.stats["window_spills"] == res.stats["window_spills"]
     assert svc.stats["window_hits"] == res.stats["window_hits"]
     assert 0.0 <= svc.boundary_stall() <= 1.0
-    assert 0.0 < svc.stream_occupancy() <= 1.0
-    assert svc.stream_occupancy() == svc.occupancy()
+    assert 0.0 < svc.occupancy() <= 1.0
     # a second unwindowed query must not move the window counters
     before = (svc.stats["window_spills"], svc.stats["window_hits"])
     svc.query()
