@@ -3,8 +3,10 @@
 The paper's Lemmas 5 (degree-0), 7 (relaxed degree-1) and 8 (degree-|P|−1)
 become bitset algebra over the frame: every degree vector is one fused
 AND+popcount sweep through `bitset_ops.ops`, every report is a masked
-multi-row append to the carry. No control flow — callers gate side-effects
-with `enable` so the DFS body stays straight-line under vmap.
+multi-row append to the carry, and Lemma 7's lookups at a vertex's partner
+are row tests on `A & P` rather than gathers by the partner index. No
+control flow — callers gate side-effects with `enable` so the DFS body
+stays straight-line under vmap.
 """
 from __future__ import annotations
 
@@ -38,17 +40,19 @@ def dynamic_reduce(carry, cfg, ctx: fr.RootContext, P, Xp, xal, rsz, Rb,
     `pre` is the optional (degP, partner) pair from the fused frame-step
     kernel — the DFS body already swept A against this call's P to build
     it, so passing it here removes the first AND+popcount sweep and the
-    Lemma-7 partner extraction from this function."""
+    Lemma-7 partner extraction from this function. The rows `A & P` are
+    built either way: Lemma 7 tests its partners on them."""
     U = ctx.u
     XC = ctx.xc
     A, x_rows, eye, eye_x = ctx.A, ctx.x_rows, ctx.eye, ctx.eye_x
     xal_mask = fr.bitset_to_mask(xal, XC)
 
+    NP = bitops.and_rows(A, P)                         # (U, W) rows ∩ P
     if pre is None:
         degP = bitops.and_popcount_rows(A, P)          # (U,)
-        partner0 = fr.single_bit_index_rows(bitops.and_rows(A, P))
+        partner = fr.single_bit_index_rows(NP)
     else:
-        degP, partner0 = pre
+        degP, partner = pre
     in_p = fr.bitset_to_mask(P, U)
     xp_mask = fr.bitset_to_mask(Xp, U)
     marked_bits = fr.or_reduce(x_rows, xal_mask) | fr.or_reduce(A, xp_mask)
@@ -62,18 +66,22 @@ def dynamic_reduce(carry, cfg, ctx: fr.RootContext, P, Xp, xal, rsz, Rb,
                             rep0 & enable)
     Xp = Xp | fr.mask_to_bitset(rep0, eye)
 
-    # relaxed dynamic degree-one (Lemma 7)
+    # relaxed dynamic degree-one (Lemma 7). Where deg1[k], row NP[k] holds
+    # one bit, the partner's, so v[partner[k]] == any_bit(NP[k] & V) for a
+    # mask v with bitset V: the partner lookups below are row tests, not
+    # per-element gathers, and every one is gated by deg1. Only the mutual
+    # pair's order reads the partner index (valid where deg == 1).
     deg1 = in_p & (degP == 1)
-    partner = partner0                                 # valid where deg == 1
     pclip = jnp.clip(partner, 0, U - 1)
-    partner_deg1 = deg1 & deg1[pclip]
+    partner_deg1 = deg1 & fr.any_bit(NP & fr.mask_to_bitset(deg1, eye))
     mutual_skip = partner_deg1 & (pclip < jnp.arange(U))
-    cond = deg1 & ~mutual_skip & (~marked | ~marked[pclip])
-    pair_rows = Rb[None, :] | eye | eye[pclip]
+    cond = deg1 & ~mutual_skip & (~marked | fr.any_bit(NP & ~marked_bits))
+    pair_rows = Rb[None, :] | eye | NP       # NP[k] == eye[partner] if cond
     carry = fr.report_multi(carry, cfg, pair_rows,
                             jnp.full((U,), rsz + 2, jnp.int32),
                             cond & enable)
-    rem1 = cond | (partner_deg1 & cond[pclip])
+    rem1 = cond | (partner_deg1
+                   & fr.any_bit(NP & fr.mask_to_bitset(cond, eye)))
     Xp = Xp | fr.mask_to_bitset(rem1, eye)
     removed = deg0 | rem1
     P = P & ~fr.mask_to_bitset(removed, eye)

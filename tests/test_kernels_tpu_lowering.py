@@ -14,12 +14,15 @@ described `v5e:2x2` topology: shapes only, nothing runs. Cases:
   U in 32..1024 (`configs/rmce.py`), chunks of 1024 roots, the window
   kernels at WINDOW_FRAMES = 8 and 64 lanes;
 * the chunk step `_sharded_counts` on a one-device mesh at the
-  `web_sparse` and `dense_core` shapes, for each engine path.
+  `web_sparse` and `dense_core` shapes, for each engine path;
+* the persistent chunk step at the graph500_s12 benchmark's U=64 shape,
+  whose lane step and refill may hold no element gather of a `pred` array.
 
 The topology is described inside a module fixture (never at import),
 which skips the file where libtpu cannot describe it.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -281,3 +284,44 @@ def test_compile_chunk_step(topo, monkeypatch, cell, engine, kernel):
         lanes=LANES).compile().as_text()
     assert "tpu_custom_call" in text
     assert f"%{kernel}" in text, f"{kernel} missing from the {cell} step"
+
+
+# ---------------------------------------------------------------------------
+# The graph500_s12 benchmark's U=64 chunk step: no element gathers of
+# boolean lane vectors in the engine's step or refill
+# ---------------------------------------------------------------------------
+
+# a pred gather whose every slice is one element (no offset dims)
+_GATHER = re.compile(r"= pred\[[^\]]*\]\S* gather\(.*offset_dims=\{\}")
+_PHASE = re.compile(r'op_name="[^"]*engine\.(step|refill)')
+
+
+def test_chunk_step_has_no_phase_pred_gathers(topo, monkeypatch):
+    """Lemma 7's partner lookups are row tests on `A & P` (DESIGN.md §4):
+    the persistent U=64 chunk program (602 roots, 512 X rows, 64 lanes)
+    keeps no element gather of a `pred` array under `engine.step` or
+    `engine.refill`, where a gather by the partner index costs one lookup
+    per lane and vertex. (The refill's row take of a root's alive X mask
+    moves whole rows and is not matched.)"""
+    from repro.core import driver
+    from repro.core.engine import EngineConfig
+    from repro.kernels.bitset_ops import ops
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    chunk, u, xc = 602, 64, 512
+    w = u // 32
+    mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+    sh = NamedSharding(mesh, P("data"))
+
+    def S(shape, dt=U32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sh)
+
+    text = driver._sharded_counts.lower(
+        S((1, chunk, u, w)), S((1, chunk, w)), S((1, chunk, xc, w)),
+        S((1, chunk, xc), BOOL), S((1, chunk), I32),
+        cfg=EngineConfig(backend="pivot"), mesh=mesh, axis=("data",),
+        engine="persistent", lanes=LANES).compile().as_text()
+    assert "%frame_step" in text
+    bad = [ln.strip()[:160] for ln in text.splitlines()
+           if _GATHER.search(ln) and _PHASE.search(ln)]
+    assert not bad, f"{len(bad)} pred element gathers: {bad[:3]}"

@@ -3,14 +3,18 @@ import numpy as np
 import pytest
 from _hyp import given, strategies as st  # optional-hypothesis shim
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import oracle
+from repro.core.engine import frames as fr
+from repro.core.engine.reductions import ReducedFrame, dynamic_reduce
 from repro.core.global_reduction import (_batch_lemma3, global_reduce_host,
                                          global_reduce_jnp, reduce_prepass)
 from repro.core.xreduction import x_prune_roots
 from repro.graph import (complete_graph, degeneracy_order, erdos_renyi,
                          from_edge_list, grid_road, random_geometric)
+from repro.kernels.bitset_ops import ops as bitops
 
 
 @st.composite
@@ -199,3 +203,169 @@ def test_dynamic_reduction_only(g):
     ref = oracle.maximal_cliques_brute(g)
     got = set(oracle.rmce(g, global_red=False, dynamic_red=True, x_red=False))
     assert got == ref
+
+
+# ---------------------------------------------------------------------------
+# Dynamic reduction: Lemma 7's row tests against the partner-index gathers
+# ---------------------------------------------------------------------------
+
+def _dynamic_reduce_gather(carry, cfg, ctx, P, Xp, xal, rsz, Rb, enable,
+                           pre=None):
+    """`dynamic_reduce` with Lemma 7's partner lookups as element gathers
+    by the partner index (`v[pclip]`), the form the row tests replace."""
+    U, XC = ctx.u, ctx.xc
+    A, x_rows, eye, eye_x = ctx.A, ctx.x_rows, ctx.eye, ctx.eye_x
+    xal_mask = fr.bitset_to_mask(xal, XC)
+    if pre is None:
+        degP = bitops.and_popcount_rows(A, P)
+        partner = fr.single_bit_index_rows(bitops.and_rows(A, P))
+    else:
+        degP, partner = pre
+    in_p = fr.bitset_to_mask(P, U)
+    xp_mask = fr.bitset_to_mask(Xp, U)
+    marked_bits = fr.or_reduce(x_rows, xal_mask) | fr.or_reduce(A, xp_mask)
+    marked = fr.bitset_to_mask(marked_bits, U)
+    deg0 = in_p & (degP == 0)
+    rep0 = deg0 & ~marked
+    carry = fr.report_multi(carry, cfg, Rb[None, :] | eye,
+                            jnp.full((U,), rsz + 1, jnp.int32),
+                            rep0 & enable)
+    Xp = Xp | fr.mask_to_bitset(rep0, eye)
+    deg1 = in_p & (degP == 1)
+    pclip = jnp.clip(partner, 0, U - 1)
+    partner_deg1 = deg1 & deg1[pclip]
+    mutual_skip = partner_deg1 & (pclip < jnp.arange(U))
+    cond = deg1 & ~mutual_skip & (~marked | ~marked[pclip])
+    carry = fr.report_multi(carry, cfg, Rb[None, :] | eye | eye[pclip],
+                            jnp.full((U,), rsz + 2, jnp.int32),
+                            cond & enable)
+    rem1 = cond | (partner_deg1 & cond[pclip])
+    Xp = Xp | fr.mask_to_bitset(rem1, eye)
+    P = P & ~fr.mask_to_bitset(deg0 | rem1, eye)
+    degP2 = bitops.and_popcount_rows(A, P)
+    in_p2 = fr.bitset_to_mask(P, U)
+    psize = fr.popcount(P)
+    full = in_p2 & (degP2 == psize - 1) & (psize > 0)
+    any_full = jnp.any(full)
+    n_full = jnp.sum(full.astype(jnp.int32))
+    full_bits = fr.mask_to_bitset(full, eye)
+    common = fr.and_reduce(A, full)
+    sub_ok = bitops.and_popcount_rows(jnp.bitwise_not(x_rows), full_bits) == 0
+    return carry, ReducedFrame(
+        P=jnp.where(any_full, P & ~full_bits, P),
+        Xp=jnp.where(any_full, Xp & common, Xp),
+        xal=jnp.where(any_full, xal & fr.mask_to_bitset(sub_ok, eye_x), xal),
+        Rb=jnp.where(any_full, Rb | full_bits, Rb),
+        rsz=jnp.where(any_full, rsz + n_full, rsz),
+        degP2=degP2, n_full=n_full)
+
+
+def _pack(bits):
+    """(..., n) bool -> (..., ceil(n/32)) uint32, bit i in word i // 32."""
+    n = bits.shape[-1]
+    pad = np.zeros(bits.shape[:-1] + (-(-n // 32) * 32,), bool)
+    pad[..., :n] = bits
+    return np.packbits(pad, axis=-1, bitorder="little").view("<u4")
+
+
+def _lemma7_frames(u, xc, seed, n=24):
+    """`n` random frames over a U-vertex universe, each with planted
+    mutual degree-one pairs and one-way degree-one vertices in P, marks
+    from alive X0 rows and from Xp at a frame's own density, and every
+    third frame disabled. Returns the packed batch and bool `parts`."""
+    rng = np.random.default_rng(seed)
+    parts = {k: [] for k in ("A", "x_rows", "P", "Xp", "xal", "Rb")}
+    rsz, enable = [], []
+    for i in range(n):
+        adj = np.triu(rng.random((u, u)) < rng.uniform(0.02, 0.3), 1)
+        adj = adj | adj.T
+        role = rng.choice(4, size=u, p=[0.55, 0.15, 0.1, 0.2])
+        in_p = role == 0
+        pidx = rng.permutation(np.flatnonzero(in_p))
+        k = len(pidx) // 4
+        for j in range(0, 2 * (k // 2), 2):          # mutual pairs
+            a, b = pidx[j], pidx[j + 1]
+            adj[[a, b], :] &= ~in_p
+            adj[:, [a, b]] &= ~in_p[:, None]
+            adj[a, b] = adj[b, a] = True
+        for c in pidx[k:2 * k]:                       # one-way: c -> d
+            d = pidx[rng.integers(2 * k, len(pidx))]
+            adj[c, :] &= ~in_p
+            adj[:, c] &= ~in_p
+            adj[c, d] = adj[d, c] = True
+        parts["A"].append(adj)
+        parts["x_rows"].append(rng.random((xc, u)) < rng.uniform(0.0, 0.08))
+        parts["P"].append(in_p)
+        parts["Xp"].append(role == 1)
+        parts["xal"].append(rng.random(xc) < rng.uniform(0.0, 0.6))
+        parts["Rb"].append(role == 2)
+        rsz.append(int(rng.integers(1, 6)))
+        enable.append(i % 3 != 2)
+    parts = {k: np.stack(v) for k, v in parts.items()}
+    batch = {k: _pack(v) for k, v in parts.items()}
+    return batch, parts, np.array(rsz, np.int32), np.array(enable)
+
+
+def _lemma7_coverage(parts):
+    """Rows of the batch in each Lemma-7 case, counted in numpy."""
+    A, P = parts["A"], parts["P"]
+    deg = (A & P[:, None, :]).sum(-1)
+    deg1 = P & (deg == 1)
+    partner = np.argmax(A & P[:, None, :], axis=-1)
+    marked = (np.any(parts["x_rows"] & parts["xal"][..., None], axis=1)
+              | np.any(A & parts["Xp"][..., None], axis=1))
+    pmarked = np.take_along_axis(marked, partner, axis=-1)
+    pdeg1 = np.take_along_axis(deg1, partner, axis=-1)
+    return {"mutual": int((deg1 & pdeg1).sum()),
+            "one_way": int((deg1 & ~pdeg1).sum()),
+            "both_marked": int((deg1 & marked & pmarked).sum()),
+            "partner_unmarked": int((deg1 & marked & ~pmarked).sum())}
+
+
+@pytest.mark.parametrize("pre", ["none", "frame_step"])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("u", [32, 64, 128])
+def test_dynamic_reduce_row_tests_match_gathers(u, seed, pre):
+    """Lemma 7 by row tests on `A & P` equals the partner-index gather
+    form bit for bit: every counter and enumerated row of the carry and
+    every ReducedFrame field, over a vmapped batch of frames, with the
+    degrees and partners from `frame_step` or from `dynamic_reduce`
+    itself."""
+    xc = 40
+    batch, parts, rsz, enable = _lemma7_frames(u, xc, seed)
+    cov = _lemma7_coverage(parts)
+    assert min(cov.values()) > 0, cov
+    cfg = fr.EngineConfig(out_cap=48)
+
+    def make(fn):
+        def one(a, x_rows, P, Xp, xal, Rb, rsz, enable, out_n):
+            ctx = fr.make_context(a, x_rows)
+            carry = fr.carry_init(cfg, ctx.words, track_root=True)
+            carry = dict(carry, out_n=out_n, calls=out_n + 1,
+                         cur_root=out_n * 7)
+            pre_ = None
+            if pre == "frame_step":
+                full = jnp.full_like(P, 0xFFFFFFFF)
+                _, _, deg, partner = bitops.frame_step(a, P, Xp, full)
+                pre_ = (deg, partner)
+            return fn(carry, cfg, ctx, P, Xp, xal, rsz, Rb, enable, pre=pre_)
+        return jax.jit(jax.vmap(one))
+
+    # empty buffers on even lanes, one row short of full on odd lanes
+    out_n = np.where(np.arange(len(rsz)) % 2, cfg.out_cap - 1, 0).astype(
+        np.int32)
+    args = (batch["A"], batch["x_rows"], batch["P"], batch["Xp"],
+            batch["xal"], batch["Rb"], rsz, enable, out_n)
+    got_carry, got = make(dynamic_reduce)(*args)
+    want_carry, want = make(_dynamic_reduce_gather)(*args)
+    assert sorted(got_carry) == sorted(want_carry)
+    for k in want_carry:
+        np.testing.assert_array_equal(np.asarray(got_carry[k]),
+                                      np.asarray(want_carry[k]), err_msg=k)
+    for k in want._fields:
+        np.testing.assert_array_equal(np.asarray(getattr(got, k)),
+                                      np.asarray(getattr(want, k)),
+                                      err_msg=k)
+    # the batch reports advance cliques and overflows some lanes' buffers
+    assert np.any(np.asarray(want_carry["cliques"]) > 0)
+    assert np.any(np.asarray(want_carry["overflow"]))
