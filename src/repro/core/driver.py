@@ -32,7 +32,6 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
-from functools import partial
 from typing import Iterator, List, Optional, Sequence, Union
 
 import jax
@@ -170,13 +169,48 @@ def _sharded_counts_impl(a, p0, xr, xa, rz, cfg: EngineConfig, mesh: Mesh,
     return {k: jnp.sum(v) for k, v in out.items()}
 
 
-# One jitted program per (chunk shape, cfg, engine, lanes). Its inputs are
-# not donated: the step returns only scalar counters, so no output could
-# alias a chunk buffer, and the driver drops each chunk's arrays after the
-# dispatch anyway.
-_sharded_counts = partial(jax.jit,
-                          static_argnames=("cfg", "mesh", "axis", "engine",
-                                           "lanes"))(_sharded_counts_impl)
+def _lockstep_counts(a, p0, xr, xa, rz, cfg: EngineConfig, mesh: Mesh, axis):
+    """The lock-step chunk program: `_sharded_counts_impl` on the per-root
+    vmap, jitted under a name of its own."""
+    return _sharded_counts_impl(a, p0, xr, xa, rz, cfg, mesh, axis,
+                                engine="perroot")
+
+
+class _ChunkStep:
+    """The chunk step the driver dispatches, one jitted function per engine,
+    so that a device trace names each engine's program apart: the
+    persistent queue runs as `jit__sharded_counts_impl`, the lock-step
+    vmap as `jit__lockstep_counts`. `lanes` only shapes the persistent
+    program.
+
+    One program per chunk shape and static arguments. Its inputs are not
+    donated: the step returns only scalar counters, so no output could
+    alias a chunk buffer, and the driver drops each chunk's arrays after
+    the dispatch anyway."""
+
+    def __init__(self):
+        self._persistent = jax.jit(
+            _sharded_counts_impl,
+            static_argnames=("cfg", "mesh", "axis", "engine", "lanes"))
+        self._lockstep = jax.jit(_lockstep_counts,
+                                 static_argnames=("cfg", "mesh", "axis"))
+
+    def _pick(self, engine: str, lanes: int):
+        if engine == "persistent":
+            return self._persistent, dict(engine=engine, lanes=lanes)
+        return self._lockstep, {}
+
+    def __call__(self, *args, engine: str = "perroot", lanes: int = 64,
+                 **kw):
+        fn, static = self._pick(engine, lanes)
+        return fn(*args, **static, **kw)
+
+    def lower(self, *args, engine: str = "perroot", lanes: int = 64, **kw):
+        fn, static = self._pick(engine, lanes)
+        return fn.lower(*args, **static, **kw)
+
+
+_sharded_counts = _ChunkStep()
 
 
 @dataclasses.dataclass

@@ -16,7 +16,9 @@ described `v5e:2x2` topology: shapes only, nothing runs. Cases:
 * the chunk step `_sharded_counts` on a one-device mesh at the
   `web_sparse` and `dense_core` shapes, for each engine path;
 * the persistent chunk step at the graph500_s12 benchmark's U=64 shape,
-  whose lane step and refill may hold no element gather of a `pred` array.
+  whose lane step and refill may hold no element gather of a `pred` array;
+* the two widest buckets of G(2000, 0.1): the U=256 lock-step and the
+  U=128 persistent chunk steps, each under its own HLO module name.
 
 The topology is described inside a module fixture (never at import),
 which skips the file where libtpu cannot describe it.
@@ -325,3 +327,39 @@ def test_chunk_step_has_no_phase_pred_gathers(topo, monkeypatch):
     bad = [ln.strip()[:160] for ln in text.splitlines()
            if _GATHER.search(ln) and _PHASE.search(ln)]
     assert not bad, f"{len(bad)} pred element gathers: {bad[:3]}"
+
+
+# ---------------------------------------------------------------------------
+# The two widest buckets of G(2000, 0.1): the lock-step program at U=256
+# and the persistent one at U=128, each under its own module name
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("engine,chunk,u,xc,module", [
+    ("perroot", 734, 256, 128, "jit__lockstep_counts"),
+    ("persistent", 690, 128, 256, "jit__sharded_counts_impl"),
+], ids=["lockstep_u256", "persistent_u128"])
+def test_compile_wide_chunk_step(topo, monkeypatch, engine, chunk, u, xc,
+                                 module):
+    """The chunk programs of G(2000, 0.1)'s U=256 lock-step bucket
+    (734 roots, 128 X rows) and U=128 persistent bucket (690 roots, 256 X
+    rows, 64 lanes) compile for the chip. The lock-step program is named
+    apart from the persistent one, so a device trace splits their time."""
+    from repro.core import driver
+    from repro.core.engine import EngineConfig
+    from repro.kernels.bitset_ops import ops
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    w = u // 32
+    mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+    sh = NamedSharding(mesh, P("data"))
+
+    def S(shape, dt=U32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sh)
+
+    text = driver._sharded_counts.lower(
+        S((1, chunk, u, w)), S((1, chunk, w)), S((1, chunk, xc, w)),
+        S((1, chunk, xc), BOOL), S((1, chunk), I32),
+        cfg=EngineConfig(backend="pivot"), mesh=mesh, axis=("data",),
+        engine=engine, lanes=LANES).compile().as_text()
+    assert re.search(rf"^HloModule {module}\b", text, re.M)
+    assert "%frame_step" in text
