@@ -17,7 +17,7 @@ Deployment model for 1000+ nodes (DESIGN.md §5–§6):
 * **Straggler mitigation** is static balancing: per bucket, roots are sorted
   by a cost estimate (|P|·2^{λ̂} proxy: universe² × mean row popcount) and
   dealt round-robin across shards, so each shard receives the same cost mass
-  (LPT-style). Lockstep waste inside a vmap batch is bounded by chunking:
+  (LPT-style). Lockstep waste inside a batch of lanes is bounded by chunking:
   each shard processes `chunk` roots per device step, so a pathological root
   stalls one chunk, not the epoch.
 * **Fault tolerance**: after every chunk the accumulated counters + cursor
@@ -43,7 +43,7 @@ from repro.core.engine import (BACKENDS, EngineConfig, MCEResult,
                                PIVOT_BACKENDS, PreparedMCE, PrepStream,
                                RootBucket, choose_engine, estimate_costs,
                                root_cost_skew, run_bucket_persistent,
-                               run_root)
+                               run_lockstep)
 from repro.core.spans import span
 from repro.graph.csr import CSRGraph
 
@@ -51,7 +51,7 @@ from repro.graph.csr import CSRGraph
 # surfaces as MCEResult.iters_exhausted instead of silently partial counts.
 # "live_iters"/"lane_iters" are the occupancy pair (useful lane-trips vs
 # lane-trip capacity): occupancy = live/lane. The perroot engine's
-# equivalent is Σ per-root iters over max(iters)·lanes — the lock-step vmap
+# equivalent is Σ per-root iters over max(iters)·lanes — the lock-step walk
 # runs every lane until the slowest root finishes, which is exactly the
 # idle time the persistent queue reclaims (surfaced per query through
 # MCEService.stats). "steals"/"entry_terms"/"window_spills"/"window_hits"
@@ -135,7 +135,7 @@ def _sharded_counts_impl(a, p0, xr, xa, rz, cfg: EngineConfig, mesh: Mesh,
     shard over the flattened ("pod", "data") product). `engine='persistent'`
     runs each shard's chunk through the lane-refill work queue — the
     chunk's cost-descending slice order IS the queue order — instead of
-    one lock-step vmap lane per root."""
+    one lock-step lane per root (`run_lockstep`)."""
 
     def per_shard(a_s, p_s, xr_s, xa_s, rz_s):
         if engine == "persistent":
@@ -147,11 +147,10 @@ def _sharded_counts_impl(a, p0, xr, xa, rz, cfg: EngineConfig, mesh: Mesh,
             spt = max(1, cfg.window_steps)
             out = dict(out, lane_iters=out["iters"] * L * spt)
         else:
-            out = jax.vmap(lambda aa, pp, rr, ll, zz: run_root(
-                aa, pp, rr, ll, zz, cfg))(
-                a_s[0], p_s[0], xr_s[0], xa_s[0], rz_s[0])
+            out = run_lockstep(a_s[0], p_s[0], xr_s[0], xa_s[0], rz_s[0],
+                               cfg)
             # lock-step equivalent of the queue's occupancy pair: every
-            # vmap lane spins until the slowest root's DFS exhausts
+            # lane spins until the slowest root's DFS exhausts
             out = dict(out, live_iters=jnp.sum(out["iters"]),
                        lane_iters=jnp.max(out["iters"]) * a_s.shape[1],
                        steals=jnp.int32(0), entry_terms=jnp.int32(0),
@@ -170,8 +169,8 @@ def _sharded_counts_impl(a, p0, xr, xa, rz, cfg: EngineConfig, mesh: Mesh,
 
 
 def _lockstep_counts(a, p0, xr, xa, rz, cfg: EngineConfig, mesh: Mesh, axis):
-    """The lock-step chunk program: `_sharded_counts_impl` on the per-root
-    vmap, jitted under a name of its own."""
+    """The lock-step chunk program: `_sharded_counts_impl` on the lock-step
+    walk, jitted under a name of its own."""
     return _sharded_counts_impl(a, p0, xr, xa, rz, cfg, mesh, axis,
                                 engine="perroot")
 
@@ -180,7 +179,7 @@ class _ChunkStep:
     """The chunk step the driver dispatches, one jitted function per engine,
     so that a device trace names each engine's program apart: the
     persistent queue runs as `jit__sharded_counts_impl`, the lock-step
-    vmap as `jit__lockstep_counts`. `lanes` only shapes the persistent
+    walk as `jit__lockstep_counts`. `lanes` only shapes the persistent
     program.
 
     One program per chunk shape and static arguments. Its inputs are not

@@ -21,7 +21,8 @@ from repro.core.engine.frames import (BACKENDS, EngineConfig,  # noqa: F401
 from repro.core.engine.loop import (MCEResult, choose_engine,  # noqa: F401
                                     dfs_step, enter_call, root_cost_skew,
                                     run, run_bucket, run_bucket_persistent,
-                                    run_root, run_stream_persistent)
+                                    run_lockstep, run_root,
+                                    run_stream_persistent)
 from repro.core.engine.pipeline import PrepStream, RootSpec  # noqa: F401
 from repro.core.engine.prepare import (PreparedMCE, RootBucket,  # noqa: F401
                                        estimate_costs, prepare)

@@ -2,8 +2,8 @@
 
 Composes the layers: `prepare` stages host-side buckets, `reductions`
 applies the per-call lemmas, `pivot` picks branch sets, and this module
-owns call entry, the explicit stack walk, the vmap over roots, and the
-end-to-end `run()`.
+owns call entry, the explicit stack walk, the lock-step and persistent
+bucket walks over lanes, and the end-to-end `run()`.
 """
 from __future__ import annotations
 
@@ -98,13 +98,14 @@ def dfs_step(cfg, ctx: fr.RootContext, depth, stack, carry, live=None):
     need no gating at all. (§Perf iteration 2, EXPERIMENTS.md.)
 
     `live=None` is the per-root path (depth is known >= 0 inside the
-    while loop). The persistent engine passes `live = depth >= 0` per
-    lane: a dead lane reads/writes clamped slot 0, every side-effect is
-    masked off, and its depth passes through unchanged until a refill
-    revives it. Dead-lane stack writes are harmless: the clamped slot-0
-    write stores the frame's own values back, and the slot-1 child push
-    is overwritten by the next real push before any read (pushes always
-    precede descends)."""
+    while loop). The lane walks (`step_lanes`) pass a per-lane `live`: a
+    dead lane reads/writes clamped slot max(depth, 0), every side-effect
+    is masked off, and its depth passes through unchanged (until a refill
+    revives it, in the persistent engine). Dead-lane stack writes are
+    harmless: the slot write stores the frame's own values back, and the
+    child push lands one slot above the lane's depth, which is dead (a
+    lane cut by max_iters keeps its top frame) or overwritten by the next
+    real push before any read (pushes always precede descends)."""
     lv = jnp.bool_(True) if live is None else live
     d = depth if live is None else jnp.maximum(depth, 0)
     f = stack.read(d)
@@ -235,6 +236,33 @@ def run_root_windowed(a, p0, x_rows, x_alive0, rsz0, cfg: EngineConfig):
     return dict(carry, iters=it, truncated=(d >= 0).astype(jnp.int32))
 
 
+def _enter_root(ctx: fr.RootContext, p0, x_alive0, rsz0, cfg: EngineConfig):
+    """One root's entry call: its first depth (0, or -1 when the root
+    finished inside the call), its stack with the root frame in slot 0,
+    and its counters."""
+    words = ctx.words
+    # root frame: R = {v} (rsz=1), Rb covers universe additions only
+    carry0, push0, frame0 = enter_call(
+        fr.carry_init(cfg, words), cfg, ctx, p0, jnp.zeros(words, U32),
+        fr.mask_to_bitset(x_alive0, ctx.eye_x), rsz0.astype(jnp.int32),
+        jnp.zeros(words, U32))
+    stack0 = FrameStack.alloc(ctx.u + 2, words, ctx.xc_words).push(0, frame0)
+    return jnp.where(push0, jnp.int32(0), jnp.int32(-1)), stack0, carry0
+
+
+def step_lanes(cfg: EngineConfig, A, x_rows, depth, live, stack, carry,
+               eye, eye_x):
+    """One masked `dfs_step` on every lane of an (L, …) batch: lane l walks
+    its own root context (A[l], x_rows[l]), and a lane whose `live` is
+    False changes nothing (`dfs_step`'s dead-lane contract). Shared by the
+    lock-step walk and the persistent engine's step."""
+    def lane_step(a_l, xr_l, d_l, lv_l, stk_l, car_l):
+        ctx = fr.RootContext(A=a_l, x_rows=xr_l, eye=eye, eye_x=eye_x)
+        return dfs_step(cfg, ctx, d_l, stk_l, car_l, live=lv_l)
+
+    return jax.vmap(lane_step)(A, x_rows, depth, live, stack, carry)
+
+
 def run_root(a, p0, x_rows, x_alive0, rsz0, cfg: EngineConfig):
     """Run the full BK subtree of one root. Returns the final carry dict
     plus `iters` (loop iterations used) and `truncated` (1 iff the walk
@@ -246,19 +274,8 @@ def run_root(a, p0, x_rows, x_alive0, rsz0, cfg: EngineConfig):
     HBM stack round-trip."""
     if _window_eligible(cfg):
         return run_root_windowed(a, p0, x_rows, x_alive0, rsz0, cfg)
-    U, words = a.shape
     ctx = fr.make_context(a, x_rows)
-    D = U + 2
-    xal_bits0 = fr.mask_to_bitset(x_alive0, ctx.eye_x)
-
-    carry0 = fr.carry_init(cfg, words)
-    # root frame: R = {v} (rsz=1), Rb covers universe additions only
-    carry0, push0, frame0 = enter_call(
-        carry0, cfg, ctx, p0, jnp.zeros(words, U32), xal_bits0,
-        rsz0.astype(jnp.int32), jnp.zeros(words, U32))
-
-    stack0 = FrameStack.alloc(D, words, ctx.xc_words).push(0, frame0)
-    depth0 = jnp.where(push0, jnp.int32(0), jnp.int32(-1))
+    depth0, stack0, carry0 = _enter_root(ctx, p0, x_alive0, rsz0, cfg)
 
     def cond(s):
         return (s[0] >= 0) & (s[1] < cfg.max_iters)
@@ -274,12 +291,58 @@ def run_root(a, p0, x_rows, x_alive0, rsz0, cfg: EngineConfig):
     return dict(carry, iters=it, truncated=(depth >= 0).astype(jnp.int32))
 
 
+def run_lockstep(a, p0, x_rows, x_alive0, rsz0, cfg: EngineConfig):
+    """Walk a bucket of N roots lock-step, one lane per root: per-root
+    stats equal to `jax.vmap(run_root)`'s, element for element.
+
+    Every root enters under vmap; then ONE `lax.while_loop` runs over the
+    (N, …) batch while any lane is live (depth >= 0 and under
+    cfg.max_iters), each trip a masked `dfs_step` per lane (`step_lanes`).
+    A `while_loop` under vmap would do the same work, but its batching
+    rule, for a per-lane predicate, ends every trip with a select of the
+    new against the old value of every carry leaf, i.e. the whole DFS
+    stack, and the copies that keep both alive (DESIGN.md §2.5). Here a
+    finished lane's step is masked inside `dfs_step` instead, so the
+    stack is only updated in place. `iters` counts each lane's live
+    trips and `truncated` flags lanes cut by cfg.max_iters.
+
+    Window-eligible configs keep the vmapped `run_root_windowed`."""
+    if _window_eligible(cfg):
+        return jax.vmap(partial(run_root_windowed, cfg=cfg))(
+            a, p0, x_rows, x_alive0, rsz0)
+    N, U, words = a.shape
+    XC = x_rows.shape[1]
+    eye = fr.eye_bits(U, words)
+    eye_x = fr.eye_bits(XC, max(-(-XC // WORD), 1))
+    depth0, stack0, carry0 = jax.vmap(
+        lambda a_r, xr_r, p_r, xa_r, rz_r: _enter_root(
+            fr.RootContext(A=a_r, x_rows=xr_r, eye=eye, eye_x=eye_x),
+            p_r, xa_r, rz_r, cfg))(a, x_rows, p0, x_alive0, rsz0)
+
+    def live_of(depth, it):
+        return (depth >= 0) & (it < cfg.max_iters)
+
+    def cond(s):
+        return jnp.any(live_of(s[0], s[1]))
+
+    @scoped("engine.step")
+    def body(s):
+        depth, it, stack, carry = s
+        live = live_of(depth, it)
+        depth, stack, carry = step_lanes(cfg, a, x_rows, depth, live, stack,
+                                         carry, eye, eye_x)
+        return depth, it + live.astype(jnp.int32), stack, carry
+
+    state = (depth0, jnp.zeros((N,), jnp.int32), stack0, carry0)
+    depth, it, _stack, carry = jax.lax.while_loop(cond, body, state)
+    return dict(carry, iters=it, truncated=(depth >= 0).astype(jnp.int32))
+
+
 @partial(jax.jit, static_argnames=("cfg",))
 def run_bucket(a, p0, x_rows, x_alive0, rsz0, cfg: EngineConfig):
-    """vmap the per-root DFS over a bucket. Returns dict of per-root stats."""
-    return jax.vmap(lambda aa, pp, xr, xa, rr: run_root(aa, pp, xr, xa, rr,
-                                                        cfg))(
-        a, p0, x_rows, x_alive0, rsz0)
+    """The lock-step walk of a bucket (`run_lockstep`), jitted. Returns a
+    dict of per-root stats."""
+    return run_lockstep(a, p0, x_rows, x_alive0, rsz0, cfg)
 
 
 # ===========================================================================
@@ -604,14 +667,8 @@ def _persistent_segment(a, p0, x_rows, x_alive0, rsz0, root_base, state,
                 lv = (wdep >= 0) & (wdep < WT - 1)
                 d_in = jnp.clip(wdep, 0, WT - 2)
 
-                def lane_step(a_l, xr_l, d_l, lv_l, stk_l, car_l):
-                    ctx = fr.RootContext(A=a_l, x_rows=xr_l, eye=eye,
-                                         eye_x=eye_x)
-                    return dfs_step(cfg, ctx, d_l, stk_l, car_l,
-                                    live=lv_l)
-
-                ndep, nstk, car = jax.vmap(lane_step)(al_, xrl_, d_in,
-                                                      lv, wstk, car)
+                ndep, nstk, car = step_lanes(cfg, al_, xrl_, d_in, lv,
+                                             wstk, car, eye, eye_x)
                 if full_win:
                     # depth <= U = D − 2 < WT − 1: a push can never reach
                     # the top slot, so no lane ever parks there
@@ -960,15 +1017,10 @@ def _persistent_segment(a, p0, x_rows, x_alive0, rsz0, root_base, state,
         else:
             ls = ls + jnp.sum((depth >= 0).astype(jnp.int32))
 
-            @scoped("engine.step")
-            def lane_step(a_l, xr_l, depth_l, stack_l, carry_l):
-                ctx = fr.RootContext(A=a_l, x_rows=xr_l, eye=eye,
-                                     eye_x=eye_x)
-                return dfs_step(cfg, ctx, depth_l, stack_l, carry_l,
-                                live=depth_l >= 0)
-
-            depth, stack, carry = jax.vmap(lane_step)(al, xrl, depth,
-                                                      stack, carry)
+            with jax.named_scope("engine.step"):
+                depth, stack, carry = step_lanes(cfg, al, xrl, depth,
+                                                 depth >= 0, stack, carry,
+                                                 eye, eye_x)
         return (it + 1, cp, ls, st, et, depth, al, xrl, stack, carry,
                 ws, wh)
 
@@ -996,25 +1048,25 @@ def run_bucket_persistent(a, p0, x_rows, x_alive0, rsz0, cfg: EngineConfig,
     """One jitted while_loop over a (LANES, …) batch of DFS states fed by a
     device-resident root work queue.
 
-    The per-root `run_bucket` vmaps lock-step: every lane spins (masked)
-    until the slowest root in the bucket finishes. Here a lane whose
-    subtree exhausts (`depth < 0`) claims the next unstarted root inside
-    the loop body — shared claim counter + per-lane exclusive-cumsum
+    The lock-step `run_bucket` keeps one lane per root: every lane spins
+    (masked) until the slowest root in the bucket finishes. Here a lane
+    whose subtree exhausts (`depth < 0`) claims the next unstarted root
+    inside the loop body — shared claim counter + per-lane exclusive-cumsum
     offsets, no host round-trip — and reinitializes its stack in place, so
     lanes stay saturated until the queue drains. Roots are consumed in the
     caller's array order (the driver passes its cost-descending canonical
     order, so the queue order IS the checkpoint cursor order).
 
-    The refill phase is wrapped in a real `lax.cond`: unlike the vmapped
-    per-root body (where cond lowers to SELECT), this loop is not under
-    vmap, so iterations with no exhausted lane skip the (LANES, U, W)
-    root-context gathers entirely. Once the queue is claimed out, a second
-    cond runs the STEAL transition (cfg.steal, pivot-family backends): an
-    idle lane splits off half of the deepest live lane's shallowest
-    splittable branch set (slot 0 while it has work, else the frame just
-    above it), so a hub root's subtree spreads across lanes instead of
-    serializing on one (counters and enumerated sets are unchanged —
-    stealing is pure scheduling).
+    The refill phase is wrapped in a real `lax.cond`: this loop is not
+    under vmap (where a cond lowers to SELECT), so iterations with no
+    exhausted lane skip the (LANES, U, W) root-context gathers entirely.
+    Once the queue is claimed out, a second cond runs the STEAL
+    transition (cfg.steal, pivot-family backends): an idle lane splits
+    off half of the deepest live lane's shallowest splittable branch set
+    (slot 0 while it has work, else the frame just above it), so a hub
+    root's subtree spreads across lanes instead of serializing on one
+    (counters and enumerated sets are unchanged — stealing is pure
+    scheduling).
 
     Returns the per-lane carry dict plus scalars: `iters` (loop trips),
     `live_iters` (Σ useful lane-trips: live lanes per trip, plus claims
@@ -1138,7 +1190,7 @@ def choose_engine(costs: Optional[np.ndarray] = None, *, lanes: int = 64,
     """Pick (engine, lanes) for one bucket from its root-cost skew.
 
     skew = max/mean of the per-root cost proxy (`prepare.estimate_costs`).
-    A uniform bucket (skew < threshold) runs the lock-step per-root vmap:
+    A uniform bucket (skew < threshold) runs the lock-step per-root walk:
     every lane finishes together, so a work queue would add claim overhead
     and win nothing. A skewed bucket runs the persistent lane-refill
     queue — that is exactly the regime where lock-step lanes idle behind

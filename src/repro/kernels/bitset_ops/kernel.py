@@ -22,7 +22,7 @@ Two structural rules keep the kernels correct and compilable beyond the
 interpret-mode tests:
 
 * **Batch-safety.** The engine reaches these kernels under `jax.vmap`
-  (`loop.run_bucket` vmaps `run_root`; per-example tracers are 2-D so the
+  (`loop.step_lanes` vmaps `dfs_step`; per-example tracers are 2-D so the
   ops dispatcher takes the pallas path and the pallas batching rule
   prepends the batch axis to the grid). Kernel bodies therefore must not
   read `pl.program_id` or accumulate across grid steps in revisited output

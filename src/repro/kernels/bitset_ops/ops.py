@@ -15,10 +15,10 @@ Batching: the `ndim` guards below only catch *explicit* leading batch dims
 (a caller handing in a 3-D array falls back to ref). They can NOT catch
 `jax.vmap` — inside vmap the per-example tracer is 2-D, so the pallas path
 is taken and jax's pallas batching rule prepends the batch axis to the
-kernel grid. That IS the engine's real call pattern (`loop.run_bucket`
-vmaps `run_root`), so the kernels are written batch-safe (no `program_id`
-reads, no revisited output blocks — see kernel.py) and vmap parity is
-tested per kernel in tests/test_bitset_ops_dispatch.py.
+kernel grid. That IS the engine's real call pattern (`loop.step_lanes`
+vmaps `dfs_step` over lanes), so the kernels are written batch-safe (no
+`program_id` reads, no revisited output blocks — see kernel.py) and vmap
+parity is tested per kernel in tests/test_bitset_ops_dispatch.py.
 
 Every public entry point traces under the device scope `kernels.bitset_ops`
 (`jax.named_scope`), so a profiler trace can sum this layer's device time;

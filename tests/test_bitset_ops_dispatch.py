@@ -112,7 +112,7 @@ def test_and_popcount_rows_pad_boundaries(k, w, block_k):
 
 
 # --------------------------------------------------------------------------
-# vmap parity: loop.run_bucket vmaps run_root, so on TPU the kernels run
+# vmap parity: the engine's lane step vmaps dfs_step, so on TPU the kernels run
 # with a batched grid — inside vmap the per-example tracer is 2-D and the
 # ops dispatcher takes the pallas path (the ndim guard cannot see vmap).
 # These tests run the batching rule in interpret mode; they fail for any

@@ -4,7 +4,7 @@ The hybrid backend adds two checks on top of pivot branching — emit P∪R
 without recursing when P∪X is already a clique (unless an X vertex
 dominates P), and switch to vertex branching on dense subproblems — so
 parity must hold on cliques AND enumerated sets across every dispatch
-path: the lock-step per-root vmap, the persistent lane-refill queue
+path: the lock-step per-root walk, the persistent lane-refill queue
 (side-effects gated on the live mask), and the auto policy. Also covers
 the ISSUE-8 bugfix sweep: `choose_engine` degenerate cost vectors,
 `root_cost_skew` clamping, and `MCEService.query` falsy-override

@@ -7,8 +7,8 @@ Here each kernel, and the driver's jitted chunk step, goes through the
 real TPU compiler (`jax.jit(...).lower(...).compile()`) against a
 described `v5e:2x2` topology: shapes only, nothing runs. Cases:
 
-* the original shapes, plain and vmapped (the engine's `run_bucket`
-  vmaps `run_root`, so the pallas_calls compile with the batch axis
+* the original shapes, plain and vmapped (the engine's lane step vmaps
+  `dfs_step`, so the pallas_calls compile with the batch axis
   prepended to the grid);
 * every kernel at the engine's real bucket widths: W = U/32 for
   U in 32..1024 (`configs/rmce.py`), chunks of 1024 roots, the window
@@ -18,7 +18,8 @@ described `v5e:2x2` topology: shapes only, nothing runs. Cases:
 * the persistent chunk step at the graph500_s12 benchmark's U=64 shape,
   whose lane step and refill may hold no element gather of a `pred` array;
 * the two widest buckets of G(2000, 0.1): the U=256 lock-step and the
-  U=128 persistent chunk steps, each under its own HLO module name.
+  U=128 persistent chunk steps, each under its own HLO module name, and
+  the lock-step one with no select over its whole stack.
 
 The topology is described inside a module fixture (never at import),
 which skips the file where libtpu cannot describe it.
@@ -114,8 +115,9 @@ def test_lower_frame_step(S):
                  S((K, W)), S((W,)), S((W,)), S((W,)))
 
 
-# Vmapped: run_bucket vmaps run_root, so on TPU the pallas_calls compile
-# with the batch axis prepended to the grid — compile exactly that.
+# Vmapped: the engine's lane step vmaps dfs_step, so on TPU the
+# pallas_calls compile with the batch axis prepended to the grid — compile
+# exactly that.
 
 B = 3
 
@@ -363,3 +365,38 @@ def test_compile_wide_chunk_step(topo, monkeypatch, engine, chunk, u, xc,
         engine=engine, lanes=LANES).compile().as_text()
     assert re.search(rf"^HloModule {module}\b", text, re.M)
     assert "%frame_step" in text
+
+
+# a select whose result has the lock-step stack's leading [roots, U + 2]
+_STACK_SELECT = re.compile(r"= [a-z0-9]+\[734,258\b[^ ]* select\(")
+
+
+def test_lockstep_step_has_no_stack_selects(topo, monkeypatch):
+    """The U=256 lock-step chunk program (734 roots, 128 X rows) walks the
+    bucket as one while_loop over a masked lane step (`run_lockstep`), so
+    no iteration selects the new stack against the old: the program holds
+    no select over a `[734,258,…]` stack field, where a while_loop under
+    vmap selects every field of its carry in every trip."""
+    from repro.core import driver
+    from repro.core.engine import EngineConfig
+    from repro.kernels.bitset_ops import ops
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    chunk, u, xc = 734, 256, 128
+    w = u // 32
+    mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+    sh = NamedSharding(mesh, P("data"))
+
+    def S(shape, dt=U32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sh)
+
+    text = driver._sharded_counts.lower(
+        S((1, chunk, u, w)), S((1, chunk, w)), S((1, chunk, xc, w)),
+        S((1, chunk, xc), BOOL), S((1, chunk), I32),
+        cfg=EngineConfig(backend="pivot"), mesh=mesh, axis=("data",),
+        engine="perroot").compile().as_text()
+    assert re.search(r"^HloModule jit__lockstep_counts\b", text, re.M)
+    assert "%frame_step" in text
+    bad = [ln.strip()[:160] for ln in text.splitlines()
+           if _STACK_SELECT.search(ln)]
+    assert not bad, f"{len(bad)} whole-stack selects: {bad[:3]}"
