@@ -21,7 +21,7 @@ Validate (exit 1 + reasons on stderr for malformed files):
 The mce-smoke CI job runs this over every emitted BENCH file, so a
 benchmark that regresses to snapshot-overwriting fails the build;
 `--require` additionally pins the metric fields a benchmark is
-contracted to emit (e.g. the stream workload's boundary_stall/steals).
+contracted to emit (e.g. the stream workload's perbucket_idle/steals).
 """
 from __future__ import annotations
 

@@ -21,9 +21,9 @@ slowest subtree (the hub) while the next slab's roots wait on the host.
 The bucket-spanning engine (`run_stream_persistent`) carries lane state
 across the boundary, so claimed-out slabs hand refills straight to the
 next slab's queue and idle lanes steal from the hub at the tail. Records
-`boundary_stall` (the per-bucket path's idle lane-trip fraction — the
+`perbucket_idle` (the per-bucket path's idle lane-trip fraction — the
 capacity the spanning engine reclaims), `steals`, and the end-to-end
-`speedup`, and asserts exact clique-count AND enumerated-set parity
+`speedup` (per-bucket over spanning), and asserts exact clique-count AND enumerated-set parity
 between the two paths before writing anything.
 
 Emits BENCH_engine.json (last run at top level + full history under
@@ -32,13 +32,9 @@ Emits BENCH_engine.json (last run at top level + full history under
    lockstep_s, persistent_s, speedup,
    lockstep_occupancy, persistent_occupancy, lanes, chunk,
    runs: [{commit, date, ...same metrics}, ...]}
-and with --stream (speedup = windowed-over-spanning at the best K from
-the window_steps sweep; spanning_speedup keeps the older
-per-bucket-over-spanning ratio):
-  {graph, n, m, roots, slabs, lanes, perbucket_s, stream_s, windowed_s,
-   speedup, spanning_speedup, window_steps, window_spills, window_hits,
-   window_sweep, boundary_stall, stream_occupancy, steals, cliques,
-   enumerated, ...}
+and with --stream:
+  {graph, n, m, roots, slabs, lanes, perbucket_s, stream_s, speedup,
+   perbucket_idle, stream_occupancy, steals, cliques, enumerated, ...}
 
   PYTHONPATH=src python -m benchmarks.perf_engine --out BENCH_engine.json
   PYTHONPATH=src python -m benchmarks.perf_engine --stream
@@ -172,8 +168,7 @@ def run(n: int = 4000, m: int = 8, blob: int = 40, blob_p: float = 0.6,
 def run_stream(n: int = 4000, m: int = 6, blob: int = 60,
                blob_p: float = 0.7, bucket: int = 64, slabs: int = 10,
                lanes: int = 32, out_cap: int = 4096,
-               out_json: str | None = "BENCH_engine.json",
-               window_sweep: tuple = (4, 8, 16, 32)):
+               out_json: str | None = "BENCH_engine.json"):
     """Multi-bucket workload: bucket-spanning engine vs per-bucket drains.
 
     The baseline is the pre-spanning engine exactly as the driver ran it:
@@ -183,19 +178,7 @@ def run_stream(n: int = 4000, m: int = 6, blob: int = 60,
     spanning path runs the same slab sequence through
     `run_stream_persistent` with stealing on. Both paths are asserted to
     exact clique-count AND enumerated-set parity before any metric is
-    recorded (stealing and spanning are pure scheduling).
-
-    The windowed sweep then re-runs the spanning path with
-    `window_steps=K` for each K in `window_sweep` — lanes walk K
-    frame-steps per stack round-trip over a resident stack window — and
-    records the best K as `window_steps` with the headline `speedup` =
-    unwindowed-spanning over best-windowed time (this PR's
-    windowed-over-spanning claim; the older per-bucket-over-spanning
-    ratio stays under `spanning_speedup`). The best-K config also runs
-    the enumerated-set parity pass — windowing must neither drop nor
-    reorder-beyond-scheduling any clique."""
-    import dataclasses
-
+    recorded (stealing and spanning are pure scheduling)."""
     import jax
 
     from repro.core.driver import canonical_order
@@ -237,17 +220,14 @@ def run_stream(n: int = 4000, m: int = 6, blob: int = 60,
         return tot, live, cap
 
     def spanning(cfg):
-        spt = max(1, cfg.window_steps)    # windowed trips walk K steps each
         outs, spans = run_stream_persistent(slab_list, cfg, lanes=lanes)
         tot = {k: sum(int(np.asarray(o[k]).sum()) for o in outs)
                for k in ("cliques", "calls", "branches", "sum_px")}
         live = sum(int(o["live_iters"]) for o in outs)
         cap = sum(int(o["iters"]) * int(np.asarray(o["calls"]).shape[0])
-                  for o in outs) * spt
+                  for o in outs)
         steals = sum(int(o["steals"]) for o in outs)
-        spills = sum(int(o.get("window_spills", 0)) for o in outs)
-        hits = sum(int(o.get("window_hits", 0)) for o in outs)
-        return tot, live, cap, steals, len(spans), spills, hits
+        return tot, live, cap, steals, len(spans)
 
     # warmup compiles both paths; second pass measures steady state
     t_pb, t_st = [], []
@@ -256,42 +236,15 @@ def run_stream(n: int = 4000, m: int = 6, blob: int = 60,
         pb_tot, pb_live, pb_cap = perbucket(cfg_base)
         t_pb.append(time.perf_counter() - t0)
         t0 = time.perf_counter()
-        st_tot, st_live, st_cap, steals, n_spans, _, _ = spanning(cfg_span)
+        st_tot, st_live, st_cap, steals, n_spans = spanning(cfg_span)
         t_st.append(time.perf_counter() - t0)
         assert pb_tot == st_tot, (pb_tot, st_tot)
-
-    # ---- windowed-lane sweep: VMEM-resident stack windows inside the
-    # spanning loop. Each K is a separate compile (the window phase is a
-    # static inner loop), so warmup-then-measure per K; windowing is pure
-    # scheduling, so every K must reproduce the unwindowed counters
-    # exactly before its time counts.
-    sweep = []
-    for K in window_sweep:
-        cfg_win = dataclasses.replace(cfg_span, window_steps=K)
-        for _ in range(2):
-            t0 = time.perf_counter()
-            (w_tot, w_live, w_cap, w_steals,
-             _, w_spills, w_hits) = spanning(cfg_win)
-            t_w = time.perf_counter() - t0
-        assert w_tot == st_tot, (K, w_tot, st_tot)
-        sweep.append(dict(window_steps=K, windowed_s=t_w,
-                          speedup=t_st[-1] / t_w,
-                          window_spills=w_spills, window_hits=w_hits,
-                          steals=w_steals,
-                          occupancy=w_live / w_cap if w_cap else 0.0))
-        print(f"window K={K:3d}: {t_w:.2f}s "
-              f"speedup-over-spanning={sweep[-1]['speedup']:.2f}x "
-              f"spills={w_spills} hits={w_hits} "
-              f"occ={sweep[-1]['occupancy']:.2f}", flush=True)
-    best = max(sweep, key=lambda r: r["speedup"])
 
     # enumerated-set parity (untimed): same roots, same cliques, lane and
     # boundary scheduling free — compare (stream-global root, members) sets
     def enum_sets():
         ecfg_b = EngineConfig(steal=False, out_cap=out_cap)
         ecfg_s = EngineConfig(steal=True, out_cap=out_cap)
-        ecfg_w = dataclasses.replace(ecfg_s,
-                                     window_steps=best["window_steps"])
         pb = set()
         for si, slab in enumerate(slab_list):
             L = min(lanes, slab[0].shape[0])
@@ -302,64 +255,41 @@ def run_stream(n: int = 4000, m: int = 6, blob: int = 60,
                 for k in range(int(out["out_n"][l])):
                     pb.add((int(bases[si]) + int(out["out_root"][l, k]),
                             out["out_rows"][l, k].tobytes()))
-        stream_sets = []
-        for ecfg in (ecfg_s, ecfg_w):
-            st = set()
-            outs, _ = run_stream_persistent(slab_list, ecfg, lanes=lanes)
-            for out in outs:
-                out = jax.tree.map(np.asarray, out)
-                assert not out["overflow"].any(), "raise --out-cap"
-                for l in range(out["out_n"].shape[0]):
-                    for k in range(int(out["out_n"][l])):
-                        st.add((int(out["out_root"][l, k]),
-                                out["out_rows"][l, k].tobytes()))
-            stream_sets.append(st)
-        return pb, stream_sets[0], stream_sets[1]
+        st = set()
+        outs, _ = run_stream_persistent(slab_list, ecfg_s, lanes=lanes)
+        for out in outs:
+            out = jax.tree.map(np.asarray, out)
+            assert not out["overflow"].any(), "raise --out-cap"
+            for l in range(out["out_n"].shape[0]):
+                for k in range(int(out["out_n"][l])):
+                    st.add((int(out["out_root"][l, k]),
+                            out["out_rows"][l, k].tobytes()))
+        return pb, st
 
-    pb_set, st_set, win_set = enum_sets()
+    pb_set, st_set = enum_sets()
     assert pb_set == st_set, (
         f"enumerated-set divergence: {len(pb_set - st_set)} only-perbucket, "
         f"{len(st_set - pb_set)} only-stream")
-    assert win_set == st_set, (
-        f"windowed enumerated-set divergence at K={best['window_steps']}: "
-        f"{len(st_set - win_set)} dropped, {len(win_set - st_set)} extra")
     assert len(pb_set) == pb_tot["cliques"]
 
-    boundary_stall = 1.0 - pb_live / pb_cap
+    perbucket_idle = 1.0 - pb_live / pb_cap
     stream_occ = st_live / st_cap
-    spanning_speedup = t_pb[-1] / t_st[-1]
+    speedup = t_pb[-1] / t_st[-1]
     row = dict(graph=f"ba:n={n},m={m}+blob({blob},p={blob_p})",
                n=g.n, m=g.m, roots=total, slabs=len(slab_list),
                lanes=lanes, bucket=bucket,
-               perbucket_s=t_pb[-1], stream_s=t_st[-1],
-               # headline: windowed-over-spanning at the best K; the
-               # PR-9 per-bucket-over-spanning ratio keeps its own key
-               speedup=best["speedup"],
-               spanning_speedup=spanning_speedup,
-               window_steps=best["window_steps"],
-               windowed_s=best["windowed_s"],
-               window_spills=best["window_spills"],
-               window_hits=best["window_hits"],
-               window_sweep=[dict(window_steps=r["window_steps"],
-                                  windowed_s=r["windowed_s"],
-                                  speedup=r["speedup"]) for r in sweep],
-               boundary_stall=boundary_stall,
+               perbucket_s=t_pb[-1], stream_s=t_st[-1], speedup=speedup,
+               perbucket_idle=perbucket_idle,
                stream_occupancy=stream_occ, steals=steals,
                spans=n_spans, cliques=pb_tot["cliques"],
                enumerated=len(pb_set))
     print(f"roots={total} slabs={len(slab_list)} spans={n_spans} "
           f"cliques={row['cliques']} (enumerated parity: {len(pb_set)} "
-          f"sets equal, windowed included)", flush=True)
-    print(f"per-bucket : {t_pb[-1]:.2f}s stall={boundary_stall:.2f} "
+          f"sets equal)", flush=True)
+    print(f"per-bucket : {t_pb[-1]:.2f}s idle={perbucket_idle:.2f} "
           f"(drains at every slab boundary, no stealing)", flush=True)
     print(f"spanning   : {t_st[-1]:.2f}s occupancy={stream_occ:.2f} "
-          f"steals={steals} ({spanning_speedup:.2f}x over per-bucket)",
-          flush=True)
-    print(f"windowed   : {best['windowed_s']:.2f}s at "
-          f"K={best['window_steps']} spills={best['window_spills']} "
-          f"hits={best['window_hits']}", flush=True)
-    print(f"speedup (windowed over spanning): {best['speedup']:.2f}x",
-          flush=True)
+          f"steals={steals} ({speedup:.2f}x over per-bucket)", flush=True)
     if out_json:
         from benchmarks.bench_record import append_run
         append_run(out_json, row)
@@ -384,16 +314,11 @@ if __name__ == "__main__":
                          "vs per-bucket persistent drains")
     ap.add_argument("--slabs", type=int, default=10)
     ap.add_argument("--out-cap", type=int, default=4096)
-    ap.add_argument("--window-sweep", type=int, nargs="+",
-                    default=(4, 8, 16, 32),
-                    help="--stream: window_steps values to autotune over "
-                         "(best K becomes the recorded window_steps)")
     a = ap.parse_args()
     if a.stream:
         run_stream(a.n or 4000, a.m or 6, a.blob or 60,
                    a.blob_p if a.blob_p is not None else 0.7,
-                   a.bucket, a.slabs, a.lanes or 32, a.out_cap, a.out,
-                   window_sweep=tuple(a.window_sweep))
+                   a.bucket, a.slabs, a.lanes or 32, a.out_cap, a.out)
     else:
         run(a.n or 4000, a.m or 8, a.blob or 40,
             a.blob_p if a.blob_p is not None else 0.6,

@@ -61,16 +61,10 @@ def queries():
     return [
         ("pivot/perroot", EngineConfig(backend="pivot"), "perroot",
          {"frame_step", "and_popcount_rows", "and_popcount_argmax"}),
-        ("pivot/persistent/window8",
-         EngineConfig(backend="pivot", dynamic_red=False, window_steps=8),
-         "persistent", {"dfs_step_window_lanes"}),
         ("hybrid/perroot", EngineConfig(backend="hybrid"), "perroot",
          {"clique_counts"}),
         ("rcd/perroot", EngineConfig(backend="rcd"), "perroot",
          {"and_popcount_many"}),
-        ("pivot/perroot/window8",
-         EngineConfig(backend="pivot", dynamic_red=False, window_steps=8),
-         "perroot", {"dfs_step_window"}),
     ]
 
 

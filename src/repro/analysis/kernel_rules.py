@@ -44,9 +44,7 @@ R3 (Mosaic compilability): flag what the installed TPU compiler refuses
   borrow dims from, so the BlockSpec "equals the array dim" escape does
   not exist: Mosaic allocates the scratch tile at compile time and a
   traced/derived dim either fails to lower or pads to a tile silently.
-  The dfs_step_window kernel's resident stack window is the contract's
-  poster child (literal (8, 128) frames); SMEM scratch is scalar memory
-  and exempt.
+  SMEM scratch is scalar memory and exempt.
 
 The dtype rules are static approximations: dtypes are inferred by a
 local forward dataflow over the kernel body and over the module's own

@@ -54,16 +54,13 @@ from repro.graph.csr import CSRGraph
 # equivalent is Σ per-root iters over max(iters)·lanes — the lock-step walk
 # runs every lane until the slowest root finishes, which is exactly the
 # idle time the persistent queue reclaims (surfaced per query through
-# MCEService.stats). "steals"/"entry_terms"/"window_spills"/"window_hits"
-# only move on the persistent engine (adopted branch-set halves, claims
-# that finished inside their entry call, and windowed trips that stopped
-# at a window boundary vs ran fully VMEM-resident); the perroot path
-# zero-fills them so the counter schema — and every checkpoint written
-# against it — is engine-independent. Checkpoints from before a key
-# existed resume via `.get` in `_settle`.
+# MCEService.stats). "steals"/"entry_terms" only move on the persistent
+# engine (adopted branch-set halves and claims that finished inside their
+# entry call); the perroot path zero-fills them so the counter schema —
+# and every checkpoint written against it — is engine-independent.
+# Checkpoints from before a key existed resume via `.get` in `_settle`.
 COUNTER_KEYS = ("cliques", "calls", "branches", "sum_px", "truncated",
-                "live_iters", "lane_iters", "steals", "entry_terms",
-                "window_spills", "window_hits")
+                "live_iters", "lane_iters", "steals", "entry_terms")
 # the work each chunk program does, folded per (u_pad, x_pad, engine) into
 # stats["buckets"]: the operands of a work-based kernel roofline
 BUCKET_KEYS = ("calls", "sum_px", "live_iters", "lane_iters")
@@ -142,10 +139,7 @@ def _sharded_counts_impl(a, p0, xr, xa, rz, cfg: EngineConfig, mesh: Mesh,
             L = min(lanes, a_s.shape[1])
             out = run_bucket_persistent(
                 a_s[0], p_s[0], xr_s[0], xa_s[0], rz_s[0], cfg, lanes=L)
-            # each windowed trip offers up to window_steps frame-steps
-            # per lane, so the occupancy denominator scales with it
-            spt = max(1, cfg.window_steps)
-            out = dict(out, lane_iters=out["iters"] * L * spt)
+            out = dict(out, lane_iters=out["iters"] * L)
         else:
             out = run_lockstep(a_s[0], p_s[0], xr_s[0], xa_s[0], rz_s[0],
                                cfg)
@@ -153,9 +147,7 @@ def _sharded_counts_impl(a, p0, xr, xa, rz, cfg: EngineConfig, mesh: Mesh,
             # lane spins until the slowest root's DFS exhausts
             out = dict(out, live_iters=jnp.sum(out["iters"]),
                        lane_iters=jnp.max(out["iters"]) * a_s.shape[1],
-                       steals=jnp.int32(0), entry_terms=jnp.int32(0),
-                       window_spills=jnp.int32(0),
-                       window_hits=jnp.int32(0))
+                       steals=jnp.int32(0), entry_terms=jnp.int32(0))
         sums = {k: jnp.sum(out[k]).astype(jnp.int32)[None]
                 for k in COUNTER_KEYS}
         return sums

@@ -124,23 +124,6 @@ class EngineConfig:
     # transferable subtree — 'deepest' keeps the legacy deepest-lane
     # heuristic. Pure scheduling either way (bit-identical counters/sets).
     steal_victim: str = "branchiest"
-    # VMEM stack windowing: >0 walks K frame-steps per stack round-trip
-    # with a WINDOW_FRAMES-deep window resident. Eligible per-root walks
-    # (pivot backend, dynamic_red off, counting only) and the persistent
-    # engine's eligible configs use the fused `dfs_step_window`/
-    # `dfs_step_window_lanes` dispatch; other persistent configs window
-    # the ordinary dfs_step (enumeration, dynamic reduction, rcd/hybrid
-    # all work from inside the window — DESIGN.md §2.6/§3). 0 = off.
-    window_steps: int = 0
-    # Engine-step window DEPTH (frames). 0 = auto: the kernel-contract
-    # path always uses the literal `bitset_ops.WINDOW_FRAMES` (its VMEM
-    # scratch shape), and the engine-step path defaults to the FULL stack
-    # — the degenerate window: no re-centering, no boundary stops, the
-    # whole stack rides the trip as loop carry. Set >0 to bound the
-    # engine-step window (e.g. when stack residency is VMEM-limited);
-    # a kernel-eligible config stays kernel-eligible only at 0 or
-    # WINDOW_FRAMES. Pure scheduling — counters/sets bit-identical.
-    window_frames: int = 0
 
 
 # ===========================================================================

@@ -156,86 +156,6 @@ def dfs_step(cfg, ctx: fr.RootContext, depth, stack, carry, live=None):
     return new_depth, stack, carry
 
 
-def _window_eligible(cfg: EngineConfig) -> bool:
-    """Static gate for the FUSED VMEM stack-window walk: the
-    `dfs_step_window`/`dfs_step_window_lanes` kernel contract covers the
-    pivot backend with dynamic reduction off and counting only (no
-    enumeration buffers ride in the window). Ineligible configs with
-    `window_steps > 0` still window in the persistent engine — via the
-    engine-step window, which runs the full `dfs_step` contract."""
-    return (cfg.window_steps > 0 and cfg.backend == "pivot"
-            and not cfg.dynamic_red and not cfg.out_cap
-            and cfg.window_frames in (0, bitops.WINDOW_FRAMES))
-
-
-def run_root_windowed(a, p0, x_rows, x_alive0, rsz0, cfg: EngineConfig):
-    """`run_root` with the DFS stack walked through a T-frame VMEM window.
-
-    The plain walk round-trips the whole frame through HBM on every
-    `dfs_step`. Here the outer while loop advances `cfg.window_steps`
-    frame-steps per trip via the fused `dfs_step_window` dispatch: the
-    top-T stack frames stay resident across those steps, and the HBM
-    stack is touched only at the window boundary — one T-row slice down,
-    one T-row write-back up per trip. The per-frame X0 alive set is not
-    stacked at all: it is a closed form of the frame's Rb (see
-    ref.dfs_step_window), so the window carries (P, B, Xp, Rb, rsz).
-    The window is re-centered each trip (`base = clip(d − T/2, 0, D−T)`),
-    so the walk always enters with both push and pop headroom; the kernel
-    stops early on window overflow/underflow and this wrapper re-slices.
-    Counters are bit-identical to `run_root` (same straight-line masked
-    step semantics, steps merely batched per HBM round-trip)."""
-    U, words = a.shape
-    T = bitops.WINDOW_FRAMES
-    ctx = fr.make_context(a, x_rows)
-    xal_bits0 = fr.mask_to_bitset(x_alive0, ctx.eye_x)
-    carry0 = fr.carry_init(cfg, words)
-    carry0, push0, frame0 = enter_call(
-        carry0, cfg, ctx, p0, jnp.zeros(words, U32), xal_bits0,
-        rsz0.astype(jnp.int32), jnp.zeros(words, U32))
-    alive0 = x_alive0.astype(jnp.int32)
-    # depth never exceeds U = D − 2 (every push consumes a P vertex), so a
-    # freshly centered window always has a free slot above the top frame
-    D = max(U + 2, T)
-    sP = jnp.zeros((D, words), U32).at[0].set(frame0.P)
-    sB = jnp.zeros((D, words), U32).at[0].set(frame0.B)
-    sXp = jnp.zeros((D, words), U32).at[0].set(frame0.Xp)
-    sRb = jnp.zeros((D, words), U32)
-    srsz = jnp.zeros((D,), jnp.int32).at[0].set(frame0.rsz)
-    d0 = jnp.where(push0, jnp.int32(0), jnp.int32(-1))
-
-    def cond(s):
-        return (s[0] >= 0) & (s[1] < cfg.max_iters)
-
-    @scoped("engine.step")
-    def body(s):
-        d, it, sP, sB, sXp, sRb, srsz, carry = s
-        base = jnp.clip(d - T // 2, 0, D - T)
-
-        def sl(arr):
-            return jax.lax.dynamic_slice_in_dim(arr, base, T, axis=0)
-
-        wP, wB, wXp, wRb, wrsz, ctl = bitops.dfs_step_window(
-            a, x_rows, ctx.eye, alive0, sl(sP), sl(sB), sl(sXp), sl(sRb),
-            sl(srsz), d - base, steps=cfg.window_steps)
-
-        def up(arr, w):
-            return jax.lax.dynamic_update_slice_in_dim(arr, w, base, axis=0)
-
-        sP, sB, sXp = up(sP, wP), up(sB, wB), up(sXp, wXp)
-        sRb, srsz = up(sRb, wRb), up(srsz, wrsz)
-        carry = dict(carry,
-                     calls=carry["calls"] + ctl[1],
-                     branches=carry["branches"] + ctl[2],
-                     sum_px=carry["sum_px"] + ctl[3],
-                     cliques=carry["cliques"] + ctl[4])
-        return base + ctl[0], it + ctl[5], sP, sB, sXp, sRb, srsz, carry
-
-    state = (d0, jnp.int32(0), sP, sB, sXp, sRb, srsz, carry0)
-    out = jax.lax.while_loop(cond, body, state)
-    d, it, carry = out[0], out[1], out[7]
-    return dict(carry, iters=it, truncated=(d >= 0).astype(jnp.int32))
-
-
 def _enter_root(ctx: fr.RootContext, p0, x_alive0, rsz0, cfg: EngineConfig):
     """One root's entry call: its first depth (0, or -1 when the root
     finished inside the call), its stack with the root frame in slot 0,
@@ -268,12 +188,8 @@ def run_root(a, p0, x_rows, x_alive0, rsz0, cfg: EngineConfig):
     plus `iters` (loop iterations used) and `truncated` (1 iff the walk
     hit cfg.max_iters with frames still live — the counts are partial).
 
-    With `cfg.window_steps > 0` and an eligible config (pivot backend,
-    dynamic reduction off, counting only) the walk routes through the
-    VMEM stack window (`run_root_windowed`) — same counters, K steps per
-    HBM stack round-trip."""
-    if _window_eligible(cfg):
-        return run_root_windowed(a, p0, x_rows, x_alive0, rsz0, cfg)
+    The reference walk of one root: `run_lockstep` runs a bucket of them
+    in one loop, and tests/test_lockstep_loop.py holds the two equal."""
     ctx = fr.make_context(a, x_rows)
     depth0, stack0, carry0 = _enter_root(ctx, p0, x_alive0, rsz0, cfg)
 
@@ -304,12 +220,7 @@ def run_lockstep(a, p0, x_rows, x_alive0, rsz0, cfg: EngineConfig):
     stack, and the copies that keep both alive (DESIGN.md §2.5). Here a
     finished lane's step is masked inside `dfs_step` instead, so the
     stack is only updated in place. `iters` counts each lane's live
-    trips and `truncated` flags lanes cut by cfg.max_iters.
-
-    Window-eligible configs keep the vmapped `run_root_windowed`."""
-    if _window_eligible(cfg):
-        return jax.vmap(partial(run_root_windowed, cfg=cfg))(
-            a, p0, x_rows, x_alive0, rsz0)
+    trips and `truncated` flags lanes cut by cfg.max_iters."""
     N, U, words = a.shape
     XC = x_rows.shape[1]
     eye = fr.eye_bits(U, words)
@@ -353,10 +264,8 @@ def run_bucket(a, p0, x_rows, x_alive0, rsz0, cfg: EngineConfig):
 def _persistent_state0(cfg: EngineConfig, lanes: int, U: int, words: int,
                        XC: int):
     """Fresh lane state for one same-shape span of the root stream."""
-    # depth never exceeds U (= D − 2), and the windowed segment slices
-    # WINDOW_FRAMES + 1 consecutive slots per lane (T resident frames plus
-    # one spill slot), so guarantee the stack always has slice room
-    D = max(U + 2, bitops.WINDOW_FRAMES + 1)
+    # depth never exceeds U (= D − 2): every push consumes a P vertex
+    D = U + 2
     xc_words = max(-(-XC // WORD), 1)
     track = bool(cfg.out_cap)
     carry0 = jax.tree.map(
@@ -373,9 +282,7 @@ def _persistent_state0(cfg: EngineConfig, lanes: int, U: int, words: int,
             jnp.full((lanes,), jnp.int32(-1)),   # per-lane DFS depth
             jnp.zeros((lanes, U, words), U32),   # per-lane adjacency context
             jnp.zeros((lanes, XC, words), U32),  # per-lane X0 rows
-            stack0, carry0,
-            jnp.int32(0),                        # ws: window spills
-            jnp.int32(0))                        # wh: window hits
+            stack0, carry0)
 
 
 @partial(jax.jit, static_argnames=("cfg", "lanes", "drain"))
@@ -391,27 +298,15 @@ def _persistent_segment(a, p0, x_rows, x_alive0, rsz0, root_base, state,
     `root_base` offsets `cur_root` so enumerated cliques decode against
     the stream-global root index.
 
-    With `cfg.window_steps > 0` each loop trip walks every live lane
-    through up to K frame-steps over a resident stack window instead of
-    one step against the full HBM stack (DESIGN.md §2.6 WINDOW): the trip
-    slices a per-lane window of consecutive stack slots re-centered on
-    the lane's depth, steps it K times — via the lane-batched fused
-    `dfs_step_window_lanes` dispatch when the config is window-eligible
-    (pivot, no dynamic reduction, counting only), else via an inner
-    while_loop of the ordinary `dfs_step` over a T+1-slot window so
-    dynamic reduction, rcd/hybrid branching, early termination, and
-    enumeration buffers all work from inside the window — and writes the
-    window back. Refill claims, steals, slab spans, and checkpoint
-    boundaries always observe a flushed stack because windows live only
-    within a trip's step phase; a lane stopping on window
-    underflow/overflow merely idles for the rest of that trip (its
-    neighbors keep stepping) and re-enters the next trip with a freshly
-    centered window. Windowing is pure scheduling: the same masked step
-    semantics run in a different batching, so counters and enumerated
-    sets are bit-identical to the unwindowed walk."""
+    Each trip runs three phases over the (L, …) lane state: the refill
+    cond (`engine.refill`: exhausted lanes claim the next queue roots),
+    the steal cond once the queue is claimed out (`engine.steal`: an
+    idle lane adopts half of a live lane's branch set), and one masked
+    `dfs_step` on every lane (`step_lanes`, `engine.step`). Refill and
+    steal are pure scheduling: counters and enumerated sets are
+    bit-identical to the lock-step walk's."""
     R, U, words = a.shape
     XC = x_rows.shape[1]
-    L = lanes
     eye = fr.eye_bits(U, words)
     xc_words = max(-(-XC // WORD), 1)
     eye_x = fr.eye_bits(XC, xc_words)
@@ -420,24 +315,6 @@ def _persistent_segment(a, p0, x_rows, x_alive0, rsz0, root_base, state,
     if cfg.steal_victim not in ("branchiest", "deepest"):
         raise ValueError(f"unknown steal_victim {cfg.steal_victim!r} "
                          "(expected 'branchiest' or 'deepest')")
-    windowed = cfg.window_steps > 0
-    # window-eligible configs run the fused lane-batched kernel contract
-    # (aliveness as a closed form of Rb — per-frame xal is NOT maintained
-    # inside the window); everything else windows the engine's dfs_step
-    win_kernel = _window_eligible(cfg)
-    D = int(state[8].P.shape[1])
-    if win_kernel:
-        T = bitops.WINDOW_FRAMES
-        WT = T
-    else:
-        # engine-path window depth: cfg.window_frames, or the full stack
-        # when 0 (the degenerate window — no re-centering, no boundary
-        # stops, the whole stack rides the trip as loop carry; the right
-        # default wherever stack residency is not VMEM-bounded). The +1
-        # is the spill slot; full-depth windows need none (depth <= U =
-        # D - 2 < WT - 1, a push can never overflow).
-        T = cfg.window_frames if cfg.window_frames > 0 else D
-        WT = min(T + 1, D)
 
     def cond(s):
         it, cp, depth = s[0], s[1], s[5]
@@ -545,21 +422,7 @@ def _persistent_segment(a, p0, x_rows, x_alive0, rsz0, root_base, state,
         thief = jnp.argmax(idle).astype(victim.dtype)
         P0, B0 = stack.P[victim, slot], stack.B[victim, slot]
         Xp0, Rb0 = stack.Xp[victim, slot], stack.Rb[victim, slot]
-        rs0 = stack.rsz[victim, slot]
-        if win_kernel:
-            # kernel-contract windows never write per-frame xal (aliveness
-            # is the closed form of Rb), so slots above 0 are stale in the
-            # HBM stack; rebuild the donated frame's alive bitset from the
-            # victim's slot-0 set — alive0' ∧ (Rb ⊆ N(x)) — which is
-            # idempotent when slot == 0 and exact above it (every window
-            # frame's Rb extends slot 0's, see dfs_step_window)
-            alive_root = fr.bitset_to_mask(stack.xal[victim, 0], XC)
-            nrb = fr.popcount(Rb0)
-            alive_d = alive_root & (bitops.and_popcount_rows(
-                xrl[victim], Rb0) == nrb)
-            xa0 = fr.mask_to_bitset(alive_d, eye_x)
-        else:
-            xa0 = stack.xal[victim, slot]
+        rs0, xa0 = stack.rsz[victim, slot], stack.xal[victim, slot]
         # split B at bit rank ceil(|B|/2): keep = lowest-ranked half
         in_b = fr.bitset_to_mask(B0, U)
         ib = in_b.astype(jnp.int32)
@@ -590,454 +453,37 @@ def _persistent_segment(a, p0, x_rows, x_alive0, rsz0, root_base, state,
         st = st + do.astype(jnp.int32)
         return st, depth, al, xrl, stack, carry
 
-    def window_phase(cp, depth, al, xrl, stack, carry):
-        """One trip's K-step window walk (WINDOW, DESIGN.md §2.6).
-
-        Slices a WT-slot window per lane centered on its depth, steps it
-        up to K times HBM-free, writes it back, and reports per-lane
-        steps-done. Dead lanes (depth < 0) pass through untouched.
-
-        STAGED REFILL (engine-step path, counting mode): the trip
-        boundary pre-claims the next pool of queue roots — gathers their
-        contexts and runs their entry calls once, batched — and a lane
-        whose SUBTREE exhausts mid-trip (wdep < 0 at window base 0, not
-        a mere underflow of a higher-based window) swaps a staged root
-        in under a real `lax.cond` instead of idling until the boundary.
-        Staged roots are consumed in death order, so `cp + used` remains
-        the same prefix cursor the boundary refill maintains (§5); their
-        entry-call counter deltas are added exactly once at consumption.
-        Enumerating configs (out_cap > 0) skip staging — reports must
-        land in the shared output buffer at the step that finds them —
-        and fall back to the quorum exit below.
-
-        The walk ends the trip early when a QUORUM of lanes (1/8th, at
-        least one) is exhausted beyond what the staged pool can revive
-        while a refill or steal could re-arm them. A single empty lane
-        idles at most K−1 masked steps — cheaper than paying the trip
-        boundary to revive it — but a pile-up of empty lanes is exactly
-        the drain stall windowing must not reintroduce. Pure scheduling
-        either way — counters/sets bit-identical."""
-        K = cfg.window_steps
-        live_in = depth >= 0
-        base = jnp.clip(depth - T // 2, 0, D - WT)
-        full_win = not win_kernel and WT == D   # degenerate: whole stack
-
-        def sl(arr, b):
-            return jax.lax.dynamic_slice_in_dim(arr, b, WT, axis=0)
-
-        if full_win:
-            wstack = stack                       # base is identically 0
-        else:
-            wstack = jax.tree.map(
-                lambda arr: jax.vmap(sl)(arr, base), stack)
-        wd = jnp.where(live_in, depth - base, jnp.int32(-1))
-        if win_kernel:
-            # lane-batched fused window: per-frame xal is a closed form
-            # of Rb inside the window, seeded from each lane's slot-0
-            # alive set (valid for every window frame — their Rb all
-            # extend slot 0's, so alive0' ∧ Rb ⊆ N(x) is exact)
-            alive0l = jax.vmap(
-                lambda bits: fr.bitset_to_mask(bits, XC))(stack.xal[:, 0])
-            with jax.named_scope("engine.step"):
-                wP, wB, wXp, wRb, wrsz, ctl = bitops.dfs_step_window_lanes(
-                    al, xrl, eye, alive0l.astype(jnp.int32), wstack.P,
-                    wstack.B, wstack.Xp, wstack.Rb, wstack.rsz, wd,
-                    steps=K)
-            wstack = wstack._replace(P=wP, B=wB, Xp=wXp, Rb=wRb, rsz=wrsz)
-            nd = ctl[:, 0]
-            carry = dict(carry,
-                         calls=carry["calls"] + ctl[:, 1],
-                         branches=carry["branches"] + ctl[:, 2],
-                         sum_px=carry["sum_px"] + ctl[:, 3],
-                         cliques=carry["cliques"] + ctl[:, 4])
-            sdone = ctl[:, 5]
-            used = jnp.int32(0)
-            nterm = jnp.int32(0)
-            stolen = jnp.int32(0)
-        else:
-            # engine-step window: the full dfs_step contract (dynamic
-            # reduction, rcd/hybrid, enumeration carry) over a WT-slot
-            # window whose top slot is spill-only — a push landing there
-            # parks the lane until the next trip re-centers its window
-            stage = cfg.out_cap == 0 and R > 0
-            S = max(2, L // 4)
-
-            @scoped("engine.step")
-            def one_step(wdep, wstk, car, sd, al_, xrl_):
-                lv = (wdep >= 0) & (wdep < WT - 1)
-                d_in = jnp.clip(wdep, 0, WT - 2)
-
-                ndep, nstk, car = step_lanes(cfg, al_, xrl_, d_in, lv,
-                                             wstk, car, eye, eye_x)
-                if full_win:
-                    # depth <= U = D − 2 < WT − 1: a push can never reach
-                    # the top slot, so no lane ever parks there
-                    wstk = nstk
-                else:
-                    # dfs_step's "dead-lane writes are harmless" invariant
-                    # assumes slots above the lane's depth are dead —
-                    # false for a lane PARKED at the spill slot (wdep ==
-                    # WT−1, all window slots live), where the masked
-                    # step's child push at d_in+1 == WT−1 clobbers the
-                    # live top frame. That push is the only live-slot
-                    # write a masked step makes (its cur-frame write at
-                    # d_in is value-preserving), so restoring the top
-                    # slot for parked lanes suffices.
-                    parked = wdep >= WT - 1
-                    wstk = jax.tree.map(
-                        lambda n, o: n.at[:, WT - 1].set(jnp.where(
-                            parked.reshape((-1,) + (1,) * (n.ndim - 2)),
-                            o[:, WT - 1], n[:, WT - 1])),
-                        nstk, wstk)
-                wdep = jnp.where(lv, ndep, wdep)
-                return wdep, wstk, car, sd + lv.astype(jnp.int32)
-
-            # While the queue still has roots, one empty lane idles at
-            # most K−1 masked steps — cheaper than a trip boundary, which
-            # is why exit waits for a QUORUM (1/8th of lanes) beyond what
-            # the staged pool can still revive: the boundary refill
-            # revives all of them in one batch. Once the queue is out,
-            # a boundary buys quorum-many steals, so the trip yields at
-            # the same quorum — otherwise the drain tail serializes K
-            # idle steps per revived lane. `k < 1` forces one step of
-            # progress per trip even when idle lanes can't actually be
-            # revived (e.g. nothing splittable to steal).
-            quorum = jnp.int32(max(1, L // 8))
-
-            if stage:
-                # stage the next S queue roots: gather + batched entry
-                # calls, skipped entirely (lax.cond) once the queue is
-                # out. Entry effects land in per-root counter DELTAS,
-                # applied exactly once when a lane consumes the root.
-                @scoped("engine.refill")
-                def do_stage(_):
-                    s_idx = cp + jnp.arange(S, dtype=jnp.int32)
-                    s_ok = s_idx < R
-                    s_cl = jnp.minimum(s_idx, jnp.int32(R - 1))
-                    sa_ = jnp.take(a, s_cl, axis=0)
-                    sxr_ = jnp.take(x_rows, s_cl, axis=0)
-
-                    def stage_entry(ok_l, p_l, a_l, xr_l, xa_l, rz_l):
-                        ctx = fr.RootContext(A=a_l, x_rows=xr_l,
-                                             eye=eye, eye_x=eye_x)
-                        c1, push_l, f0_l = enter_call(
-                            fr.carry_init(cfg, words), cfg, ctx, p_l,
-                            jnp.zeros(words, U32),
-                            fr.mask_to_bitset(xa_l, eye_x),
-                            rz_l.astype(jnp.int32),
-                            jnp.zeros(words, U32), enable=ok_l)
-                        d_l = jnp.stack([c1["calls"], c1["branches"],
-                                         c1["sum_px"], c1["cliques"]])
-                        return d_l, push_l, f0_l
-
-                    sdel_, spush_, sf0_ = jax.vmap(stage_entry)(
-                        s_ok, jnp.take(p0, s_cl, axis=0), sa_, sxr_,
-                        jnp.take(x_alive0, s_cl, axis=0),
-                        jnp.take(rsz0, s_cl, axis=0))
-                    return (jnp.sum(s_ok.astype(jnp.int32)), sdel_,
-                            spush_, sf0_, sa_, sxr_)
-
-                def no_stage(_):
-                    return (jnp.int32(0),
-                            jnp.zeros((S, 4), jnp.int32),
-                            jnp.zeros((S,), jnp.bool_),
-                            Frame(P=jnp.zeros((S, words), U32),
-                                  B=jnp.zeros((S, words), U32),
-                                  Xp=jnp.zeros((S, words), U32),
-                                  Rb=jnp.zeros((S, words), U32),
-                                  rsz=jnp.zeros((S,), jnp.int32),
-                                  xal=jnp.zeros((S, xc_words), U32)),
-                            jnp.zeros((S, U, words), U32),
-                            jnp.zeros((S, XC, words), U32))
-
-                n_stage, sdel, spush, sf0, sa, sxr = jax.lax.cond(
-                    cp < R, do_stage, no_stage, None)
-                # in-trip steal needs the victim's donation slot INSIDE
-                # its window — guaranteed only by the full-depth window
-                # (base is identically 0); bounded windows keep boundary
-                # steals instead
-                trip_steal = can_steal and full_win
-                squorum = jnp.int32(max(1, L // 16))
-
-                @scoped("engine.steal")
-                def steal_multi(cs):
-                    """Multi-way in-trip STEAL: rank-partition the
-                    branchiest victim's donation slot across ALL idle
-                    lanes in one shot. Each piece t takes the branch
-                    bits ranked [t·q, (t+1)·q) with P \\ {lower ranks}
-                    and Xp ∪ {lower ranks} — exactly the state the
-                    victim's own walk would reach before branching on
-                    that piece's first bit, so every branch vertex still
-                    receives one enter_call with an identical frame:
-                    the halving parity lemma applied k ways. Counters
-                    and enumerated sets stay bit-identical."""
-                    wdep, wstk, car, al_, xrl_, stl = cs
-                    idle = wdep < 0          # base == 0: true exhaustion
-                    bcnt = fr.popcount(wstk.B)              # (L, D)
-                    slot_ix = jnp.arange(D, dtype=jnp.int32)[None, :]
-                    live_slot = ((slot_ix <= wdep[:, None])
-                                 & (bcnt >= 2))
-                    splittable = (wdep >= 0) & jnp.any(live_slot, axis=1)
-                    do = jnp.any(idle) & jnp.any(splittable)
-                    slot_l = jnp.argmax(live_slot, axis=1).astype(
-                        jnp.int32)
-                    donor = jnp.take_along_axis(
-                        bcnt, slot_l[:, None], axis=1)[:, 0]
-                    if cfg.steal_victim == "deepest":
-                        victim = jnp.argmax(jnp.where(
-                            splittable, wdep, jnp.int32(-1)))
-                    else:
-                        victim = jnp.argmax(jnp.where(
-                            splittable, donor, jnp.int32(-1)))
-                    slot = slot_l[victim]
-                    nb = bcnt[victim, slot]
-                    B0 = wstk.B[victim, slot]
-                    P0 = wstk.P[victim, slot]
-                    Xp0 = wstk.Xp[victim, slot]
-                    Rb0 = wstk.Rb[victim, slot]
-                    rs0 = wstk.rsz[victim, slot]
-                    xa0 = wstk.xal[victim, slot]
-                    in_b = fr.bitset_to_mask(B0, U)
-                    ib = in_b.astype(jnp.int32)
-                    rank = jnp.cumsum(ib) - ib
-                    n_idle = jnp.sum(idle.astype(jnp.int32))
-                    q = -(-nb // jnp.maximum(n_idle + 1, 1))  # ceil
-                    # thief t ∈ 1..n_idle takes ranks [t·q, (t+1)·q)
-                    ii = idle.astype(jnp.int32)
-                    t = jnp.cumsum(ii) * ii                 # 0 for live
-                    lo = t * q
-                    tk = do & idle & (lo < nb)
-                    low_m = in_b[None, :] & (rank[None, :] < lo[:, None])
-                    pc_m = (in_b[None, :] & (rank[None, :] >= lo[:, None])
-                            & (rank[None, :] < (lo + q)[:, None]))
-                    low_b = jax.vmap(fr.mask_to_bitset,
-                                     in_axes=(0, None))(low_m, eye)
-                    pc_b = jax.vmap(fr.mask_to_bitset,
-                                    in_axes=(0, None))(pc_m, eye)
-
-                    def mixs(new, old):
-                        return jnp.where(
-                            tk.reshape((-1,) + (1,) * (new.ndim - 1)),
-                            new, old)
-
-                    wstk = wstk._replace(
-                        P=wstk.P.at[:, 0].set(
-                            mixs(P0[None] & ~low_b, wstk.P[:, 0])),
-                        B=wstk.B.at[:, 0].set(mixs(pc_b, wstk.B[:, 0])),
-                        Xp=wstk.Xp.at[:, 0].set(
-                            mixs(Xp0[None] | low_b, wstk.Xp[:, 0])),
-                        Rb=wstk.Rb.at[:, 0].set(
-                            mixs(jnp.broadcast_to(Rb0, (L,) + Rb0.shape),
-                                 wstk.Rb[:, 0])),
-                        rsz=wstk.rsz.at[:, 0].set(
-                            jnp.where(tk, rs0, wstk.rsz[:, 0])),
-                        xal=wstk.xal.at[:, 0].set(
-                            mixs(jnp.broadcast_to(xa0, (L,) + xa0.shape),
-                                 wstk.xal[:, 0])))
-                    # the victim keeps piece 0 (ranks < q)
-                    keep = fr.mask_to_bitset(in_b & (rank < q), eye)
-                    wstk = wstk._replace(B=wstk.B.at[victim, slot].set(
-                        jnp.where(do, keep, wstk.B[victim, slot])))
-                    wdep = jnp.where(tk, jnp.int32(0), wdep)
-                    al_ = mixs(jnp.broadcast_to(
-                        al_[victim][None], al_.shape), al_)
-                    xrl_ = mixs(jnp.broadcast_to(
-                        xrl_[victim][None], xrl_.shape), xrl_)
-                    stl = stl + jnp.sum(tk.astype(jnp.int32))
-                    return wdep, wstk, car, al_, xrl_, stl
-
-                @scoped("engine.refill")
-                def consume(cs):
-                    """Swap staged roots into dead lanes, death order."""
-                    wdep, wstk, car, al_, xrl_, used, ntm = cs
-                    dead = (wdep < 0) & (base == 0)
-                    di = dead.astype(jnp.int32)
-                    idx = used + jnp.cumsum(di) - di
-                    idxc = jnp.minimum(idx, jnp.int32(S - 1))
-                    tk = dead & (idx < n_stage)
-
-                    def mix(new, old):
-                        return jnp.where(
-                            tk.reshape((-1,) + (1,) * (new.ndim - 1)),
-                            new, old)
-
-                    # dead lanes sit at base 0: window slot 0 IS stack
-                    # slot 0, the same slot the boundary refill writes
-                    wstk = wstk._replace(**{
-                        k: w.at[:, 0].set(
-                            mix(jnp.take(n, idxc, axis=0), w[:, 0]))
-                        for k, w, n in zip(Frame._fields,
-                                           wstk, sf0)})
-                    push = jnp.take(spush, idxc)
-                    wdep = jnp.where(tk & push, jnp.int32(0), wdep)
-                    al_ = mix(jnp.take(sa, idxc, axis=0), al_)
-                    xrl_ = mix(jnp.take(sxr, idxc, axis=0), xrl_)
-                    dl = (jnp.take(sdel, idxc, axis=0)
-                          * tk.astype(jnp.int32)[:, None])
-                    car = dict(car,
-                               calls=car["calls"] + dl[:, 0],
-                               branches=car["branches"] + dl[:, 1],
-                               sum_px=car["sum_px"] + dl[:, 2],
-                               cliques=car["cliques"] + dl[:, 3])
-                    used = used + jnp.sum(tk.astype(jnp.int32))
-                    ntm = ntm + jnp.sum((tk & ~push).astype(jnp.int32))
-                    return wdep, wstk, car, al_, xrl_, used, ntm
-
-                def wbody(ws):
-                    (k, wdep, wstk, car, sd, al_, xrl_, used, ntm,
-                     stl) = ws
-                    may = (jnp.any((wdep < 0) & (base == 0))
-                           & (used < n_stage))
-                    wdep, wstk, car, al_, xrl_, used, ntm = jax.lax.cond(
-                        may, consume, lambda cs: cs,
-                        (wdep, wstk, car, al_, xrl_, used, ntm))
-                    if trip_steal:
-                        n_dead = jnp.sum((wdep < 0).astype(jnp.int32))
-                        may_s = ((cp + used >= R)
-                                 & (n_dead >= squorum)
-                                 & jnp.any(wdep >= 0))
-                        (wdep, wstk, car, al_, xrl_,
-                         stl) = jax.lax.cond(
-                            may_s, steal_multi, lambda cs: cs,
-                            (wdep, wstk, car, al_, xrl_, stl))
-                    wdep, wstk, car, sd = one_step(wdep, wstk, car, sd,
-                                                   al_, xrl_)
-                    return (k + 1, wdep, wstk, car, sd, al_, xrl_,
-                            used, ntm, stl)
-
-                def wcond(ws):
-                    k, wdep, used = ws[0], ws[1], ws[7]
-                    dead = (wdep < 0) & (base == 0)
-                    n_dead = jnp.sum(dead.astype(jnp.int32))
-                    pool_left = n_stage - used
-                    exit_refill = ((cp + used < R)
-                                   & (n_dead - pool_left >= quorum))
-                    # with in-trip stealing the trip never yields for a
-                    # steal — the split happens under a cond inside
-                    exit_steal = (jnp.bool_(can_steal and not trip_steal)
-                                  & (cp + used >= R)
-                                  & (n_dead >= quorum))
-                    alive = jnp.any((wdep >= 0) & (wdep < WT - 1))
-                    return ((k < K)
-                            & (alive | (jnp.any(dead)
-                                        & (used < n_stage)))
-                            & ((k < 1) | ~(exit_refill | exit_steal)))
-
-                (_, nd, wstack, carry, sdone, al, xrl, used,
-                 nterm, stolen) = jax.lax.while_loop(
-                    wcond, wbody,
-                    (jnp.int32(0), wd, wstack, carry,
-                     jnp.zeros_like(wd), al, xrl, jnp.int32(0),
-                     jnp.int32(0), jnp.int32(0)))
-            else:
-                def wbody(ws):
-                    k, wdep, wstk, car, sd = ws
-                    wdep, wstk, car, sd = one_step(wdep, wstk, car, sd,
-                                                   al, xrl)
-                    return k + 1, wdep, wstk, car, sd
-
-                def wcond(ws):
-                    k, wdep = ws[0], ws[1]
-                    # idle-but-revivable: exhausted during this trip
-                    # (window at base 0 — a higher-based underflow is a
-                    # re-center, not an exhaustion) or dead at entry
-                    idle = ~live_in | ((wdep < 0) & (base == 0))
-                    n_idle = jnp.sum(idle.astype(jnp.int32))
-                    exit_refill = (cp < R) & (n_idle >= quorum)
-                    exit_steal = (jnp.bool_(can_steal) & (cp >= R)
-                                  & (n_idle >= quorum))
-                    return ((k < K)
-                            & jnp.any((wdep >= 0) & (wdep < WT - 1))
-                            & ((k < 1) | ~(exit_refill | exit_steal)))
-
-                _, nd, wstack, carry, sdone = jax.lax.while_loop(
-                    wcond, wbody,
-                    (jnp.int32(0), wd, wstack, carry,
-                     jnp.zeros_like(wd)))
-                used = jnp.int32(0)
-                nterm = jnp.int32(0)
-                stolen = jnp.int32(0)
-
-        def up(arr, win, b):
-            return jax.lax.dynamic_update_slice_in_dim(arr, win, b, axis=0)
-
-        if full_win:
-            stack = wstack
-        else:
-            stack = jax.tree.map(
-                lambda arr, win: jax.vmap(up)(arr, win, base), stack,
-                wstack)
-        # nd >= 0 also covers lanes REVIVED mid-trip by staged refill
-        # (dead at entry, live at exit); their base is 0 by definition
-        depth = jnp.where(live_in | (nd >= 0), base + nd, depth)
-        # a lane that ran all K steps stayed window-resident the whole
-        # trip (hit); one that stopped early paid a window boundary —
-        # overflow, underflow, or subtree exhaustion (spill)
-        fin = sdone >= jnp.int32(K)
-        hits = jnp.sum((live_in & fin).astype(jnp.int32))
-        spills = jnp.sum((live_in & ~fin).astype(jnp.int32))
-        return (depth, al, xrl, stack, carry, jnp.sum(sdone), spills,
-                hits, used, nterm, stolen)
-
     def body(s):
-        it, cp, ls, st, et, depth, al, xrl, stack, carry, ws, wh = s
+        it, cp, ls, st, et, depth, al, xrl, stack, carry = s
         need = (cp < R) & jnp.any(depth < 0)
         cp, ls, et, depth, al, xrl, stack, carry = jax.lax.cond(
             need, refill, lambda args: args,
             (cp, ls, et, depth, al, xrl, stack, carry))
         if can_steal:
             # only once the queue can no longer feed the idle lane — while
-            # roots remain, claiming is strictly cheaper than splitting.
-            # A windowed body whose trips steal IN-TRIP (staged, full-
-            # depth windows) needs the boundary steal only as a safety
-            # net (e.g. a trip that exited with every lane dead); other
-            # windowed bodies repeat it up to quorum times: their trips
-            # yield once a quorum of lanes idles, so the boundary must
-            # re-arm the whole quorum, not just one lane (each repeat
-            # picks a fresh thief, and a fresh victim once the last
-            # donor's halved slot stops being the branchiest).
-            in_trip = (windowed and not win_kernel and WT == D
-                       and cfg.out_cap == 0 and R > 0)
-            n_st = 1 if in_trip else (max(1, L // 8) if windowed else 1)
-            for _ in range(n_st):
-                may = jnp.any(depth < 0) & jnp.any(depth >= 0) & (cp >= R)
-                st, depth, al, xrl, stack, carry = jax.lax.cond(
-                    may, steal, lambda args: args,
-                    (st, depth, al, xrl, stack, carry))
-        if windowed:
-            (depth, al, xrl, stack, carry, steps_done, spills, hits,
-             used, nterm, stolen) = window_phase(cp, depth, al, xrl,
-                                                stack, carry)
-            cp = cp + used          # staged claims advance the cursor
-            ls = ls + steps_done + nterm
-            et = et + nterm         # staged roots done inside entry
-            st = st + stolen        # in-trip multi-way steal pieces
-            ws = ws + spills
-            wh = wh + hits
-        else:
-            ls = ls + jnp.sum((depth >= 0).astype(jnp.int32))
-
-            with jax.named_scope("engine.step"):
-                depth, stack, carry = step_lanes(cfg, al, xrl, depth,
-                                                 depth >= 0, stack, carry,
-                                                 eye, eye_x)
-        return (it + 1, cp, ls, st, et, depth, al, xrl, stack, carry,
-                ws, wh)
+            # roots remain, claiming is strictly cheaper than splitting
+            may = jnp.any(depth < 0) & jnp.any(depth >= 0) & (cp >= R)
+            st, depth, al, xrl, stack, carry = jax.lax.cond(
+                may, steal, lambda args: args,
+                (st, depth, al, xrl, stack, carry))
+        ls = ls + jnp.sum((depth >= 0).astype(jnp.int32))
+        with jax.named_scope("engine.step"):
+            depth, stack, carry = step_lanes(cfg, al, xrl, depth, depth >= 0,
+                                             stack, carry, eye, eye_x)
+        return it + 1, cp, ls, st, et, depth, al, xrl, stack, carry
 
     return jax.lax.while_loop(cond, body, state)
 
 
 def _persistent_out(state, R: int):
     """Realize a lane state into the public output dict."""
-    (it, cp, ls, st, et, depth, _al, _xrl, _stack, carry, ws, wh) = state
+    (it, cp, ls, st, et, depth, _al, _xrl, _stack, carry) = state
     out = dict(carry)
     out["iters"] = it
     out["live_iters"] = ls
     out["claimed"] = cp
     out["steals"] = st
     out["entry_terms"] = et
-    out["window_spills"] = ws
-    out["window_hits"] = wh
     out["truncated"] = ((cp < R) | jnp.any(depth >= 0)).astype(jnp.int32)
     return out
 
@@ -1062,8 +508,9 @@ def run_bucket_persistent(a, p0, x_rows, x_alive0, rsz0, cfg: EngineConfig,
     exhausted lane skip the (LANES, U, W) root-context gathers entirely.
     Once the queue is claimed out, a second cond runs the STEAL
     transition (cfg.steal, pivot-family backends): an idle lane splits
-    off half of the deepest live lane's shallowest splittable branch set
-    (slot 0 while it has work, else the frame just above it), so a hub
+    off half of a live lane's shallowest splittable branch set (slot 0
+    while it has work, else the frame just above it; the lane is picked
+    by `cfg.steal_victim`), so a hub
     root's subtree spreads across lanes instead of serializing on one
     (counters and enumerated sets are unchanged — stealing is pure
     scheduling).
@@ -1075,12 +522,8 @@ def run_bucket_persistent(a, p0, x_rows, x_alive0, rsz0, cfg: EngineConfig,
     (iters·lanes)), `claimed`, `steals` (adopted branch-set halves),
     `entry_terms` (claims that completed inside their entry call — for
     the hybrid backend this includes every root early-terminated by the
-    refill-phase census), `window_spills`/`window_hits` (windowed trips
-    that stopped early at a window boundary vs ran all K steps resident;
-    both 0 when `cfg.window_steps == 0`), and `truncated` (1 iff
-    cfg.max_iters hit with work remaining). With `cfg.window_steps > 0`
-    `live_iters` counts executed frame-steps (each trip offers up to K
-    per lane), so occupancy denominators scale by the window depth."""
+    refill-phase census), and `truncated` (1 iff cfg.max_iters hit with
+    work remaining)."""
     R, U, words = a.shape
     XC = x_rows.shape[1]
     state0 = _persistent_state0(cfg, lanes, U, words, XC)
@@ -1253,8 +696,7 @@ def run(g: CSRGraph, *, global_red: bool = True, dynamic_red: bool = True,
         max_x_rows: int = 8192,
         split_threshold: Optional[int] = None,
         engine: str = "perroot", lanes: int = 64,
-        steal: bool = True, steal_victim: str = "branchiest",
-        window_steps: int = 0) -> MCEResult:
+        steal: bool = True, steal_victim: str = "branchiest") -> MCEResult:
     """End-to-end single-host MCE: prepare on host, run buckets on device.
 
     `engine='persistent'` routes each bucket through the lane-refill work
@@ -1272,8 +714,7 @@ def run(g: CSRGraph, *, global_red: bool = True, dynamic_red: bool = True,
                    split_threshold=split_threshold)
     cfg = EngineConfig(dynamic_red=dynamic_red, backend=backend,
                        out_cap=out_cap if enumerate_cliques else 0,
-                       steal=steal, steal_victim=steal_victim,
-                       window_steps=window_steps)
+                       steal=steal, steal_victim=steal_victim)
     total = MCEResult(cliques=len(prep.pre_reported), calls=0, branches=0,
                       sum_px=0, pre_reported=len(prep.pre_reported),
                       enumerated=list(prep.pre_reported) if enumerate_cliques else None)
@@ -1286,23 +727,16 @@ def run(g: CSRGraph, *, global_red: bool = True, dynamic_red: bool = True,
         outs, spans = run_stream_persistent(slabs, cfg, lanes=lanes)
         prefix = np.cumsum([0] + [b.num_roots for b in prep.buckets])
         total.stats = dict(iters=0, live_iters=0, lane_iters=0, steals=0,
-                           entry_terms=0, window_spills=0, window_hits=0,
-                           spans=len(spans))
-        # a windowed trip offers up to K steps per lane, so the occupancy
-        # denominator (lane_iters) scales by the window depth
-        spt = max(1, window_steps)
+                           entry_terms=0, spans=len(spans))
         for out, (lo, hi) in zip(outs, spans):
             out = jax.tree.map(np.asarray, out)
             total.stats["iters"] += int(out["iters"])
             total.stats["live_iters"] += int(out["live_iters"])
             # carry is per-lane, so its leading dim is this span's lanes
             total.stats["lane_iters"] += (int(out["iters"])
-                                          * int(out["calls"].shape[0])
-                                          * spt)
+                                          * int(out["calls"].shape[0]))
             total.stats["steals"] += int(out["steals"])
             total.stats["entry_terms"] += int(out["entry_terms"])
-            total.stats["window_spills"] += int(out["window_spills"])
-            total.stats["window_hits"] += int(out["window_hits"])
             total.cliques += int(out["cliques"].sum())
             # padded no-op roots (compile-count hygiene) are one call each
             total.calls += (int(out["calls"].sum())

@@ -56,7 +56,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 
 DEFAULT_BLOCK_K = 256
@@ -331,355 +330,3 @@ def and_popcount_many(rows: jnp.ndarray, masks: jnp.ndarray,
         interpret=interpret,
     )(rows, masks)
     return out[:m, :k]
-
-
-# ===========================================================================
-# dfs_step_window — K fused BK frame-steps with the top-T stack frames in
-# VMEM scratch (DESIGN.md §2.6/§3)
-# ===========================================================================
-
-# Literal VMEM scratch geometry for the stack window. The scratch shapes
-# must be (8, 128)-aligned literals (mce_lint R3): 8 frames × 128 words
-# bounds the eligible problem at U ≤ 4096 vertices per root universe.
-WINDOW_FRAMES = 8
-WINDOW_WORDS = 128
-
-
-def _window_walk(a, xr, eye, alive0, read_a, read_x,
-                 sp_ref, sb_ref, sxp_ref, srb_ref, srsz_ref,
-                 t, w, u, xc, d0, steps):
-    """Shared fori body of the window kernels: up to `steps` masked DFS
-    frame-steps over the VMEM scratch window.
-
-    `a`/`xr`/`eye`/`alive0` are the materialized per-invocation constants;
-    `read_a(i)`/`read_x(i)` load one (1, W) row via a ref dynamic slice
-    (the per-root and lane-batched kernels differ only in ref rank, which
-    these closures absorb). Every count and selection stays in int32:
-    Mosaic has no float iota and no uint32 -> float32 cast, and it reduces
-    int32 over either axis. Argmax/first-bit selections use the
-    min-of-masked-iota idiom so tie-breaking matches jnp.argmax (first
-    occurrence wins) bit-for-bit. Returns the final (dloc, done, calls,
-    branches, sum_px, cliques, steps_done) state."""
-    big = jnp.int32(1 << 30)
-    iw = jax.lax.broadcasted_iota(jnp.int32, (1, w), 1)
-    iu = jax.lax.broadcasted_iota(jnp.int32, (u, 1), 0)
-    ix = jax.lax.broadcasted_iota(jnp.int32, (xc, 1), 0)
-
-    def step(_, s):
-        dl, done, calls, branches, spx, clq, sdone = s
-        d = jnp.clip(dl, 0, t - 1)
-        fP = sp_ref[pl.ds(d, 1), :w]                       # (1, w)
-        fB = sb_ref[pl.ds(d, 1), :w]
-        fXp = sxp_ref[pl.ds(d, 1), :w]
-        fRb = srb_ref[pl.ds(d, 1), :w]
-        frsz = srsz_ref[d]
-        has_branch = jnp.max(jnp.where(fB != 0, 1, 0)) > 0
-        blocked = has_branch & (dl >= t - 1)
-        act = (done == 0) & ~blocked & (dl >= 0)
-        done = jnp.where(blocked | (dl < 0), jnp.int32(1), done)
-
-        # first set bit of B: per-word low-bit position, min over words
-        low = jnp.bitwise_and(fB, jnp.uint32(0) - fB)
-        pos = _popcount(low - jnp.uint32(1))
-        cand = jnp.where(fB != 0, iw * 32 + pos, big)
-        wv = jnp.clip(jnp.min(cand), 0, u - 1)
-        wbit = jnp.where(iw == wv // 32,
-                         jnp.uint32(1) << (wv % 32).astype(jnp.uint32),
-                         jnp.uint32(0))
-        wrow = read_a(wv)
-        childP = jnp.bitwise_and(fP, wrow)
-        childXp = jnp.bitwise_and(fXp, wrow)
-        childRb = jnp.bitwise_or(fRb, wbit)
-
-        deg = _pcsum(jnp.bitwise_and(a, childP))           # (u, 1)
-        # gather-free P ∪ X membership: one-hot rows AND the member bitset
-        inpool = _pcsum(jnp.bitwise_and(
-            eye, jnp.bitwise_or(childP, childXp))) > 0
-        pcx = _pcsum(jnp.bitwise_and(xr, childP))          # (xc, 1)
-        # closed-form alive set from Rb (see ref.dfs_step_window)
-        pc_rb = jnp.sum(_pcsum(childRb))
-        alive = jnp.where(
-            (alive0 > 0) & (_pcsum(jnp.bitwise_and(xr, childRb)) == pc_rb),
-            1, 0)
-
-        # enter_call, restricted: counts + leaf report + pivot branch set
-        en = act & has_branch
-        en_i = en.astype(jnp.int32)
-        branches = branches + en_i
-        calls = calls + en_i
-        pc_p = jnp.sum(_pcsum(childP))
-        pc_x = jnp.sum(_pcsum(childXp))
-        nal = jnp.sum(alive)
-        spx = spx + (pc_p + pc_x + nal) * en_i
-        p_empty = pc_p == 0
-        x_empty = (nal == 0) & (pc_x == 0)
-        crsz = frsz + 1
-        clq = clq + (p_empty & x_empty & (crsz >= 2) & en).astype(jnp.int32)
-        push = ~p_empty & en
-
-        su_s = jnp.where(inpool, deg, -1)
-        su = jnp.max(su_s)
-        best_u = jnp.min(jnp.where(su_s == su, iu, big))
-        sx_s = jnp.where(alive > 0, pcx, -1)
-        sx = jnp.max(sx_s)
-        best_x = jnp.min(jnp.where(sx_s == sx, ix, big))
-        use_x = sx > su
-        rowu = read_a(best_u)
-        rowx = read_x(jnp.clip(best_x, 0, xc - 1))
-        pivot_row = jnp.where(use_x, rowx, rowu)
-        childB = jnp.bitwise_and(childP, jnp.bitwise_not(pivot_row))
-
-        # current frame: P \ w, X ∪ w, B \ w (identity when not branching)
-        nwbit = jnp.bitwise_not(wbit)
-        sp_ref[pl.ds(d, 1), :w] = jnp.where(
-            en, jnp.bitwise_and(fP, nwbit), fP)
-        sxp_ref[pl.ds(d, 1), :w] = jnp.where(
-            en, jnp.bitwise_or(fXp, wbit), fXp)
-        sb_ref[pl.ds(d, 1), :w] = jnp.where(
-            en, jnp.bitwise_and(fB, nwbit), fB)
-        # child frame at d+1 (clamped; identity unless descended into)
-        cd = jnp.clip(d + 1, 0, t - 1)
-        sp_ref[pl.ds(cd, 1), :w] = jnp.where(
-            push, childP, sp_ref[pl.ds(cd, 1), :w])
-        sb_ref[pl.ds(cd, 1), :w] = jnp.where(
-            push, childB, sb_ref[pl.ds(cd, 1), :w])
-        sxp_ref[pl.ds(cd, 1), :w] = jnp.where(
-            push, childXp, sxp_ref[pl.ds(cd, 1), :w])
-        srb_ref[pl.ds(cd, 1), :w] = jnp.where(
-            push, childRb, srb_ref[pl.ds(cd, 1), :w])
-        srsz_ref[cd] = jnp.where(push, crsz, srsz_ref[cd])
-
-        dl = jnp.where(act,
-                       jnp.where(has_branch,
-                                 jnp.where(push, dl + 1, dl), dl - 1), dl)
-        sdone = sdone + act.astype(jnp.int32)
-        return dl, done, calls, branches, spx, clq, sdone
-
-    z = jnp.int32(0)
-    return jax.lax.fori_loop(0, steps, step, (d0, z, z, z, z, z, z))
-
-
-def _dfs_step_window_kernel(a_ref, xr_ref, eye_ref, alive_ref,
-                            winp_ref, winb_ref, winxp_ref, winrb_ref,
-                            winrsz_ref, dloc_ref,
-                            outp_ref, outb_ref, outxp_ref, outrb_ref,
-                            outrsz_ref, ctl_ref,
-                            sp_ref, sb_ref, sxp_ref, srb_ref, srsz_ref,
-                            *, steps):
-    """One invocation = up to `steps` masked DFS frame-steps.
-
-    The window frames live in VMEM scratch for the whole invocation (the
-    per-frame |R| sizes and the control scalars ride in SMEM); the HBM
-    stack is untouched until the engine wrapper writes the returned
-    window back. The step loop itself is `_window_walk`, shared with the
-    lane-batched variant below.
-    """
-    t, w = winp_ref.shape
-    u = a_ref.shape[0]
-    xc = xr_ref.shape[0]
-    sp_ref[:, :w] = winp_ref[...]
-    sb_ref[:, :w] = winb_ref[...]
-    sxp_ref[:, :w] = winxp_ref[...]
-    srb_ref[:, :w] = winrb_ref[...]
-    for i in range(t):
-        srsz_ref[i] = winrsz_ref[0, i]
-    s = _window_walk(a_ref[...], xr_ref[...], eye_ref[...],
-                     alive_ref[...],
-                     lambda i: a_ref[pl.ds(i, 1), :],
-                     lambda i: xr_ref[pl.ds(i, 1), :],
-                     sp_ref, sb_ref, sxp_ref, srb_ref, srsz_ref,
-                     t, w, u, xc, dloc_ref[0, 0], steps)
-    z = jnp.int32(0)
-    outp_ref[...] = sp_ref[:, :w]
-    outb_ref[...] = sb_ref[:, :w]
-    outxp_ref[...] = sxp_ref[:, :w]
-    outrb_ref[...] = srb_ref[:, :w]
-    for i in range(t):
-        outrsz_ref[0, i] = srsz_ref[i]
-    ctl_ref[0, 0] = s[0]
-    ctl_ref[0, 1] = s[2]
-    ctl_ref[0, 2] = s[3]
-    ctl_ref[0, 3] = s[4]
-    ctl_ref[0, 4] = s[5]
-    ctl_ref[0, 5] = s[6]
-    ctl_ref[0, 6] = z
-    ctl_ref[0, 7] = z
-
-
-def _dfs_step_window_lanes_kernel(a_ref, xr_ref, eye_ref, alive_ref,
-                                  winp_ref, winb_ref, winxp_ref, winrb_ref,
-                                  winrsz_ref, dloc_ref,
-                                  outp_ref, outb_ref, outxp_ref, outrb_ref,
-                                  outrsz_ref, ctl_ref,
-                                  sp_ref, sb_ref, sxp_ref, srb_ref,
-                                  srsz_ref, *, steps):
-    """Lane-batched window walk: one grid step = one lane's K frame-steps.
-
-    Every input/output block is that lane's plane of the (L, …) array —
-    the (1, U, W) adjacency, (1, XC, W) X rows, (1, T, W) windows in
-    VMEM, and the per-lane scalars (dloc in, rsz, ctl out) in
-    (1, 1, ·) SMEM lane rows. The (8, 128) VMEM scratch window is
-    re-initialized from
-    the lane's own block at the top of every grid step and written back
-    at the end — no state crosses grid steps (no `pl.program_id` reads,
-    no revisited blocks), so the batched-grid lowering under `jax.vmap`
-    stays correct and lanes never observe each other: a lane that stops
-    on underflow/overflow simply burns the rest of its own grid step
-    without stalling its neighbors.
-    """
-    t, w = winp_ref.shape[1], winp_ref.shape[2]
-    u = a_ref.shape[1]
-    xc = xr_ref.shape[1]
-    sp_ref[:, :w] = winp_ref[0]
-    sb_ref[:, :w] = winb_ref[0]
-    sxp_ref[:, :w] = winxp_ref[0]
-    srb_ref[:, :w] = winrb_ref[0]
-    for i in range(t):
-        srsz_ref[i] = winrsz_ref[0, 0, i]
-    s = _window_walk(a_ref[0], xr_ref[0], eye_ref[...],
-                     alive_ref[0],
-                     lambda i: a_ref[0, pl.ds(i, 1), :],
-                     lambda i: xr_ref[0, pl.ds(i, 1), :],
-                     sp_ref, sb_ref, sxp_ref, srb_ref, srsz_ref,
-                     t, w, u, xc, dloc_ref[0, 0, 0], steps)
-    z = jnp.int32(0)
-    outp_ref[0] = sp_ref[:, :w]
-    outb_ref[0] = sb_ref[:, :w]
-    outxp_ref[0] = sxp_ref[:, :w]
-    outrb_ref[0] = srb_ref[:, :w]
-    for i in range(t):
-        outrsz_ref[0, 0, i] = srsz_ref[i]
-    ctl_ref[0, 0, 0] = s[0]
-    ctl_ref[0, 0, 1] = s[2]
-    ctl_ref[0, 0, 2] = s[3]
-    ctl_ref[0, 0, 3] = s[4]
-    ctl_ref[0, 0, 4] = s[5]
-    ctl_ref[0, 0, 5] = s[6]
-    ctl_ref[0, 0, 6] = z
-    ctl_ref[0, 0, 7] = z
-
-
-@functools.partial(jax.jit, static_argnames=("steps", "interpret"))
-def dfs_step_window(a: jnp.ndarray, x_rows: jnp.ndarray, eye: jnp.ndarray,
-                    alive0: jnp.ndarray, winP: jnp.ndarray,
-                    winB: jnp.ndarray, winXp: jnp.ndarray,
-                    winRb: jnp.ndarray, winrsz: jnp.ndarray,
-                    dloc: jnp.ndarray, steps: int = 16,
-                    interpret: bool = False):
-    """Pallas path for ref.dfs_step_window (same contract).
-
-    The (T, W) window frames are copied into VMEM scratch once, mutated
-    in place across up to `steps` frame-steps, and written back to the
-    output refs at the end — the kernel's whole point is that the stack
-    state does NOT round-trip HBM between steps. The adjacency, X rows,
-    eye, and alive inputs stay resident in VMEM across the invocation;
-    the |R| sizes and control scalars (dloc in, ctl out) ride in SMEM.
-    """
-    t, w = winP.shape
-    assert t == WINDOW_FRAMES, f"window must have {WINDOW_FRAMES} frames"
-    assert w <= WINDOW_WORDS, f"word width {w} exceeds {WINDOW_WORDS}"
-    u = a.shape[0]
-    xc = x_rows.shape[0]
-
-    def vmem(shape):
-        return pl.BlockSpec(shape, lambda: tuple(0 for _ in shape))
-
-    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    outs = pl.pallas_call(
-        functools.partial(_dfs_step_window_kernel, steps=steps),
-        out_shape=(jax.ShapeDtypeStruct((t, w), jnp.uint32),
-                   jax.ShapeDtypeStruct((t, w), jnp.uint32),
-                   jax.ShapeDtypeStruct((t, w), jnp.uint32),
-                   jax.ShapeDtypeStruct((t, w), jnp.uint32),
-                   jax.ShapeDtypeStruct((1, t), jnp.int32),
-                   jax.ShapeDtypeStruct((1, 8), jnp.int32)),
-        in_specs=[vmem((u, w)), vmem((xc, w)), vmem((u, w)),
-                  vmem((xc, 1)), vmem((t, w)), vmem((t, w)),
-                  vmem((t, w)), vmem((t, w)), smem, smem],
-        out_specs=(vmem((t, w)), vmem((t, w)), vmem((t, w)), vmem((t, w)),
-                   smem, smem),
-        scratch_shapes=[
-            pltpu.VMEM((8, 128), jnp.uint32),
-            pltpu.VMEM((8, 128), jnp.uint32),
-            pltpu.VMEM((8, 128), jnp.uint32),
-            pltpu.VMEM((8, 128), jnp.uint32),
-            pltpu.SMEM((8,), jnp.int32),
-        ],
-        name="dfs_step_window",
-        interpret=interpret,
-    )(a, x_rows, eye, alive0.astype(jnp.int32)[:, None], winP, winB,
-      winXp, winRb, winrsz.astype(jnp.int32)[None],
-      jnp.asarray(dloc, jnp.int32)[None, None])
-    outP, outB, outXp, outRb, outrsz, ctl = outs
-    return outP, outB, outXp, outRb, outrsz[0], ctl[0]
-
-
-@functools.partial(jax.jit, static_argnames=("steps", "interpret"))
-def dfs_step_window_lanes(a: jnp.ndarray, x_rows: jnp.ndarray,
-                          eye: jnp.ndarray, alive0: jnp.ndarray,
-                          winP: jnp.ndarray, winB: jnp.ndarray,
-                          winXp: jnp.ndarray, winRb: jnp.ndarray,
-                          winrsz: jnp.ndarray, dloc: jnp.ndarray,
-                          steps: int = 16, interpret: bool = False):
-    """Pallas path for ref.dfs_step_window_lanes (same contract).
-
-    The grid runs over lanes: each grid step walks one lane's window for
-    up to `steps` frame-steps entirely in the shared (8, 128) VMEM
-    scratch, touching only that lane's blocks of the (L, …) inputs and
-    outputs. Per-lane scalars — the window-local depth in, the per-frame
-    |R| sizes, and the ctl row out — ride in SMEM lane rows shaped
-    (1, 1, T)/(1, 1, 1)/(1, 1, 8) over (L, 1, ·) arrays: Mosaic checks
-    the LAST TWO dims of every block (even SMEM) against the array dims,
-    so the lane axis is the mapped leading dim and the trailing (1, ·)
-    matches the array exactly. a: (L, U, W); x_rows: (L, XC, W); eye:
-    (U, W) shared; alive0: (L, XC); winP/winB/winXp/winRb: (L, T, W);
-    winrsz: (L, T); dloc: (L,). Returns the updated lane windows plus
-    ctl (L, 8).
-    """
-    l, t, w = winP.shape
-    assert t == WINDOW_FRAMES, f"window must have {WINDOW_FRAMES} frames"
-    assert w <= WINDOW_WORDS, f"word width {w} exceeds {WINDOW_WORDS}"
-    u = a.shape[1]
-    xc = x_rows.shape[1]
-
-    def lane(shape):
-        return pl.BlockSpec((1,) + shape,
-                            lambda i: (i,) + (0,) * len(shape))
-
-    def smem(cols):
-        # (1, 1, cols) lane rows of an (L, 1, cols) array: Mosaic requires
-        # the last TWO block dims to be 8/128-divisible or equal to the
-        # array dims, so per-lane scalars carry a middle singleton — the
-        # lane axis is Mapped, the trailing (1, cols) matches exactly.
-        return pl.BlockSpec((1, 1, cols), lambda i: (i, 0, 0),
-                            memory_space=pltpu.SMEM)
-
-    outs = pl.pallas_call(
-        functools.partial(_dfs_step_window_lanes_kernel, steps=steps),
-        grid=(l,),
-        out_shape=(jax.ShapeDtypeStruct((l, t, w), jnp.uint32),
-                   jax.ShapeDtypeStruct((l, t, w), jnp.uint32),
-                   jax.ShapeDtypeStruct((l, t, w), jnp.uint32),
-                   jax.ShapeDtypeStruct((l, t, w), jnp.uint32),
-                   jax.ShapeDtypeStruct((l, 1, t), jnp.int32),
-                   jax.ShapeDtypeStruct((l, 1, 8), jnp.int32)),
-        in_specs=[lane((u, w)), lane((xc, w)),
-                  pl.BlockSpec((u, w), lambda i: (0, 0)),
-                  lane((xc, 1)), lane((t, w)), lane((t, w)),
-                  lane((t, w)), lane((t, w)), smem(t), smem(1)],
-        out_specs=(lane((t, w)), lane((t, w)), lane((t, w)), lane((t, w)),
-                   smem(t), smem(8)),
-        scratch_shapes=[
-            pltpu.VMEM((8, 128), jnp.uint32),
-            pltpu.VMEM((8, 128), jnp.uint32),
-            pltpu.VMEM((8, 128), jnp.uint32),
-            pltpu.VMEM((8, 128), jnp.uint32),
-            pltpu.SMEM((8,), jnp.int32),
-        ],
-        name="dfs_step_window_lanes",
-        interpret=interpret,
-    )(a, x_rows, eye, alive0.astype(jnp.int32)[..., None], winP, winB,
-      winXp, winRb, winrsz.astype(jnp.int32)[:, None, :],
-      jnp.asarray(dloc, jnp.int32)[:, None, None])
-    outP, outB, outXp, outRb, outrsz, ctl = outs
-    return outP, outB, outXp, outRb, outrsz[:, 0], ctl[:, 0]

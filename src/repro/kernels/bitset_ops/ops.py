@@ -109,72 +109,6 @@ def clique_counts(rows: jnp.ndarray, mask: jnp.ndarray, in_p: jnp.ndarray,
     return ref.clique_counts(rows, mask, in_p, in_x)
 
 
-# VMEM stack-window geometry (DESIGN.md §2.6/§3): the fused dfs_step_window
-# kernel keeps this many stack frames resident in VMEM scratch, whose
-# literal scratch shapes bound the eligible problem size (words ≤ 128 ⇒
-# U ≤ 4096 vertices, X0 rows ≤ 4096). Shapes outside the bounds — and every
-# non-TPU backend — take the jnp ref path with the same contract.
-WINDOW_FRAMES = 8
-WINDOW_MAX_WORDS = 128
-WINDOW_MAX_XROWS = 4096
-
-
-@_scoped
-def dfs_step_window(a: jnp.ndarray, x_rows: jnp.ndarray, eye: jnp.ndarray,
-                    alive0: jnp.ndarray, winP: jnp.ndarray,
-                    winB: jnp.ndarray, winXp: jnp.ndarray,
-                    winRb: jnp.ndarray, winrsz: jnp.ndarray,
-                    dloc: jnp.ndarray, steps: int):
-    """Up to `steps` fused BK frame-steps over a resident T-frame stack
-    window (pivot backend, dynamic reduction off, counting only).
-
-    Returns the updated window plus ctl (8,) int32 = [dloc', calls,
-    branches, sum_px, cliques, steps_done, 0, 0]; stops early on window
-    underflow (dloc' == −1) or overflow (a branch step at the top slot).
-    The engine's `run_root_windowed` owns the HBM stack and the
-    spill/refill around each call — see ref.dfs_step_window for the full
-    contract."""
-    if (_on_tpu() and a.ndim == 2 and winP.shape[0] == WINDOW_FRAMES
-            and a.shape[1] <= WINDOW_MAX_WORDS
-            and x_rows.shape[0] <= WINDOW_MAX_XROWS):
-        return kernel.dfs_step_window(a, x_rows, eye, alive0, winP, winB,
-                                      winXp, winRb, winrsz, dloc,
-                                      steps=steps, interpret=False)
-    return ref.dfs_step_window(a, x_rows, eye, alive0, winP, winB, winXp,
-                               winRb, winrsz, dloc, steps)
-
-
-@_scoped
-def dfs_step_window_lanes(a: jnp.ndarray, x_rows: jnp.ndarray,
-                          eye: jnp.ndarray, alive0: jnp.ndarray,
-                          winP: jnp.ndarray, winB: jnp.ndarray,
-                          winXp: jnp.ndarray, winRb: jnp.ndarray,
-                          winrsz: jnp.ndarray, dloc: jnp.ndarray,
-                          steps: int):
-    """Lane-batched window walk for the persistent engine: each of the L
-    lanes runs up to `steps` fused BK frame-steps over its own resident
-    T-frame stack window (pivot backend, dynamic reduction off, counting
-    only — same eligibility as `dfs_step_window`).
-
-    a: (L, U, W); x_rows: (L, XC, W); eye: (U, W) shared; alive0:
-    (L, XC); windows (L, T, W); winrsz (L, T); dloc (L,) with dloc < 0
-    marking a dead lane (no-op, zero deltas). Returns the updated
-    windows plus ctl (L, 8) int32 = [dloc', calls, branches, sum_px,
-    cliques, steps_done, 0, 0] per lane. On TPU this is one grid-over-
-    lanes Pallas kernel (per-lane VMEM scratch window, per-lane scalars
-    in 2-D SMEM); elsewhere a vmapped jnp window walk with the same
-    contract."""
-    if (_on_tpu() and a.ndim == 3 and winP.shape[1] == WINDOW_FRAMES
-            and a.shape[2] <= WINDOW_MAX_WORDS
-            and x_rows.shape[1] <= WINDOW_MAX_XROWS):
-        return kernel.dfs_step_window_lanes(a, x_rows, eye, alive0, winP,
-                                            winB, winXp, winRb, winrsz,
-                                            dloc, steps=steps,
-                                            interpret=False)
-    return ref.dfs_step_window_lanes(a, x_rows, eye, alive0, winP, winB,
-                                     winXp, winRb, winrsz, dloc, steps)
-
-
 @_scoped
 def frame_step(rows: jnp.ndarray, p: jnp.ndarray, xp: jnp.ndarray,
                wrow: jnp.ndarray):

@@ -90,165 +90,6 @@ def clique_counts(rows: jnp.ndarray, mask: jnp.ndarray, in_p: jnp.ndarray,
             jnp.sum(dom.astype(jnp.int32), axis=-1))
 
 
-def dfs_step_window(a: jnp.ndarray, x_rows: jnp.ndarray, eye: jnp.ndarray,
-                    alive0: jnp.ndarray, winP: jnp.ndarray,
-                    winB: jnp.ndarray, winXp: jnp.ndarray,
-                    winRb: jnp.ndarray, winrsz: jnp.ndarray,
-                    dloc: jnp.ndarray, steps: int):
-    """K masked BK frame-steps over a T-frame stack window (counting only).
-
-    The windowed DFS contract (DESIGN.md §2.6/§3): run up to `steps`
-    straight-line frame-steps of the *pivot* backend with dynamic
-    reduction off and no enumeration, touching only the T resident stack
-    frames. The caller (engine `run_root_windowed`) owns the full HBM
-    stack and re-slices a fresh window when this returns.
-
-    a: (U, W) uint32 adjacency; x_rows: (XC, W) uint32; eye: (U, W)
-    one-hot bitsets (fr.eye_bits — the gather-free membership test);
-    alive0: (XC,) int32 0/1 root X0 alive mask. winP/winB/winXp/winRb:
-    (T, W) uint32; winrsz: (T,) int32; dloc: () int32 window-local depth.
-
-    The per-frame X0 alive set does NOT ride in the window: aliveness is
-    a closed form of the frame's Rb — `alive[k] = alive0[k] ∧ Rb ⊆
-    N(x_k)` (each branch vertex taken lands in Rb, and a row stays alive
-    iff adjacent to every one) — recomputed per step with one
-    AND+popcount sweep in the same (XC,) orientation it is consumed in.
-
-    Returns (winP, winB, winXp, winRb, winrsz, ctl) with ctl (8,) int32
-    = [dloc', calls, branches, sum_px, cliques, steps_done, 0, 0].
-    Stops early when the walk pops below the window (dloc' == −1) or a
-    branch step lands on the top slot (dloc' == T−1 with branches left —
-    the push target would be outside the window); counter deltas are
-    exact for the steps executed either way.
-    """
-    T, W = winP.shape
-    U = a.shape[0]
-    XC = x_rows.shape[0]
-    iota_u = jnp.arange(U, dtype=jnp.int32)
-    iota_w = jnp.arange(W, dtype=jnp.int32)
-    big = jnp.int32(1 << 30)
-
-    def first_bit(bits):
-        low = jnp.bitwise_and(bits, jnp.uint32(0) - bits)
-        pos = jax.lax.population_count(low - jnp.uint32(1)).astype(jnp.int32)
-        cand = jnp.where(bits != 0, 32 * iota_w + pos, big)
-        return jnp.min(cand)
-
-    def unpack(bits):
-        return ((bits[iota_u // 32] >> (iota_u % 32).astype(jnp.uint32))
-                & jnp.uint32(1)) != 0
-
-    def first_argmax(scores):
-        m = jnp.max(scores)
-        idx = jnp.min(jnp.where(
-            scores == m, jnp.arange(scores.shape[0], dtype=jnp.int32), big))
-        return idx.astype(jnp.int32), m
-
-    def body(s):
-        (wP, wB, wXp, wRb, wrsz, dl, done, it,
-         calls, branches, spx, clq) = s
-        d = jnp.clip(dl, 0, T - 1)
-        fP, fB, fXp = wP[d], wB[d], wXp[d]
-        fRb, frsz = wRb[d], wrsz[d]
-        has_branch = jnp.any(fB != 0)
-        blocked = has_branch & (dl >= T - 1)
-        act = ~done & ~blocked & (dl >= 0)
-        done = done | blocked | (dl < 0)
-
-        w = jnp.clip(first_bit(fB), 0, U - 1)
-        wbit = jnp.where(iota_w == w // 32,
-                         jnp.uint32(1) << (w % 32).astype(jnp.uint32),
-                         jnp.uint32(0))
-        wrow = a[w]
-        childP = fP & wrow
-        childXp = fXp & wrow
-        childRb = fRb | wbit
-        deg = and_popcount_rows(a, childP)                    # (U,)
-        pcx = and_popcount_rows(x_rows, childP)               # (XC,)
-        # closed-form child alive set (see docstring)
-        pc_rb = jnp.sum(jax.lax.population_count(childRb)).astype(jnp.int32)
-        alive = alive0 * (and_popcount_rows(x_rows, childRb)
-                          == pc_rb).astype(jnp.int32)
-
-        # enter_call, restricted: counts + leaf report + pivot branch set
-        en = act & has_branch
-        en_i = en.astype(jnp.int32)
-        branches = branches + en_i
-        calls = calls + en_i
-        pc_p = jnp.sum(jax.lax.population_count(childP)).astype(jnp.int32)
-        pc_x = jnp.sum(jax.lax.population_count(childXp)).astype(jnp.int32)
-        nal = jnp.sum(alive)
-        spx = spx + (pc_p + pc_x + nal) * en_i
-        p_empty = pc_p == 0
-        x_empty = (nal == 0) & (pc_x == 0)
-        crsz = frsz + 1
-        clq = clq + (p_empty & x_empty & (crsz >= 2) & en).astype(jnp.int32)
-        push = ~p_empty & en
-
-        # pivot over P ∪ X (pivot.branch_set deg-vector path, exactly)
-        pool = unpack(childP | childXp)
-        best_u, su = first_argmax(jnp.where(pool, deg, jnp.int32(-1)))
-        best_x, sx = first_argmax(jnp.where(alive > 0, pcx, jnp.int32(-1)))
-        use_x = sx > su
-        pivot_row = jnp.where(use_x, x_rows[jnp.clip(best_x, 0, XC - 1)],
-                              a[best_u])
-        childB = childP & ~pivot_row
-
-        # current frame: P \ w, X ∪ w, B \ w (identity when not branching)
-        wP = wP.at[d].set(jnp.where(en, fP & ~wbit, fP))
-        wXp = wXp.at[d].set(jnp.where(en, fXp | wbit, fXp))
-        wB = wB.at[d].set(jnp.where(en, fB & ~wbit, fB))
-        # child frame at d+1, written only when descended into
-        cd = jnp.clip(d + 1, 0, T - 1)
-        wP = wP.at[cd].set(jnp.where(push, childP, wP[cd]))
-        wB = wB.at[cd].set(jnp.where(push, childB, wB[cd]))
-        wXp = wXp.at[cd].set(jnp.where(push, childXp, wXp[cd]))
-        wRb = wRb.at[cd].set(jnp.where(push, childRb, wRb[cd]))
-        wrsz = wrsz.at[cd].set(jnp.where(push, crsz, wrsz[cd]))
-
-        dl = jnp.where(act,
-                       jnp.where(has_branch,
-                                 jnp.where(push, dl + 1, dl), dl - 1), dl)
-        it = it + act.astype(jnp.int32)
-        return (wP, wB, wXp, wRb, wrsz, dl, done, it,
-                calls, branches, spx, clq)
-
-    def cond(s):
-        return (s[7] < steps) & ~s[6]
-
-    z = jnp.int32(0)
-    s = jax.lax.while_loop(cond, body, (
-        winP, winB, winXp, winRb, winrsz, dloc.astype(jnp.int32),
-        jnp.bool_(False), z, z, z, z, z))
-    ctl = jnp.stack([s[5], s[8], s[9], s[10], s[11], s[7], z, z])
-    return s[0], s[1], s[2], s[3], s[4], ctl
-
-
-def dfs_step_window_lanes(a: jnp.ndarray, x_rows: jnp.ndarray,
-                          eye: jnp.ndarray, alive0: jnp.ndarray,
-                          winP: jnp.ndarray, winB: jnp.ndarray,
-                          winXp: jnp.ndarray, winRb: jnp.ndarray,
-                          winrsz: jnp.ndarray, dloc: jnp.ndarray,
-                          steps: int):
-    """Lane-batched `dfs_step_window`: one independent window walk per lane.
-
-    a: (L, U, W) per-lane adjacency; x_rows: (L, XC, W); eye: (U, W)
-    shared; alive0: (L, XC) int32 0/1 per-lane root alive masks;
-    winP/winB/winXp/winRb: (L, T, W); winrsz: (L, T); dloc: (L,) int32.
-    Returns the lane-batched windows plus ctl (L, 8) int32 rows of the
-    single-lane contract. A dead lane (dloc < 0) no-ops: its first body
-    evaluation marks it done, so it returns unchanged with zero counter
-    deltas and steps_done 0. Lanes are independent — one lane stopping on
-    underflow/overflow only masks its own updates (the vmapped while_loop
-    keeps stepping the others), so a blocked lane never stalls its
-    neighbors' progress.
-    """
-    return jax.vmap(
-        lambda a_l, xr_l, al_l, wp, wb, wxp, wrb, wrz, dl: dfs_step_window(
-            a_l, xr_l, eye, al_l, wp, wb, wxp, wrb, wrz, dl, steps)
-    )(a, x_rows, alive0, winP, winB, winXp, winRb, winrsz, dloc)
-
-
 def and_popcount_many(rows: jnp.ndarray, masks: jnp.ndarray) -> jnp.ndarray:
     """One row matrix against a batch of masks.
 

@@ -106,13 +106,6 @@ def main() -> None:
                          "with the largest donation-slot branch set, "
                          "'deepest' the legacy deepest lane (pure "
                          "scheduling — counters/sets bit-identical)")
-    ap.add_argument("--window-steps", type=int, default=0,
-                    help="walk this many DFS frame-steps per stack "
-                         "round-trip over a VMEM-resident stack window "
-                         "(0 = one step per trip). Per-root walks need "
-                         "pivot + --no-dynamic-red; the persistent engine "
-                         "windows every config (fused kernel when "
-                         "eligible, windowed dfs_step otherwise)")
     args = ap.parse_args()
     compile_cache.enable()
 
@@ -122,8 +115,7 @@ def main() -> None:
     drv = DistributedMCE(
         g, chunk=args.chunk, ckpt_path=args.ckpt,
         cfg=EngineConfig(dynamic_red=args.dred, backend=args.backend,
-                         steal=args.steal, steal_victim=args.steal_victim,
-                         window_steps=args.window_steps),
+                         steal=args.steal, steal_victim=args.steal_victim),
         global_red=args.gred, x_red=args.xred,
         streaming=not args.materialize, stream_roots=args.stream_roots,
         split_threshold=args.split_threshold,
@@ -156,11 +148,6 @@ def main() -> None:
     if lc.get("steals") or lc.get("entry_terms"):
         print(f"queue: steals={lc.get('steals', 0)} "
               f"entry_terms={lc.get('entry_terms', 0)}")
-    wtrips = lc.get("window_spills", 0) + lc.get("window_hits", 0)
-    if wtrips:
-        print(f"window: spills={lc['window_spills']} "
-              f"hits={lc['window_hits']} "
-              f"boundary_stall={lc['window_spills'] / wtrips:.2f}")
 
 
 if __name__ == "__main__":

@@ -30,9 +30,9 @@ class MCEService:
     replays included): `live_iters` / `lane_iters` are the useful vs
     capacity lane-trips of every engine dispatch (occupancy() = ratio),
     `truncated` counts chunks that hit cfg.max_iters with work left,
-    `window_spills` / `window_hits` split windowed lane-trips by whether
-    they ended at a stack boundary (boundary_stall() = spill fraction),
-    and `engine_choices` tallies the per-bucket auto-policy picks. The
+    `steals` / `entry_terms` are the lane queue's adopted branch-set
+    halves and roots finished inside their entry call, and
+    `engine_choices` tallies the per-bucket auto-policy picks. The
     per-query deltas ride on each returned result as `res.stats`.
     """
 
@@ -56,24 +56,12 @@ class MCEService:
         self.last_driver: Optional[DistributedMCE] = None
         self.stats = {"live_iters": 0, "lane_iters": 0, "truncated": 0,
                       "steals": 0, "entry_terms": 0,
-                      "window_spills": 0, "window_hits": 0,
                       "engine_choices": {"perroot": 0, "persistent": 0}}
 
     def occupancy(self) -> float:
         """Useful lane-trips / lane-trip capacity over all queries so far."""
         cap = self.stats["lane_iters"]
         return self.stats["live_iters"] / cap if cap else 0.0
-
-    def boundary_stall(self) -> float:
-        """Fraction of windowed lane-trips that ended at a stack boundary.
-
-        window_spills / (window_spills + window_hits): a *spill* is a
-        windowed trip that stopped short of its K steps (window overflow/
-        underflow forced an HBM round-trip), a *hit* ran all K steps
-        VMEM-resident. 0.0 when no windowed trips ran (window_steps=0 or
-        perroot-only queries) — low is good."""
-        trips = self.stats["window_spills"] + self.stats["window_hits"]
-        return self.stats["window_spills"] / trips if trips else 0.0
 
     def query(self, cfg: EngineConfig = EngineConfig(),
               ckpt_path: Optional[str] = None,
@@ -106,14 +94,11 @@ class MCEService:
         res = drv.run(resume=resume)
         self.last_driver = drv
         self.queries += 1
-        delta = {k: int(drv.last_counters.get(k, 0))
-                 for k in ("live_iters", "lane_iters", "truncated",
-                           "steals", "entry_terms",
-                           "window_spills", "window_hits")}
+        keys = ("live_iters", "lane_iters", "truncated", "steals",
+                "entry_terms")
+        delta = {k: int(drv.last_counters.get(k, 0)) for k in keys}
         delta["engine_choices"] = dict(drv.stats["engine_choices"])
-        for k in ("live_iters", "lane_iters", "truncated",
-                  "steals", "entry_terms",
-                  "window_spills", "window_hits"):
+        for k in keys:
             self.stats[k] += delta[k]
         for k, v in delta["engine_choices"].items():
             self.stats["engine_choices"][k] += v
@@ -138,23 +123,16 @@ def main() -> None:
                      lanes=args.lanes)
     for label, cfg in [("pivot", EngineConfig(backend="pivot")),
                        ("pivot-nodyn", EngineConfig(backend="pivot",
-                                                    dynamic_red=False)),
-                       ("pivot-win", EngineConfig(backend="pivot",
-                                                  window_steps=8))]:
+                                                    dynamic_red=False))]:
         t0 = time.time()
         res = svc.query(cfg)
         occ = (res.stats["live_iters"] / res.stats["lane_iters"]
                if res.stats["lane_iters"] else 0.0)
-        wtrips = res.stats["window_spills"] + res.stats["window_hits"]
-        stall = res.stats["window_spills"] / wtrips if wtrips else 0.0
         print(f"{label:12s} cliques={res.cliques} calls={res.calls} "
-              f"occ={occ:.2f} stall={stall:.2f} {time.time() - t0:.2f}s "
+              f"occ={occ:.2f} {time.time() - t0:.2f}s "
               f"({'cold: streamed+packed' if svc.queries == 1 else 'cached buckets'})")
     print(f"service: {svc.queries} queries, "
           f"occupancy {svc.occupancy():.2f}, "
-          f"boundary_stall {svc.boundary_stall():.2f} "
-          f"(spills={svc.stats['window_spills']} "
-          f"hits={svc.stats['window_hits']}), "
           f"engine_choices={svc.stats['engine_choices']}")
 
 

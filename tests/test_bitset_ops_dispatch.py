@@ -413,175 +413,3 @@ def test_dispatch_cpu_uses_ref():
     np.testing.assert_array_equal(
         np.asarray(ops.and_popcount_rows(rows, mask)),
         np.asarray(ref.and_popcount_rows(rows, mask)))
-
-
-# --------------------------------------------------------------------------
-# dfs_step_window: fused K-step frame window (VMEM-resident stack slice)
-# --------------------------------------------------------------------------
-
-def _window_case(seed, u=64, w=2, xc=24, t=8):
-    """A plausible window invocation: symmetric adjacency, random X rows,
-    root-ish frame at slot 0 (the wrapper always re-centers so the live
-    frame sits mid-window; slot 0 with dloc=0 is the cold-start shape)."""
-    from repro.core.engine import frames as fr
-    r = np.random.default_rng(seed)
-    m = r.random((u, u)) < 0.25
-    m = np.triu(m, 1)
-    m = m | m.T
-    a = np.zeros((u, w), np.uint32)
-    for i in range(u):
-        for j in range(u):
-            if m[i, j]:
-                a[i, j // 32] |= np.uint32(1 << (j % 32))
-    xr = r.integers(0, 2**32, (xc, w), dtype=np.uint32)
-    alive0 = (r.random(xc) < 0.6).astype(np.int32)
-    winp = np.zeros((t, w), np.uint32)
-    winp[0] = r.integers(0, 2**32, w, dtype=np.uint32)
-    winb = np.zeros((t, w), np.uint32)
-    winb[0] = winp[0] & r.integers(0, 2**32, w, dtype=np.uint32)
-    winrsz = np.zeros(t, np.int32)
-    winrsz[0] = 1
-    return (jnp.asarray(a), jnp.asarray(xr), fr.eye_bits(u, w),
-            jnp.asarray(alive0), jnp.asarray(winp), jnp.asarray(winb),
-            jnp.zeros((t, w), jnp.uint32), jnp.zeros((t, w), jnp.uint32),
-            jnp.asarray(winrsz), jnp.int32(0))
-
-
-@pytest.mark.parametrize("seed", range(5))
-@pytest.mark.parametrize("steps", [1, 7, 32])
-def test_dfs_step_window_parity(seed, steps):
-    """Kernel vs jnp ref, bit-exact: every window plane, rsz, and the
-    packed ctl row (dloc', calls, branches, sum_px, cliques, steps_done)."""
-    args = _window_case(seed)
-    want = ref.dfs_step_window(*args, steps)
-    got = bk.dfs_step_window(*args, steps=steps, interpret=True)
-    for i, (g, r) in enumerate(zip(got, want)):
-        np.testing.assert_array_equal(
-            np.asarray(g), np.asarray(r), err_msg=f"output {i}")
-
-
-def test_dfs_step_window_underflow_stops():
-    """A window with no branch work anywhere (B empty in every frame)
-    must pop straight below the window — dloc' = -1 — without fabricating
-    work: zero calls, branches, cliques."""
-    args = list(_window_case(3))
-    args[5] = jnp.zeros_like(args[5])          # winB: no branch bits
-    got = bk.dfs_step_window(*args, steps=16, interpret=True)
-    ctl = np.asarray(got[-1])
-    assert ctl[0] == -1                        # dloc'
-    assert ctl[1] == ctl[2] == ctl[4] == 0     # calls, branches, cliques
-
-
-def test_vmap_dfs_step_window_parity():
-    """The engine vmaps the window step over lanes (shared eye)."""
-    b = 3
-    cases = [_window_case(100 + i) for i in range(b)]
-    eye = cases[0][2]
-    stacked = [jnp.stack([c[i] for c in cases])
-               for i in range(10) if i != 2]
-
-    def f(a, xr, alive0, wp, wb, wxp, wrb, wrsz, dl):
-        return bk.dfs_step_window(a, xr, eye, alive0, wp, wb, wxp, wrb,
-                                  wrsz, dl, steps=9, interpret=True)
-
-    got = jax.vmap(f)(*stacked)
-    for bi, c in enumerate(cases):
-        want = ref.dfs_step_window(*c, 9)
-        for i, (g, r) in enumerate(zip(got, want)):
-            np.testing.assert_array_equal(
-                np.asarray(g[bi]), np.asarray(r),
-                err_msg=f"batch {bi} output {i}")
-
-
-def test_dispatch_dfs_step_window(monkeypatch):
-    """On TPU an (8, <=128)-word window routes to the kernel; CPU (this
-    container) and oversized operands take the ref path."""
-    args = _window_case(7)
-    want = ref.dfs_step_window(*args, 4)
-
-    got = ops.dfs_step_window(*args, steps=4)  # CPU -> ref
-    for g, r in zip(got, want):
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
-
-    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
-    calls = []
-
-    def fake(*a, steps, interpret):
-        calls.append((steps, interpret))
-        return ref.dfs_step_window(*a, steps)
-
-    monkeypatch.setattr(ops.kernel, "dfs_step_window", fake)
-    got = ops.dfs_step_window(*args, steps=4)
-    assert calls == [(4, False)]
-    for g, r in zip(got, want):
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
-
-    calls.clear()                              # too many X rows -> ref
-    big = list(args)
-    big[1] = jnp.zeros((ops.WINDOW_MAX_XROWS + 1, 2), jnp.uint32)
-    big[3] = jnp.zeros(ops.WINDOW_MAX_XROWS + 1, jnp.int32)
-    ops.dfs_step_window(*big, steps=4)
-    assert calls == []
-
-
-# --------------------------------------------------------------------------
-# dfs_step_window_lanes: grid-over-lanes window walk (the persistent
-# engine's batched form — one Pallas grid step per lane)
-# --------------------------------------------------------------------------
-
-def _lanes_case(nlanes=4, seed=200):
-    """Stack independent single-lane window cases; lane 2 starts dead
-    (dloc = -1), the engine's idle-lane shape the kernel must no-op."""
-    cases = [_window_case(seed + i) for i in range(nlanes)]
-    eye = cases[0][2]
-    stacked = [jnp.stack([c[i] for c in cases])
-               for i in range(10) if i != 2]
-    a, xr, alive0, wp, wb, wxp, wrb, wrsz, dl = stacked
-    dl = dl.at[2].set(-1)
-    return (a, xr, eye, alive0, wp, wb, wxp, wrb, wrsz, dl)
-
-
-@pytest.mark.parametrize("steps", [1, 9, 32])
-def test_dfs_step_window_lanes_parity(steps):
-    """Kernel vs vmapped ref, bit-exact per lane — including the dead
-    lane, which must return unchanged with zero counter deltas."""
-    args = _lanes_case()
-    want = ref.dfs_step_window_lanes(*args, steps)
-    got = bk.dfs_step_window_lanes(*args, steps=steps, interpret=True)
-    for i, (g, r) in enumerate(zip(got, want)):
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(r),
-                                      err_msg=f"output {i}")
-    ctl = np.asarray(got[-1])
-    assert ctl[2, 0] == -1                       # dead lane stays dead
-    assert (ctl[2, 1:6] == 0).all()              # ...with zero deltas
-
-
-def test_dispatch_dfs_step_window_lanes(monkeypatch):
-    """On TPU a lane-batched (L, 8, <=128)-word window routes to the grid
-    kernel; CPU and oversized operands take the vmapped ref path."""
-    args = _lanes_case(nlanes=3, seed=300)
-    want = ref.dfs_step_window_lanes(*args, 4)
-
-    got = ops.dfs_step_window_lanes(*args, steps=4)   # CPU -> ref
-    for g, r in zip(got, want):
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
-
-    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
-    calls = []
-
-    def fake(*a, steps, interpret):
-        calls.append((steps, interpret))
-        return ref.dfs_step_window_lanes(*a, steps)
-
-    monkeypatch.setattr(ops.kernel, "dfs_step_window_lanes", fake)
-    got = ops.dfs_step_window_lanes(*args, steps=4)
-    assert calls == [(4, False)]
-    for g, r in zip(got, want):
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
-
-    calls.clear()                              # too many X rows -> ref
-    big = list(args)
-    big[1] = jnp.zeros((3, ops.WINDOW_MAX_XROWS + 1, 2), jnp.uint32)
-    big[3] = jnp.zeros((3, ops.WINDOW_MAX_XROWS + 1), jnp.int32)
-    ops.dfs_step_window_lanes(*big, steps=4)
-    assert calls == []

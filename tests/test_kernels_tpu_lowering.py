@@ -11,15 +11,16 @@ described `v5e:2x2` topology: shapes only, nothing runs. Cases:
   `dfs_step`, so the pallas_calls compile with the batch axis
   prepended to the grid);
 * every kernel at the engine's real bucket widths: W = U/32 for
-  U in 32..1024 (`configs/rmce.py`), chunks of 1024 roots, the window
-  kernels at WINDOW_FRAMES = 8 and 64 lanes;
+  U in 32..1024 (`configs/rmce.py`), chunks of 1024 roots;
 * the chunk step `_sharded_counts` on a one-device mesh at the
   `web_sparse` and `dense_core` shapes, for each engine path;
 * the persistent chunk step at the graph500_s12 benchmark's U=64 shape,
   whose lane step and refill may hold no element gather of a `pred` array;
-* the two widest buckets of G(2000, 0.1): the U=256 lock-step and the
-  U=128 persistent chunk steps, each under its own HLO module name, and
-  the lock-step one with no select over its whole stack.
+* the lock-step and the persistent chunk steps at every bucket width,
+  each under its own HLO module name: the two widest buckets of
+  G(2000, 0.1) (U=256 lock-step, U=128 persistent) and the widths no
+  benchmark cell runs yet, and the U=256 lock-step one with no select
+  over its whole stack.
 
 The topology is described inside a module fixture (never at import),
 which skips the file where libtpu cannot describe it.
@@ -146,51 +147,6 @@ def test_lower_vmapped_frame_step(S):
 
 
 # ---------------------------------------------------------------------------
-# dfs_step_window / dfs_step_window_lanes: the fused VMEM stack-window
-# kernels (plain + vmapped; eye is shared, in_axes=None)
-# ---------------------------------------------------------------------------
-
-T = bk.WINDOW_FRAMES
-SHARED_EYE = (0, 0, None, 0, 0, 0, 0, 0, 0, 0)
-
-
-def _window_shapes(S, u, w, xc, lead=()):
-    """One window invocation's operands; `lead` prefixes every operand
-    but the shared eye (lanes and/or a vmap batch)."""
-    L = tuple(lead)
-    return (S(L + (u, w)), S(L + (xc, w)), S((u, w)), S(L + (xc,), I32),
-            S(L + (T, w)), S(L + (T, w)), S(L + (T, w)), S(L + (T, w)),
-            S(L + (T,), I32), S(L, I32))
-
-
-def _window(steps):
-    return lambda *a: bk.dfs_step_window(*a, steps=steps)
-
-
-def _window_lanes(steps):
-    return lambda *a: bk.dfs_step_window_lanes(*a, steps=steps)
-
-
-def test_lower_dfs_step_window(S):
-    _compile_tpu(_window(16), *_window_shapes(S, 64, 2, 24))
-
-
-def test_lower_vmapped_dfs_step_window(S):
-    _compile_tpu(jax.vmap(_window(16), in_axes=SHARED_EYE),
-                 *_window_shapes(S, 64, 2, 24, lead=(2,)))
-
-
-def test_lower_dfs_step_window_lanes(S):
-    _compile_tpu(_window_lanes(16), *_window_shapes(S, 64, 2, 24, lead=(4,)))
-
-
-def test_lower_vmapped_dfs_step_window_lanes(S):
-    # shard_map/vmap over device shards batches the lane axis
-    _compile_tpu(jax.vmap(_window_lanes(16), in_axes=SHARED_EYE),
-                 *_window_shapes(S, 64, 2, 24, lead=(2, 4)))
-
-
-# ---------------------------------------------------------------------------
 # Real bucket widths: each kernel as the engine calls it, at every bucket
 # size the service packs (U = 32..1024 vertices, W = U/32 words)
 # ---------------------------------------------------------------------------
@@ -220,19 +176,13 @@ def _real_case(name, S, u):
     if name == "frame_step":
         return (jax.vmap(bk.frame_step),
                 (S((c, u, w)), S((c, w)), S((c, w)), S((c, w))))
-    if name == "dfs_step_window":
-        return (jax.vmap(_window(8), in_axes=SHARED_EYE),
-                _window_shapes(S, u, w, xc, lead=(c,)))
-    if name == "dfs_step_window_lanes":
-        return _window_lanes(8), _window_shapes(S, u, w, xc, lead=(LANES,))
     raise KeyError(name)
 
 
 @pytest.mark.parametrize("u", UNIVERSES, ids=lambda u: f"U{u}")
 @pytest.mark.parametrize("name", [
     "and_popcount_rows", "and_popcount_argmax", "and_popcount_many",
-    "clique_counts", "frame_step",
-    "dfs_step_window", "dfs_step_window_lanes"])
+    "clique_counts", "frame_step"])
 def test_compile_real_width(S, name, u):
     f, shapes = _real_case(name, S, u)
     _compile_tpu(f, *shapes)
@@ -254,14 +204,13 @@ CELLS = {"web_sparse": (1024, 64, 64), "dense_core": (128, 1024, 1024)}
 
 def _engine(name):
     from repro.core.engine import EngineConfig
-    if name == "persistent":   # the fused window-lanes path
-        return "persistent", EngineConfig(backend="pivot", dynamic_red=False,
-                                          window_steps=8)
+    if name == "persistent":   # the benchmark cells' queue program
+        return "persistent", EngineConfig(backend="pivot")
     return "perroot", EngineConfig(backend=name)
 
 
 @pytest.mark.parametrize("engine,kernel", [
-    ("pivot", "frame_step"), ("persistent", "dfs_step_window_lanes"),
+    ("pivot", "frame_step"), ("persistent", "frame_step"),
     ("hybrid", "clique_counts")])
 @pytest.mark.parametrize("cell", sorted(CELLS))
 def test_compile_chunk_step(topo, monkeypatch, cell, engine, kernel):
@@ -332,25 +281,40 @@ def test_chunk_step_has_no_phase_pred_gathers(topo, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# The two widest buckets of G(2000, 0.1): the lock-step program at U=256
-# and the persistent one at U=128, each under its own module name
+# The chunk programs at every bucket width, each engine under its own
+# module name
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("engine,chunk,u,xc,module", [
-    ("perroot", 734, 256, 128, "jit__lockstep_counts"),
-    ("persistent", 690, 128, 256, "jit__sharded_counts_impl"),
-], ids=["lockstep_u256", "persistent_u128"])
-def test_compile_wide_chunk_step(topo, monkeypatch, engine, chunk, u, xc,
-                                 module):
-    """The chunk programs of G(2000, 0.1)'s U=256 lock-step bucket
-    (734 roots, 128 X rows) and U=128 persistent bucket (690 roots, 256 X
-    rows, 64 lanes) compile for the chip. The lock-step program is named
-    apart from the persistent one, so a device trace splits their time."""
+# U -> (roots per chunk, X rows): the benchmark cells' buckets where one
+# has that width (graph500_s12 at U=32/64, G(2000, 0.1) at U=128/256),
+# `orkut_scale` (configs/rmce.py) at U=512, and a full chunk at U=1024
+WIDTHS = {32: (1024, 2048), 64: (602, 512), 128: (690, 256),
+          256: (734, 128), 512: (256, 2048), 1024: (CHUNK, 1024)}
+MODULES = {"perroot": "jit__lockstep_counts",
+           "persistent": "jit__sharded_counts_impl"}
+# the persistent U=64 program is compiled by
+# test_chunk_step_has_no_phase_pred_gathers
+WIDE_CASES = [(e, u) for e in MODULES for u in UNIVERSES
+              if (e, u) != ("persistent", 64)]
+
+
+@pytest.mark.parametrize(
+    "engine,u", WIDE_CASES,
+    ids=[f"{'lockstep' if e == 'perroot' else e}_u{u}"
+         for e, u in WIDE_CASES])
+def test_compile_wide_chunk_step(topo, monkeypatch, engine, u):
+    """The chunk program of each engine at each bucket width compiles for
+    the chip with RMCE's config (`EngineConfig(backend="pivot")`, every
+    reduction on), among them G(2000, 0.1)'s U=256 lock-step bucket (734
+    roots, 128 X rows) and U=128 persistent bucket (690 roots, 256 X
+    rows, 64 lanes). The lock-step program is named apart from the
+    persistent one, so a device trace splits their time."""
     from repro.core import driver
     from repro.core.engine import EngineConfig
     from repro.kernels.bitset_ops import ops
 
     monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    chunk, xc = WIDTHS[u]
     w = u // 32
     mesh = Mesh(np.array(topo.devices[:1]), ("data",))
     sh = NamedSharding(mesh, P("data"))
@@ -363,7 +327,7 @@ def test_compile_wide_chunk_step(topo, monkeypatch, engine, chunk, u, xc,
         S((1, chunk, xc), BOOL), S((1, chunk), I32),
         cfg=EngineConfig(backend="pivot"), mesh=mesh, axis=("data",),
         engine=engine, lanes=LANES).compile().as_text()
-    assert re.search(rf"^HloModule {module}\b", text, re.M)
+    assert re.search(rf"^HloModule {MODULES[engine]}\b", text, re.M)
     assert "%frame_step" in text
 
 

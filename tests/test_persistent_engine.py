@@ -17,9 +17,9 @@ import pytest
 
 from repro.core import oracle
 from repro.core.driver import DistributedMCE
-from repro.core.engine import (EngineConfig, PrepStream, choose_engine,
-                               estimate_costs, prepare, run, run_bucket,
-                               run_bucket_persistent,
+from repro.core.engine import (PIVOT_BACKENDS, EngineConfig, PrepStream,
+                               choose_engine, estimate_costs, prepare, run,
+                               run_bucket, run_bucket_persistent,
                                run_stream_persistent)
 from repro.launch.mce_service import MCEService
 from repro.graph import generators as gen
@@ -64,11 +64,13 @@ def test_persistent_matches_perroot_counts(backend, gname):
     assert not res.iters_exhausted
 
 
+@pytest.mark.parametrize("backend", ["pivot", "revised", "rcd", "hybrid"])
 @pytest.mark.parametrize("gname", sorted(GRAPHS))
-def test_persistent_enumerates_same_sets(gname):
+def test_persistent_enumerates_same_sets(gname, backend):
     g = GRAPHS[gname]()
-    ref = run(g, enumerate_cliques=True, engine="perroot")
-    res = run(g, enumerate_cliques=True, engine="persistent", lanes=5)
+    ref = run(g, backend=backend, enumerate_cliques=True, engine="perroot")
+    res = run(g, backend=backend, enumerate_cliques=True,
+              engine="persistent", lanes=5)
     assert not res.overflow and not ref.overflow
     assert set(res.enumerated) == set(ref.enumerated)
     assert set(res.enumerated) == set(oracle.bk_pivot(g))
@@ -216,6 +218,33 @@ def test_choose_engine_policy():
     # degenerate inputs fall back to lock-step
     assert choose_engine(np.zeros(0))[0] == "perroot"
     assert choose_engine(skew=None, n_roots=None)[0] == "perroot"
+
+
+def test_choose_engine_steal_halves_skew_threshold():
+    """The steal flag halves the skew threshold: stealing de-serializes
+    moderate-skew buckets."""
+    n = 64
+    # moderate skew: between thr/2 and thr -> the flag decides
+    mid = np.array([3.0] + [1.0] * (n - 1))
+    skew = float(mid.max() / mid.mean())
+    assert 2.0 < skew < 4.0
+    assert choose_engine(mid)[0] == "perroot"
+    assert choose_engine(mid, steal=True)[0] == "persistent"
+    # below even the halved threshold: perroot either way
+    low = np.array([1.8] + [1.0] * (n - 1))
+    assert float(low.max() / low.mean()) < 2.0
+    assert choose_engine(low)[0] == "perroot"
+    assert choose_engine(low, steal=True)[0] == "perroot"
+    # above the full threshold: persistent either way, same lane sizing
+    high = np.array([1000.0] + [1.0] * (n - 1))
+    assert choose_engine(high) == choose_engine(high, steal=True)
+    assert choose_engine(high, steal=True)[0] == "persistent"
+    # tiny buckets stay lock-step no matter how skewed or steal-capable
+    tiny = np.array([99.0, 1.0, 1.0])
+    assert choose_engine(tiny, steal=True)[0] == "perroot"
+    # memoized-skew callers hit the same boundary
+    assert choose_engine(skew=skew, n_roots=n, steal=True)[0] == "persistent"
+    assert choose_engine(skew=skew, n_roots=n, steal=False)[0] == "perroot"
 
 
 def test_auto_picks_persistent_on_skewed_bucket():
@@ -406,16 +435,20 @@ def test_stream_spanning_enumerates_same_sets_on_hub_graphs(gname):
     assert set(res.enumerated) == set(oracle.bk_pivot(g))
 
 
-def test_steal_on_off_parity_and_steal_counter():
+STEAL_BACKENDS = list(PIVOT_BACKENDS)   # 'rcd' has no branch set to split
+
+
+@pytest.mark.parametrize("backend", STEAL_BACKENDS)
+def test_steal_on_off_parity_and_steal_counter(backend):
     """Stealing is pure scheduling: identical counters either way, with
     the steal counter live on the hub fixture and pinned to zero off."""
     # blob=40/p=0.6: big enough that graph reduction does not collapse
     # the hub, so idle lanes really do adopt stolen branch sets
     g = skewed_graph(blob=40, p=0.6)
-    on = run(g, bucket_sizes=(64,), engine="persistent", lanes=8,
-             steal=True)
-    off = run(g, bucket_sizes=(64,), engine="persistent", lanes=8,
-              steal=False)
+    on = run(g, backend=backend, bucket_sizes=(64,), engine="persistent",
+             lanes=8, steal=True)
+    off = run(g, backend=backend, bucket_sizes=(64,), engine="persistent",
+              lanes=8, steal=False)
     assert (on.cliques, on.calls, on.branches, on.sum_px) == \
            (off.cliques, off.calls, off.branches, off.sum_px)
     assert on.cliques == len(oracle.bk_pivot(g))
@@ -423,15 +456,32 @@ def test_steal_on_off_parity_and_steal_counter():
     assert off.stats["steals"] == 0
 
 
-def test_steal_enumerates_same_sets():
+@pytest.mark.parametrize("backend", STEAL_BACKENDS)
+def test_steal_enumerates_same_sets(backend):
     g = skewed_graph(blob=40, p=0.6)
-    on = run(g, enumerate_cliques=True, bucket_sizes=(64,),
+    on = run(g, backend=backend, enumerate_cliques=True, bucket_sizes=(64,),
              engine="persistent", lanes=8, steal=True)
-    off = run(g, enumerate_cliques=True, bucket_sizes=(64,),
-              engine="persistent", lanes=8, steal=False)
+    off = run(g, backend=backend, enumerate_cliques=True,
+              bucket_sizes=(64,), engine="persistent", lanes=8, steal=False)
     assert not on.overflow and not off.overflow
     assert set(on.enumerated) == set(off.enumerated)
     assert set(on.enumerated) == set(oracle.bk_pivot(g))
+
+
+@pytest.mark.parametrize("backend", STEAL_BACKENDS)
+def test_steal_victim_policies_bit_identical(backend):
+    """The steal victim policy (branchiest vs deepest) is pure
+    scheduling: bit-identical counters either way."""
+    g = skewed_graph(blob=40, p=0.6)
+    br = run(g, backend=backend, bucket_sizes=(64,), engine="persistent",
+             lanes=8, steal=True, steal_victim="branchiest")
+    de = run(g, backend=backend, bucket_sizes=(64,), engine="persistent",
+             lanes=8, steal=True, steal_victim="deepest")
+    assert (br.cliques, br.calls, br.branches, br.sum_px) == \
+           (de.cliques, de.calls, de.branches, de.sum_px)
+    assert br.cliques == len(oracle.bk_pivot(g))
+    assert br.stats["steals"] > 0
+    assert de.stats["steals"] > 0
 
 
 def test_hybrid_entry_terms_counted_in_refill():
@@ -511,34 +561,6 @@ def test_stream_persistent_out_root_is_stream_global():
              for k in range(int(out["out_n"][l]))}
     assert roots and all(0 <= x < r for x in roots)
     assert max(roots) >= h, "second slab's cliques must carry global ids"
-
-
-# ---------------------------------------------------------------------------
-# VMEM stack windowing: run_root_windowed parity through run()
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("gname", sorted(GRAPHS))
-@pytest.mark.parametrize("steps", [4, 16])
-def test_windowed_walk_matches_plain(gname, steps):
-    """window_steps routes eligible per-root walks (pivot, dynamic_red
-    off, counting only) through dfs_step_window; counters must be
-    identical to the plain one-step-per-HBM-round-trip walk."""
-    g = GRAPHS[gname]()
-    ref = run(g, dynamic_red=False, engine="perroot")
-    res = run(g, dynamic_red=False, engine="perroot", window_steps=steps)
-    assert (res.cliques, res.calls, res.branches, res.sum_px) == \
-           (ref.cliques, ref.calls, ref.branches, ref.sum_px)
-    assert res.cliques == len(oracle.bk_pivot(g))
-
-
-def test_window_gate_ignores_ineligible_configs():
-    """window_steps with dynamic reduction on (outside the dfs_step_window
-    contract) must silently take the plain walk — same counters."""
-    g = GRAPHS["er"]()
-    ref = run(g, engine="perroot")
-    res = run(g, engine="perroot", window_steps=16)
-    assert (res.cliques, res.calls, res.branches, res.sum_px) == \
-           (ref.cliques, ref.calls, ref.branches, ref.sum_px)
 
 
 # ---------------------------------------------------------------------------
