@@ -58,12 +58,18 @@ from repro.graph.csr import CSRGraph
 # engine (adopted branch-set halves and claims that finished inside their
 # entry call); the perroot path zero-fills them so the counter schema —
 # and every checkpoint written against it — is engine-independent.
+# "trips" counts the chunk loop's trips (lane_iters / lanes) and
+# "phase_trips" the persistent queue's trips that ran its refill and steal
+# conds (zero on the perroot path, which has none).
 # Checkpoints from before a key existed resume via `.get` in `_settle`.
 COUNTER_KEYS = ("cliques", "calls", "branches", "sum_px", "truncated",
-                "live_iters", "lane_iters", "steals", "entry_terms")
+                "live_iters", "lane_iters", "steals", "entry_terms",
+                "trips", "phase_trips")
 # the work each chunk program does, folded per (u_pad, x_pad, engine) into
-# stats["buckets"]: the operands of a work-based kernel roofline
-BUCKET_KEYS = ("calls", "sum_px", "live_iters", "lane_iters")
+# stats["buckets"]: the operands of a work-based kernel roofline, and the
+# loop's trips beside the ones that ran the queue's phases
+BUCKET_KEYS = ("calls", "sum_px", "live_iters", "lane_iters", "trips",
+               "phase_trips")
 # the host stages that `host_pack_s` sums: everything but the settle
 HOST_PACK_SPANS = ("driver.fetch", "driver.gather", "driver.upload",
                    "driver.dispatch")
@@ -139,15 +145,17 @@ def _sharded_counts_impl(a, p0, xr, xa, rz, cfg: EngineConfig, mesh: Mesh,
             L = min(lanes, a_s.shape[1])
             out = run_bucket_persistent(
                 a_s[0], p_s[0], xr_s[0], xa_s[0], rz_s[0], cfg, lanes=L)
-            out = dict(out, lane_iters=out["iters"] * L)
+            out = dict(out, lane_iters=out["iters"] * L, trips=out["iters"])
         else:
             out = run_lockstep(a_s[0], p_s[0], xr_s[0], xa_s[0], rz_s[0],
                                cfg)
             # lock-step equivalent of the queue's occupancy pair: every
             # lane spins until the slowest root's DFS exhausts
+            trips = jnp.max(out["iters"])
             out = dict(out, live_iters=jnp.sum(out["iters"]),
-                       lane_iters=jnp.max(out["iters"]) * a_s.shape[1],
-                       steals=jnp.int32(0), entry_terms=jnp.int32(0))
+                       lane_iters=trips * a_s.shape[1], trips=trips,
+                       steals=jnp.int32(0), entry_terms=jnp.int32(0),
+                       phase_trips=jnp.int32(0))
         sums = {k: jnp.sum(out[k]).astype(jnp.int32)[None]
                 for k in COUNTER_KEYS}
         return sums

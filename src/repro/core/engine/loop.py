@@ -279,6 +279,7 @@ def _persistent_state0(cfg: EngineConfig, lanes: int, U: int, words: int,
             jnp.int32(0),                        # ls: Σ useful lane steps
             jnp.int32(0),                        # st: steal count
             jnp.int32(0),                        # et: entry-terminated roots
+            jnp.int32(0),                        # pt: phase (outer) trips
             jnp.full((lanes,), jnp.int32(-1)),   # per-lane DFS depth
             jnp.zeros((lanes, U, words), U32),   # per-lane adjacency context
             jnp.zeros((lanes, XC, words), U32),  # per-lane X0 rows
@@ -288,7 +289,7 @@ def _persistent_state0(cfg: EngineConfig, lanes: int, U: int, words: int,
 @partial(jax.jit, static_argnames=("cfg", "lanes", "drain"))
 def _persistent_segment(a, p0, x_rows, x_alive0, rsz0, root_base, state,
                         cfg: EngineConfig, lanes: int, drain: bool):
-    """One jitted while_loop draining one root slab into a lane state.
+    """One jitted (nested) loop draining one root slab into a lane state.
 
     `drain=True` runs until every lane's subtree exhausts (the classic
     per-bucket persistent loop). `drain=False` returns as soon as the
@@ -298,13 +299,25 @@ def _persistent_segment(a, p0, x_rows, x_alive0, rsz0, root_base, state,
     `root_base` offsets `cur_root` so enumerated cliques decode against
     the stream-global root index.
 
-    Each trip runs three phases over the (L, …) lane state: the refill
-    cond (`engine.refill`: exhausted lanes claim the next queue roots),
-    the steal cond once the queue is claimed out (`engine.steal`: an
-    idle lane adopts half of a live lane's branch set), and one masked
+    A trip runs up to three phases over the (L, …) lane state: the
+    refill (`engine.refill`: exhausted lanes claim the next queue roots),
+    the steal once the queue is claimed out (`engine.steal`: an idle
+    lane adopts half of a live lane's branch set), and one masked
     `dfs_step` on every lane (`step_lanes`, `engine.step`). Refill and
     steal are pure scheduling: counters and enumerated sets are
-    bit-identical to the lock-step walk's."""
+    bit-identical to the lock-step walk's.
+
+    The loop is nested (DESIGN.md §2.6). An outer trip runs one cond:
+    the refill, then the steal if the refill claimed the queue out, when
+    a refill is due, else the steal. An inner `while_loop` follows, whose
+    first trip is the outer trip's step and whose further trips are bare
+    steps, taken for as long as no refill is due and no steal could move
+    work. On those trips refill and steal would be the identity, so the
+    lane steps are those of a loop that runs both on every trip, trip
+    for trip, without passing the lane state through a cond's identity
+    branch. The root contexts `al`, `xrl` are invariant in the inner
+    loop, so work on them alone (Lemma 8's complement of the X rows) is
+    hoisted out of it. `pt` counts the outer trips."""
     R, U, words = a.shape
     XC = x_rows.shape[1]
     eye = fr.eye_bits(U, words)
@@ -316,10 +329,28 @@ def _persistent_segment(a, p0, x_rows, x_alive0, rsz0, root_base, state,
         raise ValueError(f"unknown steal_victim {cfg.steal_victim!r} "
                          "(expected 'branchiest' or 'deepest')")
 
+    def more(it, cp, depth):
+        left = ((cp < R) | jnp.any(depth >= 0)) if drain else (cp < R)
+        return left & (it < cfg.max_iters)
+
     def cond(s):
-        it, cp, depth = s[0], s[1], s[5]
-        more = ((cp < R) | jnp.any(depth >= 0)) if drain else (cp < R)
-        return more & (it < cfg.max_iters)
+        return more(s[0], s[1], s[6])
+
+    def refill_due(cp, depth):
+        return (cp < R) & jnp.any(depth < 0)
+
+    def steal_on(cp, depth):
+        # only once the queue can no longer feed the idle lane — while
+        # roots remain, claiming is strictly cheaper than splitting
+        return jnp.any(depth < 0) & jnp.any(depth >= 0) & (cp >= R)
+
+    def split_slots(depth, B):
+        """(branch counts, splittable slots, splittable lanes): a live
+        slot whose branch set still has >= 2 branches can be halved."""
+        bcnt = fr.popcount(B)                          # (L, D)
+        slot_ix = jnp.arange(bcnt.shape[1], dtype=jnp.int32)[None, :]
+        live_slot = (slot_ix <= depth[:, None]) & (bcnt >= 2)
+        return bcnt, live_slot, (depth >= 0) & jnp.any(live_slot, axis=1)
 
     @scoped("engine.refill")
     def refill(args):
@@ -402,10 +433,7 @@ def _persistent_segment(a, p0, x_rows, x_alive0, rsz0, root_base, state,
         # when it has work left, else the next-shallowest. Shallow frames
         # root the largest remaining subtrees, so halving there moves the
         # most work per steal.
-        bcnt = fr.popcount(stack.B)                    # (L, D)
-        slot_ix = jnp.arange(bcnt.shape[1], dtype=jnp.int32)[None, :]
-        live_slot = (slot_ix <= depth[:, None]) & (bcnt >= 2)
-        splittable = (depth >= 0) & jnp.any(live_slot, axis=1)
+        bcnt, live_slot, splittable = split_slots(depth, stack.B)
         do = jnp.any(idle) & jnp.any(splittable)
         # each lane's donation slot is its shallowest splittable frame;
         # score victims by that slot's branch-set size (the work a steal
@@ -453,33 +481,69 @@ def _persistent_segment(a, p0, x_rows, x_alive0, rsz0, root_base, state,
         st = st + do.astype(jnp.int32)
         return st, depth, al, xrl, stack, carry
 
-    def body(s):
-        it, cp, ls, st, et, depth, al, xrl, stack, carry = s
-        need = (cp < R) & jnp.any(depth < 0)
-        cp, ls, et, depth, al, xrl, stack, carry = jax.lax.cond(
-            need, refill, lambda args: args,
+    def refill_then_steal(args):
+        """A refill, then the steal if the refill claimed the queue out."""
+        cp, ls, st, et, depth, al, xrl, stack, carry = args
+        cp, ls, et, depth, al, xrl, stack, carry = refill(
             (cp, ls, et, depth, al, xrl, stack, carry))
         if can_steal:
-            # only once the queue can no longer feed the idle lane — while
-            # roots remain, claiming is strictly cheaper than splitting
-            may = jnp.any(depth < 0) & jnp.any(depth >= 0) & (cp >= R)
             st, depth, al, xrl, stack, carry = jax.lax.cond(
-                may, steal, lambda args: args,
+                steal_on(cp, depth), steal, lambda args: args,
                 (st, depth, al, xrl, stack, carry))
-        ls = ls + jnp.sum((depth >= 0).astype(jnp.int32))
-        with jax.named_scope("engine.step"):
-            depth, stack, carry = step_lanes(cfg, al, xrl, depth, depth >= 0,
-                                             stack, carry, eye, eye_x)
-        return it + 1, cp, ls, st, et, depth, al, xrl, stack, carry
+        return cp, ls, st, et, depth, al, xrl, stack, carry
+
+    def steal_only(args):
+        """The steal: with no refill due it moves work exactly when a lane
+        idles on an empty queue beside a splittable one (its `do`), and
+        changes nothing otherwise."""
+        cp, ls, st, et, depth, al, xrl, stack, carry = args
+        if can_steal:
+            st, depth, al, xrl, stack, carry = steal(
+                (st, depth, al, xrl, stack, carry))
+        return cp, ls, st, et, depth, al, xrl, stack, carry
+
+    def body(s):
+        it, cp, ls, st, et, pt, depth, al, xrl, stack, carry = s
+        cp, ls, st, et, depth, al, xrl, stack, carry = jax.lax.cond(
+            refill_due(cp, depth), refill_then_steal, steal_only,
+            (cp, ls, st, et, depth, al, xrl, stack, carry))
+
+        def stepping(w):
+            """The outer trip's own step, then bare steps while no phase
+            is due."""
+            first, it, _, depth, stack, _ = w
+            due = refill_due(cp, depth)
+            if can_steal:
+                # the splittable test reads every branch set: only while
+                # a lane idles on an empty queue
+                due = due | jax.lax.cond(
+                    steal_on(cp, depth),
+                    lambda B: jnp.any(split_slots(depth, B)[2]),
+                    lambda B: jnp.bool_(False), stack.B)
+            return first | (more(it, cp, depth) & ~due)
+
+        def step(w):
+            _, it, ls, depth, stack, carry = w
+            ls = ls + jnp.sum((depth >= 0).astype(jnp.int32))
+            with jax.named_scope("engine.step"):
+                depth, stack, carry = step_lanes(cfg, al, xrl, depth,
+                                                 depth >= 0, stack, carry,
+                                                 eye, eye_x)
+            return jnp.bool_(False), it + 1, ls, depth, stack, carry
+
+        _, it, ls, depth, stack, carry = jax.lax.while_loop(
+            stepping, step, (jnp.bool_(True), it, ls, depth, stack, carry))
+        return it, cp, ls, st, et, pt + 1, depth, al, xrl, stack, carry
 
     return jax.lax.while_loop(cond, body, state)
 
 
 def _persistent_out(state, R: int):
     """Realize a lane state into the public output dict."""
-    (it, cp, ls, st, et, depth, _al, _xrl, _stack, carry) = state
+    (it, cp, ls, st, et, pt, depth, _al, _xrl, _stack, carry) = state
     out = dict(carry)
     out["iters"] = it
+    out["phase_trips"] = pt
     out["live_iters"] = ls
     out["claimed"] = cp
     out["steals"] = st
@@ -503,19 +567,20 @@ def run_bucket_persistent(a, p0, x_rows, x_alive0, rsz0, cfg: EngineConfig,
     caller's array order (the driver passes its cost-descending canonical
     order, so the queue order IS the checkpoint cursor order).
 
-    The refill phase is wrapped in a real `lax.cond`: this loop is not
-    under vmap (where a cond lowers to SELECT), so iterations with no
-    exhausted lane skip the (LANES, U, W) root-context gathers entirely.
-    Once the queue is claimed out, a second cond runs the STEAL
-    transition (cfg.steal, pivot-family backends): an idle lane splits
-    off half of a live lane's shallowest splittable branch set (slot 0
-    while it has work, else the frame just above it; the lane is picked
-    by `cfg.steal_victim`), so a hub
-    root's subtree spreads across lanes instead of serializing on one
-    (counters and enumerated sets are unchanged — stealing is pure
-    scheduling).
+    The refill phase sits in a real `lax.cond`: this loop is not under
+    vmap (where a cond lowers to SELECT), so trips with no exhausted lane
+    skip the (LANES, U, W) root-context gathers entirely, and trips that
+    need neither refill nor steal run in an inner loop with no cond
+    (`_persistent_segment`). Once the queue is claimed out, the STEAL
+    transition (cfg.steal, pivot-family backends) lets an idle lane
+    split off half of a live lane's shallowest splittable branch set
+    (slot 0 while it has work, else the frame just above it; the lane is
+    picked by `cfg.steal_victim`), so a hub root's subtree spreads
+    across lanes instead of serializing on one (counters and enumerated
+    sets are unchanged — stealing is pure scheduling).
 
     Returns the per-lane carry dict plus scalars: `iters` (loop trips),
+    `phase_trips` (the trips that ran the refill and steal conds),
     `live_iters` (Σ useful lane-trips: live lanes per trip, plus claims
     whose root completed inside its entry call — those do their whole
     subtree's work in the refill; occupancy = live_iters /
@@ -726,11 +791,13 @@ def run(g: CSRGraph, *, global_red: bool = True, dynamic_red: bool = True,
                  for b in prep.buckets]
         outs, spans = run_stream_persistent(slabs, cfg, lanes=lanes)
         prefix = np.cumsum([0] + [b.num_roots for b in prep.buckets])
-        total.stats = dict(iters=0, live_iters=0, lane_iters=0, steals=0,
-                           entry_terms=0, spans=len(spans))
+        total.stats = dict(iters=0, phase_trips=0, live_iters=0,
+                           lane_iters=0, steals=0, entry_terms=0,
+                           spans=len(spans))
         for out, (lo, hi) in zip(outs, spans):
             out = jax.tree.map(np.asarray, out)
             total.stats["iters"] += int(out["iters"])
+            total.stats["phase_trips"] += int(out["phase_trips"])
             total.stats["live_iters"] += int(out["live_iters"])
             # carry is per-lane, so its leading dim is this span's lanes
             total.stats["lane_iters"] += (int(out["iters"])

@@ -15,7 +15,9 @@ described `v5e:2x2` topology: shapes only, nothing runs. Cases:
 * the chunk step `_sharded_counts` on a one-device mesh at the
   `web_sparse` and `dense_core` shapes, for each engine path;
 * the persistent chunk step at the graph500_s12 benchmark's U=64 shape,
-  whose lane step and refill may hold no element gather of a `pred` array;
+  whose lane step and refill may hold no element gather of a `pred` array,
+  and whose innermost loop trip holds no cond and no NOT of the lane X
+  rows;
 * the lock-step and the persistent chunk steps at every bucket width,
   each under its own HLO module name: the two widest buckets of
   G(2000, 0.1) (U=256 lock-step, U=128 persistent) and the widths no
@@ -249,18 +251,14 @@ _GATHER = re.compile(r"= pred\[[^\]]*\]\S* gather\(.*offset_dims=\{\}")
 _PHASE = re.compile(r'op_name="[^"]*engine\.(step|refill)')
 
 
-def test_chunk_step_has_no_phase_pred_gathers(topo, monkeypatch):
-    """Lemma 7's partner lookups are row tests on `A & P` (DESIGN.md §4):
-    the persistent U=64 chunk program (602 roots, 512 X rows, 64 lanes)
-    keeps no element gather of a `pred` array under `engine.step` or
-    `engine.refill`, where a gather by the partner index costs one lookup
-    per lane and vertex. (The refill's row take of a root's alive X mask
-    moves whole rows and is not matched.)"""
+@pytest.fixture(scope="module")
+def graph500_u64(topo):
+    """The persistent U=64 chunk program of graph500_s12 (602 roots, 512 X
+    rows, 64 lanes), compiled once for the tests that read it."""
     from repro.core import driver
     from repro.core.engine import EngineConfig
     from repro.kernels.bitset_ops import ops
 
-    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
     chunk, u, xc = 602, 64, 512
     w = u // 32
     mesh = Mesh(np.array(topo.devices[:1]), ("data",))
@@ -269,15 +267,87 @@ def test_chunk_step_has_no_phase_pred_gathers(topo, monkeypatch):
     def S(shape, dt=U32):
         return jax.ShapeDtypeStruct(shape, dt, sharding=sh)
 
-    text = driver._sharded_counts.lower(
-        S((1, chunk, u, w)), S((1, chunk, w)), S((1, chunk, xc, w)),
-        S((1, chunk, xc), BOOL), S((1, chunk), I32),
-        cfg=EngineConfig(backend="pivot"), mesh=mesh, axis=("data",),
-        engine="persistent", lanes=LANES).compile().as_text()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "_on_tpu", lambda: True)
+        return driver._sharded_counts.lower(
+            S((1, chunk, u, w)), S((1, chunk, w)), S((1, chunk, xc, w)),
+            S((1, chunk, xc), BOOL), S((1, chunk), I32),
+            cfg=EngineConfig(backend="pivot"), mesh=mesh, axis=("data",),
+            engine="persistent", lanes=LANES).compile().as_text()
+
+
+def test_chunk_step_has_no_phase_pred_gathers(graph500_u64):
+    """Lemma 7's partner lookups are row tests on `A & P` (DESIGN.md §4):
+    the persistent U=64 chunk program (602 roots, 512 X rows, 64 lanes)
+    keeps no element gather of a `pred` array under `engine.step` or
+    `engine.refill`, where a gather by the partner index costs one lookup
+    per lane and vertex. (The refill's row take of a root's alive X mask
+    moves whole rows and is not matched.)"""
+    text = graph500_u64
     assert "%frame_step" in text
     bad = [ln.strip()[:160] for ln in text.splitlines()
            if _GATHER.search(ln) and _PHASE.search(ln)]
     assert not bad, f"{len(bad)} pred element gathers: {bad[:3]}"
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) .*\{$")
+_CALLEE = re.compile(r"(?:calls|to_apply|body|condition|branch_computations)"
+                     r"=\{?(%[\w.\-]+(?:, ?%[\w.\-]+)*)")
+_WHILE_BODY = re.compile(r" while\(.*body=%([\w.\-]+)")
+# Lemma 8's complement of one lane X-row batch
+_X_ROWS_NOT = re.compile(r"= u32\[64,512,2\]\S* not\(")
+
+
+def _computations(text):
+    """{computation name: its instruction lines} of an HLO module."""
+    comps, cur = {}, None
+    for ln in text.splitlines():
+        m = _COMPUTATION.match(ln)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif ln.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(ln)
+    return comps
+
+
+def _reach(comps, root):
+    """Every computation `root` runs, itself included."""
+    seen, todo = set(), [root]
+    while todo:
+        c = todo.pop()
+        if c not in seen:
+            seen.add(c)
+            for ln in comps.get(c, ()):
+                for m in _CALLEE.finditer(ln):
+                    todo += re.findall(r"%([\w.\-]+)", m.group(1))
+    return seen
+
+
+def test_queue_inner_trip_has_no_cond_and_no_x_rows_not(graph500_u64):
+    """The persistent segment's nested trip (DESIGN.md §2.6): an outer
+    while runs the refill and steal phases under conds, and the
+    innermost while runs the lane steps. Its body holds no
+    `conditional`, so the lane state passes through no cond's identity
+    branch, and no NOT of the `u32[64,512,2]` lane X rows, which are
+    invariant there and whose complement is hoisted out of it."""
+    comps = _computations(graph500_u64)
+    bodies = [m.group(1) for lines in comps.values() for ln in lines
+              for m in [_WHILE_BODY.search(ln)] if m]
+    reach = {b: _reach(comps, b) for b in bodies}
+    inner = [b for b in bodies if not any(o in reach[b] for o in bodies
+                                          if o != b)]
+    outer = [b for b in bodies if any(i in reach[b] for i in inner
+                                      if i != b)]
+    assert inner and outer, "the segment's loop is not nested"
+    for b in inner:
+        lines = [ln for c in reach[b] for ln in comps[c]]
+        assert any("%frame_step" in ln for ln in lines)
+        conds = [ln.strip()[:120] for ln in lines if " conditional(" in ln]
+        nots = [ln.strip()[:120] for ln in lines if _X_ROWS_NOT.search(ln)]
+        assert not conds, f"{len(conds)} conds in the inner trip: {conds}"
+        assert not nots, f"{len(nots)} X-row NOTs in the inner trip: {nots}"
 
 
 # ---------------------------------------------------------------------------
@@ -292,8 +362,7 @@ WIDTHS = {32: (1024, 2048), 64: (602, 512), 128: (690, 256),
           256: (734, 128), 512: (256, 2048), 1024: (CHUNK, 1024)}
 MODULES = {"perroot": "jit__lockstep_counts",
            "persistent": "jit__sharded_counts_impl"}
-# the persistent U=64 program is compiled by
-# test_chunk_step_has_no_phase_pred_gathers
+# the persistent U=64 program is compiled by the graph500_u64 fixture
 WIDE_CASES = [(e, u) for e in MODULES for u in UNIVERSES
               if (e, u) != ("persistent", 64)]
 

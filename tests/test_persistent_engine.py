@@ -404,6 +404,19 @@ def plant_hub(g, blob=18, p=0.85, seed=17):
     return from_edge_list(g.n, e)
 
 
+# run(plant_hub(GRAPHS[g]()), bucket_sizes=(32, 64), lanes=8)'s queue
+# counters, as a loop that runs the refill and steal conds on every trip
+# counts them; `phase` is the number of trips on which one was due
+STREAM_TRIPS = {
+    "ba": dict(iters=17, live_iters=116, lane_iters=136, steals=1,
+               entry_terms=48, phase=15),
+    "caveman": dict(iters=9, live_iters=59, lane_iters=72, steals=0,
+                    entry_terms=39, phase=7),
+    "er": dict(iters=51, live_iters=396, lane_iters=408, steals=1,
+               entry_terms=13, phase=29),
+}
+
+
 @pytest.mark.parametrize("gname", sorted(GRAPHS))
 def test_stream_spanning_matches_perroot_on_hub_graphs(gname):
     """Multi-bucket stream with a planted hub: the spanning engine (lane
@@ -417,6 +430,14 @@ def test_stream_spanning_matches_perroot_on_hub_graphs(gname):
     assert res.cliques == len(oracle.bk_pivot(g))
     assert res.stats["spans"] >= 1
     assert not res.iters_exhausted
+    want = dict(STREAM_TRIPS[gname])
+    phase = want.pop("phase")
+    assert {k: res.stats[k] for k in want} == want
+    # every segment after a span's first (the next slab's, and the last
+    # slab's drain) starts with an outer trip on which no phase may be due
+    slabs = len(prepare(g, bucket_sizes=(32, 64)).buckets)
+    assert phase <= res.stats["phase_trips"] <= phase + slabs
+    assert res.stats["phase_trips"] <= res.stats["iters"]
 
 
 @pytest.mark.parametrize("gname", sorted(GRAPHS))
@@ -482,6 +503,67 @@ def test_steal_victim_policies_bit_identical(backend):
     assert br.cliques == len(oracle.bk_pivot(g))
     assert br.stats["steals"] > 0
     assert de.stats["steals"] > 0
+
+
+# The queue's counters on the hub fixture (skewed_graph(blob=40, p=0.6),
+# one U=64 bucket, 8 lanes), as a loop that runs the refill and steal
+# conds on every trip counts them; `phase` is the number of those trips
+# on which a refill was due or a steal could move work.
+QUEUE_TRIPS = {
+    ("pivot", True, None): dict(
+        iters=216, live_iters=1657, claimed=85, steals=9, entry_terms=50,
+        truncated=0, cliques=1344, calls=1188, branches=1103, sum_px=7590,
+        phase=38),
+    ("pivot", False, None): dict(
+        iters=229, live_iters=1648, claimed=85, steals=0, entry_terms=50,
+        truncated=0, cliques=1344, calls=1188, branches=1103, sum_px=7590,
+        phase=30),
+    ("pivot", True, 40): dict(
+        iters=40, live_iters=320, claimed=54, steals=0, entry_terms=46,
+        truncated=1, cliques=349, calls=262, branches=208, sum_px=1443,
+        phase=7),
+    ("rcd", True, None): dict(
+        iters=315, live_iters=2419, claimed=85, steals=0, entry_terms=50,
+        truncated=0, cliques=1344, calls=1773, branches=1688, sum_px=12211,
+        phase=33),
+    ("hybrid", True, None): dict(
+        iters=215, live_iters=1648, claimed=85, steals=13, entry_terms=57,
+        truncated=0, cliques=1344, calls=1188, branches=1103, sum_px=7590,
+        phase=42),
+    ("hybrid", False, 40): dict(
+        iters=40, live_iters=320, claimed=54, steals=0, entry_terms=46,
+        truncated=1, cliques=353, calls=264, branches=210, sum_px=1450,
+        phase=7),
+}
+
+
+@pytest.mark.parametrize(
+    "backend,steal,max_iters", sorted(QUEUE_TRIPS, key=str),
+    ids=[f"{b}-steal_{'on' if s else 'off'}-{'cut' if m else 'drain'}"
+         for b, s, m in sorted(QUEUE_TRIPS, key=str)])
+def test_queue_trips_unchanged_by_the_inner_loop(backend, steal, max_iters):
+    """The inner loop runs only the trips on which the refill and steal
+    conds would be the identity, so every counter, a `max_iters` cut
+    included, is the one-cond-per-trip walk's; `phase_trips` counts the
+    trips on which a phase was due. A drained walk's useful lane steps
+    are the lock-step walk's, plus one per entry-terminated root and one
+    pop per stolen branch set."""
+    g = skewed_graph(blob=40, p=0.6)
+    args = _bucket_args(g)
+    kw = {} if max_iters is None else {"max_iters": max_iters}
+    cfg = EngineConfig(backend=backend, steal=steal, **kw)
+    out = run_bucket_persistent(*args, cfg, lanes=8)
+    want = dict(QUEUE_TRIPS[backend, steal, max_iters])
+    assert int(out["phase_trips"]) == want.pop("phase")
+    got = {k: int(np.asarray(out[k]).sum()) for k in want}
+    assert got == want
+    assert int(out["phase_trips"]) <= int(out["iters"])
+    if max_iters is None:
+        ref = run_bucket(*args, EngineConfig(backend=backend))
+        assert int(out["cliques"].sum()) == int(ref["cliques"].sum())
+        assert int(out["live_iters"]) == (int(ref["iters"].sum())
+                                          + got["entry_terms"]
+                                          + got["steals"])
 
 
 def test_hybrid_entry_terms_counted_in_refill():
