@@ -2,7 +2,8 @@
 
 Measures the trip-count-weighted per-DFS-iteration roofline terms of the
 shard_map'ed counting kernel on the production mesh, current engine vs the
-flag-gated paper-faithful degree pass (reuse_degrees=False).
+flag-gated paper-faithful degree pass (reuse_degrees=False). The step
+returns its counters per shard, with no cross-shard reduction.
 
 Iterations 2 (straight-line masked DFS, no lax.cond→select) and 3 (packed
 bitset X-alive stacks) are structural rewrites; their before/after numbers

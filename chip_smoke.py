@@ -14,9 +14,9 @@ and its compiled chunk step must hold the query's Pallas kernels
 (`tpu_custom_call`s named after their `pallas_call`).
 
 Four chips: a perroot and a persistent query on a 4-device mesh, with a
-check that every chunk step spreads its roots over all four devices, then
-a run cut after a few chunks on four devices and resumed from its
-checkpoint cursor on two.
+check that every chunk step spreads its roots over all four devices and a
+line of each bucket's loop trips per shard, then a run cut after a few
+chunks on four devices and resumed from its checkpoint cursor on two.
 
 Everything runs in this one process, which holds the chips; it starts no
 other. The compile cache follows `JAX_COMPILATION_CACHE_DIR` (else
@@ -60,6 +60,8 @@ def queries():
 
     return [
         ("pivot/perroot", EngineConfig(backend="pivot"), "perroot",
+         {"frame_step", "and_popcount_rows", "and_popcount_argmax"}),
+        ("pivot/persistent", EngineConfig(backend="pivot"), "persistent",
          {"frame_step", "and_popcount_rows", "and_popcount_argmax"}),
         ("hybrid/perroot", EngineConfig(backend="hybrid"), "perroot",
          {"clique_counts"}),
@@ -123,6 +125,10 @@ def four_chips(g, want, devices):
         f, compiled = run_query(svc, label + "/4chips", cfg, engine,
                                 kernels, want, runs=1)
         fails += f
+        shard_trips = {shape: per["shard_trips"] for shape, per in
+                       svc.last_driver.stats["buckets"].items()}
+        print(f"{label}/4chips trips per shard by (u_pad, x_pad, engine): "
+              f"{shard_trips}", flush=True)
         # every input of the step is split over the 4 devices, one
         # shard-row of roots each
         for sh, x in zip(compiled.input_shardings[0],

@@ -67,7 +67,8 @@ COUNTER_KEYS = ("cliques", "calls", "branches", "sum_px", "truncated",
                 "trips", "phase_trips")
 # the work each chunk program does, folded per (u_pad, x_pad, engine) into
 # stats["buckets"]: the operands of a work-based kernel roofline, and the
-# loop's trips beside the ones that ran the queue's phases
+# loop's trips beside the ones that ran the queue's phases; beside each
+# total, "shard_<key>" lists it per shard (the balance of the dealing)
 BUCKET_KEYS = ("calls", "sum_px", "live_iters", "lane_iters", "trips",
                "phase_trips")
 # the host stages that `host_pack_s` sums: everything but the settle
@@ -132,13 +133,17 @@ def _shard_batch(bucket: RootBucket, idx: np.ndarray, pad_to: int):
 
 def _sharded_counts_impl(a, p0, xr, xa, rz, cfg: EngineConfig, mesh: Mesh,
                          axis, engine: str = "perroot", lanes: int = 64):
-    """Run a [n_shards, chunk, ...] batch under shard_map; psum counters.
+    """Run a [n_shards, chunk, ...] batch under shard_map; per-shard counters.
 
-    `axis` is a mesh axis name or a tuple of axis names (multi-pod: roots
-    shard over the flattened ("pod", "data") product). `engine='persistent'`
-    runs each shard's chunk through the lane-refill work queue — the
-    chunk's cost-descending slice order IS the queue order — instead of
-    one lock-step lane per root (`run_lockstep`)."""
+    Returns each of `COUNTER_KEYS` as `int32[n_shards]`, sharded like the
+    roots: the program holds no cross-chip collective, so no shard waits
+    on another at the end of its chunk, and `DistributedMCE._settle` folds
+    the shards on the host. `axis` is a mesh axis name or a tuple of axis
+    names (multi-pod: roots shard over the flattened ("pod", "data")
+    product). `engine='persistent'` runs each shard's chunk through the
+    lane-refill work queue — the chunk's cost-descending slice order IS
+    the queue order — instead of one lock-step lane per root
+    (`run_lockstep`)."""
 
     def per_shard(a_s, p_s, xr_s, xa_s, rz_s):
         if engine == "persistent":
@@ -156,16 +161,14 @@ def _sharded_counts_impl(a, p0, xr, xa, rz, cfg: EngineConfig, mesh: Mesh,
                        lane_iters=trips * a_s.shape[1], trips=trips,
                        steals=jnp.int32(0), entry_terms=jnp.int32(0),
                        phase_trips=jnp.int32(0))
-        sums = {k: jnp.sum(out[k]).astype(jnp.int32)[None]
+        return {k: jnp.sum(out[k]).astype(jnp.int32)[None]
                 for k in COUNTER_KEYS}
-        return sums
 
     specs_in = (P(axis), P(axis), P(axis), P(axis), P(axis))
     specs_out = {k: P(axis) for k in COUNTER_KEYS}
     fn = jax.shard_map(per_shard, mesh=mesh, in_specs=specs_in,
                        out_specs=specs_out, check_vma=False)
-    out = fn(a, p0, xr, xa, rz)
-    return {k: jnp.sum(v) for k, v in out.items()}
+    return fn(a, p0, xr, xa, rz)
 
 
 def _lockstep_counts(a, p0, xr, xa, rz, cfg: EngineConfig, mesh: Mesh, axis):
@@ -183,7 +186,7 @@ class _ChunkStep:
     program.
 
     One program per chunk shape and static arguments. Its inputs are not
-    donated: the step returns only scalar counters, so no output could
+    donated: the step returns only per-shard counters, so no output could
     alias a chunk buffer, and the driver drops each chunk's arrays after
     the dispatch anyway."""
 
@@ -389,11 +392,11 @@ class DistributedMCE:
             done = state.roots_done if b == state.bucket else 0
             while done < total:
                 hi = min(done + window, total)
-                out, n_pad = self._run_chunk(bucket, order[done:hi],
-                                             eng_b, lanes_b, b, k)
+                out, pads = self._run_chunk(bucket, order[done:hi],
+                                            eng_b, lanes_b, b, k)
                 if pending is not None:
                     self._settle(pending, state)
-                pending = (out, n_pad, b, hi, k,
+                pending = (out, pads, b, hi, k,
                            (bucket.u_pad, bucket.x_pad, eng_b))
                 done = hi
                 k += 1
@@ -420,15 +423,16 @@ class DistributedMCE:
         `engine`/`lanes` are per-bucket: under engine="auto" the driver
         resolves them from the bucket's cost skew before each chunk; `b`
         and `k` are the bucket and chunk ids its spans carry.
-        Returns (unrealized device counters, n_pad); the caller settles the
-        previous chunk after dispatching this one, so host pack/upload of
-        chunk k+1 overlaps device execution of chunk k."""
+        Returns (unrealized device counters, pad roots per shard); the
+        caller settles the previous chunk after dispatching this one, so
+        host pack/upload of chunk k+1 overlaps device execution of chunk
+        k."""
         spans = self.stats["spans"]
         with span("driver.gather", spans, bucket=b, chunk=k):
             slices = [window[s::self.n_shards] for s in range(self.n_shards)]
             pad_to = max(len(s) for s in slices)
             parts = [_shard_batch(bucket, s, pad_to) for s in slices]
-            n_pad = sum(pad_to - len(s) for s in slices)
+            pads = np.array([pad_to - len(s) for s in slices])
             stacked = [np.stack([p[i] for p in parts]) for i in range(5)]
         sharding = NamedSharding(self.mesh, P(self.axis))
         with span("driver.upload", spans, bucket=b, chunk=k):
@@ -441,7 +445,7 @@ class DistributedMCE:
             tuple(jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding)
                   for x in (a, p0, xr, xa, rz)),
             dict(engine=engine, lanes=lanes))
-        return out, n_pad
+        return out, pads
 
     def compiled_step(self):
         """The compiled program of the last chunk step this driver ran, to
@@ -451,23 +455,32 @@ class DistributedMCE:
                                      axis=self.axis, **kw).compile()
 
     def _settle(self, pending, state: DriverCheckpoint) -> None:
-        """Block on a dispatched chunk, fold counters, checkpoint cursor."""
-        out, n_pad, b, hi, k, shape = pending
+        """Block on a dispatched chunk, fold its shards' counters into the
+        run's totals and the bucket shape's totals and per-shard lists,
+        checkpoint the cursor."""
+        out, pads, b, hi, k, shape = pending
         with span("driver.settle", self.stats["spans"], bucket=b, chunk=k):
-            out = {key: int(np.asarray(v)) for key, v in out.items()}
+            # one int64 vector per key, one entry per shard
+            out = {key: np.asarray(v).astype(np.int64)
+                   for key, v in out.items()}
             self.stats["chunks"] += 1
             # padded no-op roots contribute exactly one call each; remove
             # them so distributed counters match the single-host run
             # bit-for-bit
-            out["calls"] = out["calls"] - n_pad
+            out["calls"] = out["calls"] - pads
             for key in COUNTER_KEYS:
                 # .get: checkpoints written before a counter key existed
                 # resume cleanly (the missing key starts from zero)
-                state.counters[key] = state.counters.get(key, 0) + out[key]
+                state.counters[key] = (state.counters.get(key, 0)
+                                       + int(out[key].sum()))
             per = self.stats["buckets"].setdefault(
-                shape, {key: 0 for key in BUCKET_KEYS})
+                shape, dict.fromkeys(BUCKET_KEYS, 0))
             for key in BUCKET_KEYS:
-                per[key] += out[key]
+                # "shard_<key>": each shard's sum over the bucket's chunks
+                shards = [int(v)
+                          for v in out[key] + per.get("shard_" + key, 0)]
+                per["shard_" + key] = shards
+                per[key] = sum(shards)
             state.bucket, state.roots_done = b, hi
             if self.ckpt_path:
                 state.save(self.ckpt_path)

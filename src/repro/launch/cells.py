@@ -214,6 +214,8 @@ def _mce_cell(spec: ArchSpec, cell: ShapeCell, mesh: Mesh) -> CellProgram:
     xa = _sds((n_shards, r, xc), jnp.bool_, mesh, sp)
     rz = _sds((n_shards, r), jnp.int32, mesh, sp)
 
+    # each counter comes back as int32[n_shards], one entry per shard: the
+    # step holds no collective, and the caller folds the shards
     def fn(a_, p_, x_, l_, z_):
         return _sharded_counts(a_, p_, x_, l_, z_, ecfg, mesh, dp)
 

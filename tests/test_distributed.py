@@ -142,6 +142,41 @@ def test_elastic_restart_different_device_count(tmp_path):
     assert "CLIQUES" in out4
 
 
+def test_4dev_per_shard_counters_and_no_collective():
+    """4 virtual devices, a Kronecker scale-8 graph: the root-parallel run
+    counts what the host oracle counts, each bucket's `shard_*` lists hold
+    one entry per shard that sum to the bucket's totals, and neither chunk
+    program (the persistent queue under engine="auto", the lock-step walk)
+    holds a cross-chip collective."""
+    out = run_py("""
+        from repro.core import oracle
+        from repro.core.driver import BUCKET_KEYS, DistributedMCE
+        from repro.core.engine import EngineConfig
+        from repro.graph.generators import kronecker
+        from repro.launch.hlo_cost import COLLECTIVES
+        g = kronecker(8, 16, seed=1, a=0.57, b=0.19, c=0.19)
+        want = sum(1 for _ in oracle.bk_pivot(g))
+        for engine in ("auto", "perroot"):
+            drv = DistributedMCE(g, cfg=EngineConfig(backend="pivot"),
+                                 engine=engine)
+            assert drv.n_shards == 4, drv.n_shards
+            res = drv.run()
+            assert res.cliques == want, (engine, res.cliques, want)
+            buckets = drv.stats["buckets"]
+            assert buckets
+            for shape, per in buckets.items():
+                for key in BUCKET_KEYS:
+                    shards = per["shard_" + key]
+                    assert len(shards) == 4, (shape, key, shards)
+                    assert sum(shards) == per[key], (shape, key, shards)
+            text = drv.compiled_step().as_text()
+            found = [c for c in COLLECTIVES if c in text]
+            assert not found, (engine, found)
+            print("OK", engine, sorted(buckets))
+    """, devices=4)
+    assert "OK auto" in out and "OK perroot" in out
+
+
 @pytest.mark.slow
 def test_dryrun_entrypoint_multipod():
     """The dry-run entry point itself: one cheap cell on the 512-device

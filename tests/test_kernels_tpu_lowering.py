@@ -22,7 +22,9 @@ described `v5e:2x2` topology: shapes only, nothing runs. Cases:
   each under its own HLO module name: the two widest buckets of
   G(2000, 0.1) (U=256 lock-step, U=128 persistent) and the widths no
   benchmark cell runs yet, and the U=256 lock-step one with no select
-  over its whole stack.
+  over its whole stack;
+* the chunk step of the four-chip cell's U=64 bucket on the described
+  host's four chips, for each engine path, with no collective.
 
 The topology is described inside a module fixture (never at import),
 which skips the file where libtpu cannot describe it.
@@ -398,6 +400,42 @@ def test_compile_wide_chunk_step(topo, monkeypatch, engine, u):
         engine=engine, lanes=LANES).compile().as_text()
     assert re.search(rf"^HloModule {MODULES[engine]}\b", text, re.M)
     assert "%frame_step" in text
+
+
+@pytest.mark.parametrize("engine", ["persistent", "perroot"])
+def test_four_chip_chunk_step_holds_no_collective(topo, monkeypatch, engine):
+    """The chunk step of graph500_s12_4chips's U=64 bucket (151 roots and
+    512 X rows a chip) on the described host's four chips: each shard's
+    counters stay on its chip, so the program holds no collective, and
+    each counter is `int32[4]` split over the four devices."""
+    from repro.core import driver
+    from repro.core.engine import EngineConfig
+    from repro.kernels.bitset_ops import ops
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    chunk, u, xc = 151, 64, 512
+    w = u // 32
+    mesh = Mesh(np.array(topo.devices[:4]), ("data",))
+    sh = NamedSharding(mesh, P("data"))
+
+    def S(shape, dt=U32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sh)
+
+    compiled = driver._sharded_counts.lower(
+        S((4, chunk, u, w)), S((4, chunk, w)), S((4, chunk, xc, w)),
+        S((4, chunk, xc), BOOL), S((4, chunk), I32),
+        cfg=EngineConfig(backend="pivot"), mesh=mesh, axis=("data",),
+        engine=engine, lanes=LANES).compile()
+    text = compiled.as_text()
+    assert "%frame_step" in text
+    found = [c for c in ("all-reduce", "all-gather", "reduce-scatter",
+                         "all-to-all", "collective-permute") if c in text]
+    assert not found, found
+    outs = compiled.output_shardings
+    assert set(outs) == set(driver.COUNTER_KEYS)
+    for key, out_sh in outs.items():
+        assert len(out_sh.device_set) == 4, key
+        assert out_sh.shard_shape((4,)) == (1,), key
 
 
 # a select whose result has the lock-step stack's leading [roots, U + 2]
